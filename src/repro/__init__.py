@@ -1,8 +1,4 @@
 """repro: CuAsmRL (CGO'25) on TPU — RL-optimized instruction schedules as a
 compiler service inside a multi-pod JAX training/serving framework."""
 
-from repro import compat as _compat
-
-_compat.install()
-
 __version__ = "1.0.0"

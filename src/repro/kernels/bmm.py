@@ -11,8 +11,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 from repro.sched.spec import KernelSpec, TileIO
 
 
@@ -48,7 +46,7 @@ def bmm(a: jax.Array, b: jax.Array, *, bm: int = 128, bn: int = 128,
         out_specs=pl.BlockSpec((1, bm, bn), lambda b_, i, j, kk: (b_, i, j)),
         out_shape=jax.ShapeDtypeStruct((B, m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

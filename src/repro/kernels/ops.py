@@ -10,10 +10,6 @@ from __future__ import annotations
 
 from typing import Literal
 
-
-# canonical re-export: the kernels' CompilerParams drift shim (implemented
-# in repro.compat, which imports no kernel modules — cycle-free)
-from repro.compat import tpu_compiler_params  # noqa: F401
 from repro.kernels import (bmm as _bmm_mod, flash_attention as _fa_mod,
                            fused_ff as _ff_mod,
                            matmul_leakyrelu as _mm_mod, ref,

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 from repro.sched.spec import KernelSpec, TileIO
 
 
@@ -33,30 +31,33 @@ def _kernel(x_ref, a_ref, b_ref, c_ref, o_ref, state_ref, *, chunk: int):
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[0].astype(jnp.float32)            # (chunk, P)
-    a = a_ref[0].astype(jnp.float32)            # (chunk,)
+    a = a_ref[0, 0].astype(jnp.float32)         # (1, chunk) lane row
     b = b_ref[0].astype(jnp.float32)            # (chunk, N)
     c = c_ref[0].astype(jnp.float32)            # (chunk, N)
 
-    seg = jnp.cumsum(a)                          # inclusive decay prefix
-    total = seg[-1]
+    # inclusive decay prefix seg[t] = sum_{r<=t} a[r] as a masked lane
+    # reduction (Mosaic has no cumsum; an MXU pass would round to bf16)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    seg = jnp.sum(jnp.where(cols <= rows, a, 0.0), axis=1,
+                  keepdims=True)                # (chunk, 1)
+    total = jnp.sum(a, axis=1, keepdims=True)   # (1, 1)
 
     # within-chunk: y_intra[t] = sum_{s<=t} e^{seg t - seg s} (c_t . b_s) x_s
     scores = jnp.dot(c, b.T, preferred_element_type=jnp.float32)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    decay = jnp.exp(seg[:, None] - seg[None, :])
+    decay = jnp.exp(seg - seg.T)
     l_mat = jnp.where(rows >= cols, decay, 0.0)
     y_intra = jnp.dot(scores * l_mat, x, preferred_element_type=jnp.float32)
 
     # inter-chunk: y_inter[t] = e^{seg t} c_t . state_in
     state = state_ref[...]                       # (P, N)
-    y_inter = jnp.exp(seg)[:, None] * jnp.dot(
+    y_inter = jnp.exp(seg) * jnp.dot(
         c, state.T, preferred_element_type=jnp.float32)
 
     o_ref[0] = (y_intra + y_inter).astype(o_ref.dtype)
 
     # state update: state' = e^{total} state + sum_s e^{total-seg s} x_s b_s^T
-    w = jnp.exp(total - seg)[:, None]
+    w = jnp.exp(total - seg)
     state_ref[...] = (jnp.exp(total) * state
                       + jnp.dot((x * w).T, b,
                                 preferred_element_type=jnp.float32))
@@ -68,23 +69,27 @@ def ssd(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array, *,
     N = b.shape[-1]
     assert S % chunk == 0 and a.shape == (BH, S)
     grid = (BH, S // chunk)
+    # a as (BH, chunks, 1, chunk): each block's last two dims are whole,
+    # so the TPU tiling accepts any chunk (a (1, chunk) block of the flat
+    # (BH, S) array would not be)
+    a4 = a.reshape(BH, S // chunk, 1, chunk)
     return pl.pallas_call(
         functools.partial(_kernel, chunk=chunk),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, chunk, P), lambda h, j: (h, j, 0)),
-            pl.BlockSpec((1, chunk), lambda h, j: (h, j)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda h, j: (h, j, 0, 0)),
             pl.BlockSpec((1, chunk, N), lambda h, j: (h, j, 0)),
             pl.BlockSpec((1, chunk, N), lambda h, j: (h, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, P), lambda h, j: (h, j, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="ssd",
-    )(x, a, b, c)
+    )(x, a4, b, c)
 
 
 def make_spec(cfg: Dict) -> KernelSpec:

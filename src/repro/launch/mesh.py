@@ -18,6 +18,13 @@ from typing import Optional, Tuple
 import jax
 
 
+def _auto(axes: Tuple[str, ...]):
+    """``Auto`` axis types: the partitioner propagates shardings, as the
+    sharding rules and ``with_sharding_constraint`` calls expect (JAX's
+    ``make_mesh`` otherwise builds ``Explicit`` axes)."""
+    return (jax.sharding.AxisType.Auto,) * len(axes)
+
+
 def make_production_mesh(*, multi_pod: bool = False, pipe: int = 1):
     """single-pod: (data=16, model=16) = 256 chips;
     multi-pod:  (pod=2, data=16, model=16) = 512 chips.
@@ -35,7 +42,7 @@ def make_production_mesh(*, multi_pod: bool = False, pipe: int = 1):
         shape, axes = shape + (pipe,), axes + ("pipe",)
     shape += (16 // pipe, 16)
     axes += ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(axes))
 
 
 def host_mesh_shape(n_devices: int, *, model: int = 1,
@@ -78,4 +85,4 @@ def make_host_mesh(model: int = 1, data: Optional[int] = None,
     """
     shape, axes = host_mesh_shape(len(jax.devices()), model=model, data=data,
                                   pipe=pipe, pods=pods)
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(axes))

@@ -30,6 +30,7 @@ import argparse
 import os
 from typing import List, Optional, Sequence, Tuple
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.sched import (OptimizationSession, OptimizeRequest,
                          make_budgeted_strategy)
 from repro.sched.backends import BACKENDS, make_backend, warm_start_memo
@@ -90,6 +91,7 @@ def campaign_requests(units: Sequence[FleetUnit],
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("kernels", nargs="*",
                     help="registry kernel names (see repro.kernels.KERNELS);"

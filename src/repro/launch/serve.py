@@ -21,6 +21,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCHS, get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import for_config
 from repro.serve import generate
 
@@ -138,6 +139,7 @@ def _legacy_mode(args, cfg) -> None:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-1b", choices=list(ARCHS))
     ap.add_argument("--full", action="store_true")

@@ -24,10 +24,12 @@ import argparse
 import warnings
 
 from repro.configs import ARCHS, get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.train import TrainConfig, Trainer
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-3b", choices=list(ARCHS))
     ap.add_argument("--steps", type=int, default=50)

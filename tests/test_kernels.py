@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ref
+from repro.kernels import cases, ref
 from repro.kernels.bmm import bmm
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_ff import fused_ff
@@ -117,3 +117,14 @@ def test_ssd_vs_scan_oracle(chunk):
                          b[:, :, None, :], c[:, :, None, :])[:, :, 0, :]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-3, rtol=1e-3)
+
+
+@pytest.mark.parametrize("name", cases.BUILTIN)
+def test_check_case_matches_ref_in_interpret_mode(name):
+    """The cases chip_smoke.py runs on the chip agree with their oracles
+    here, so a miss there points at the chip, not the case."""
+    case = cases.kernel_case(name, interpret=True)
+    args = case.make(jax.random.PRNGKey(0))
+    with jax.default_matmul_precision("highest"):
+        want = case.ref(*args)
+    assert cases.relative_error(case.run(*args), want) <= cases.REL_TOL

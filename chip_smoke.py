@@ -14,16 +14,17 @@ One chip, three phases:
   ``ServeEngine.from_config(..., paged=True)``, driven by
   ``serve.loadgen.run_load`` with two tenants and shared prompt prefixes,
   then one request repeated so that prefix sharing and the copy-on-write
-  page copy run; finished requests are checked token by token against the
-  model's full forward pass;
+  page copy run; every run of that prompt must yield the same tokens, and
+  finished requests are checked token by token against the model's full
+  forward pass;
 * optimize: ``OptimizationSession().optimize`` on one kernel with a few
   hundred PPO timesteps, so the policy network runs on the chip.
 
 Four chips: stablelm-3b at published widths (24 of its 32 layers) trains
-3 steps with the GSPMD step on a (data=2, model=2) mesh and 3 steps with
-the shard_map pipeline step on a (pipe=2, data=1, model=2) mesh, from the
-same seed on the same batches; the losses must agree and all four chips
-must hold state.
+3 steps through ``Trainer`` with the GSPMD step on a (data=2, model=2)
+mesh and 3 steps with the shard_map pipeline step on a (pipe=2, data=1,
+model=2) mesh, from the same seed on the same batches; the losses must
+agree and all four chips must hold state.
 
 Every phase runs in this one process (a chip belongs to one process).
 Lines before the last are smoke facts, not speeds.  The last line is one
@@ -63,10 +64,11 @@ NEW_TOKENS = 32
 # that row's logits) of forward's largest logit.  The engine (one token
 # per pass through a paged bf16 cache) and forward (the whole sequence at
 # once) round differently in bf16 at each of 40 layers, and a deep
-# random-weight network amplifies that: near-ties flip.  A 40-layer,
-# d_model 256 rehearsal on the CPU gave gaps up to 0.30 sigma; a token
-# from a wrong page or position sits about 4.5 sigma below the top of a
-# 151936-token row.
+# random-weight network amplifies that: near-ties flip.  Sound runs gave
+# up to 0.54 sigma on a v5e.  In a 40-layer, d_model 256 rehearsal on the
+# CPU (sound: 0.31) lanes reading each other's block tables gave 5.95 and
+# block ids off by one 3.40, but a skipped copy-on-write page copy only
+# 0.76: the replay check below catches that one.
 LOGIT_TOL_SIGMA = 1.0
 
 # optimize phase
@@ -78,11 +80,19 @@ OPT_KERNEL, PPO_TIMESTEPS = "rmsnorm", 512
 # 6.0 + 9.2 GB at 24 (the GSPMD step 5.1 + 1.5 GB), against 16 GiB.
 TRAIN_ARCH, TRAIN_LAYERS = "stablelm-3b", 24
 TRAIN_STEPS, SEQ_LEN, GLOBAL_BATCH = 3, 256, 8
-# The two steps partition one bf16 computation differently (all-reduce vs
-# pipeline stages and tensor-parallel psums, whole batch vs 4
-# microbatches), so sums round in different orders; a wrong collective or
-# stage hand-off moves the loss by far more than this.
-LOSS_RTOL = 1e-2
+# Relative-gap bounds between the two runs' losses, at step 1 and at any
+# step.  The two steps partition one bf16 computation differently
+# (all-reduce vs pipeline stages and tensor-parallel psums, whole batch vs
+# 4 microbatches), so sums round in different orders.  At step 1 both see
+# the same parameters and batch and only that rounding separates them;
+# Adam's normalized updates then amplify it.  On a v5e 2x2 the sound gaps
+# were 2.4e-4 at step 1 and 3.3e-3 at worst; a batch whose second half
+# repeats its first moved the step-1 loss by 7.6e-3.  Gradients left
+# unsummed over `model` or over `pipe` stayed inside both bounds: at this
+# random init three steps of Adam barely move the loss.  The step-1 grad
+# norm is printed, not bounded: it was 4.9e-2 apart in the sound run and
+# 6.7e-2 with the `model` sum missing.
+STEP1_LOSS_RTOL, LOSS_RTOL = 1e-3, 1e-2
 # no chip may hold more than this share of the bytes in use on all four
 # (an even layout gives each about a quarter)
 MAX_DEVICE_SHARE = 0.5
@@ -173,7 +183,7 @@ def serve_phase(cfg, n_requests: int = N_REQUESTS) -> None:
     # The same prompt again while its first copy decodes: the second maps
     # the first's pages, including the partial last prompt block, and its
     # first write there forks that block (copy-on-write).
-    prompt = loaded[0].prompt
+    prompt = list(loaded[0].prompt)
     if len(prompt) % BLOCK == 0:
         prompt = prompt[:-1]          # the partial last block is the shared
     first = engine.submit(prompt, NEW_TOKENS, tenant="t0")
@@ -189,15 +199,26 @@ def serve_phase(cfg, n_requests: int = N_REQUESTS) -> None:
     if eng["cow_forks"] < 1 or eng["prefix_hits"] < 1:
         raise AssertionError(f"prefix sharing / copy-on-write did not run: "
                              f"{eng}")
+    # Greedy decoding is deterministic, and a lane's arithmetic depends on
+    # neither its lane nor its neighbours, so every run of one prompt
+    # yields the same tokens.  The second copy read pages the first wrote,
+    # and the first wrote on into the block it forked: a skipped or
+    # misdirected page copy shows here, where the forward check's
+    # tolerance may not see it.
+    runs = [first, second] + [r for r in loaded if list(r.prompt) == prompt]
+    if any(r.output != first.output for r in runs):
+        raise AssertionError(f"{len(runs)} runs of one prompt disagree: "
+                             f"{[r.output for r in runs]}")
+    log(f"serve replay: {len(runs)} runs of one prompt, identical tokens")
 
     fwd = jax.jit(functools.partial(lm.forward, cfg=cfg))
     worst = 0.0
     for req in (loaded[0], loaded[-1], first, second):
         gaps = logit_gaps(fwd, engine.params, req)
         worst = max(worst, float(gaps.max()))
-        log(f"serve request {req.id}: {len(req.output)} tokens, worst logit "
-            f"gap {gaps.max():.4f} sigma, exact argmax at "
-            f"{int(np.sum(gaps == 0))}/{len(gaps)}")
+        log(f"serve request {req.id}: {len(req.output)} tokens, logit gap "
+            f"worst {gaps.max():.4f} mean {gaps.mean():.4f} sigma, exact "
+            f"argmax at {int(np.sum(gaps == 0))}/{len(gaps)}")
     log(f"serve forward check: worst logit gap {worst:.4f} sigma "
         f"(bound {LOGIT_TOL_SIGMA})")
     if not worst <= LOGIT_TOL_SIGMA:
@@ -233,83 +254,55 @@ def optimizer_phase(cache_dir: str) -> None:
 # four chips
 # ---------------------------------------------------------------------------
 
-def train_setup(cfg, mesh, *, pipeline: bool):
-    """(jitted init -> placed TrainState, jitted step) on ``mesh``: the
-    GSPMD step with the dist layer's parameter shardings, or the
-    shard_map pipeline step with its stage specs.  The state is created
-    in place on the mesh, never whole on one chip."""
+def train_metrics(cfg, mesh):
+    """``Trainer(cfg, ..., mesh=mesh).run()``'s (loss, grad norm) per step:
+    the GSPMD step on a mesh without ``pipe``, the shard_map pipeline step
+    with it.  Fails when one chip holds most of the bytes in use, which
+    would show the state placed whole on one chip."""
     import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.dist import sharding as shd
-    from repro.models import lm
-    from repro.optim import adamw
-    from repro.optim.adamw import AdamState
-    from repro.train.step import (TrainState, make_sharded_train_step,
-                                  make_train_step)
+    from repro.train import TrainConfig, Trainer
 
-    opt = adamw(3e-4, weight_decay=0.1, max_grad_norm=1.0)
-    spec_tree = lm.model_spec(cfg)
-    if pipeline:
-        specs = shd.sharded_param_specs(spec_tree, shd.STAGE_KEYS, mesh)
-        step = make_sharded_train_step(cfg, opt, mesh)
-    else:
-        specs = shd.logical_specs(spec_tree, mesh)
-        step = make_train_step(cfg, opt, mesh=mesh)
-    param_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
-                            is_leaf=lambda x: isinstance(x, P))
-    rep = NamedSharding(mesh, P())
-    state_sh = TrainState(param_sh, AdamState(rep, param_sh, param_sh), rep)
-
-    def init():
-        params = lm.init_model(cfg, jax.random.PRNGKey(SEED))
-        return TrainState(params, opt.init(params), jnp.zeros((), jnp.int32))
-
-    return (jax.jit(init, out_shardings=state_sh),
-            jax.jit(step, donate_argnums=0))
-
-
-def train_losses(cfg, mesh, batches, *, pipeline: bool):
-    import jax
-
-    init, step = train_setup(cfg, mesh, pipeline=pipeline)
     t0 = time.monotonic()
-    state = init()
-    losses = []
-    for batch in batches:
-        state, metrics = step(state, batch)
-        losses.append(float(metrics["loss"]))
-    jax.block_until_ready(state)
+    trainer = Trainer(cfg, TrainConfig(
+        steps=TRAIN_STEPS, seq_len=SEQ_LEN, global_batch=GLOBAL_BATCH,
+        lr=3e-4, warmup=0, seed=SEED), mesh=mesh)
+    steps = [(m["loss"], m["grad_norm"]) for m in trainer.run()]
+    jax.block_until_ready(trainer.state)
     used = [d.memory_stats()["bytes_in_use"] for d in mesh.devices.flat]
-    name = "pipeline" if pipeline else "gspmd"
-    log(f"train {name} on {dict(mesh.shape)}: losses {losses}; "
-        f"{time.monotonic() - t0:.1f}s with compile; bytes in use per "
-        f"device {used}")
+    name = "pipeline" if trainer.use_pipeline else "gspmd"
+    log(f"train {name} on {dict(mesh.shape)}: (loss, grad norm) per step "
+        f"{steps}; {time.monotonic() - t0:.1f}s with compile; bytes in use "
+        f"per device {used}")
     if max(used) > MAX_DEVICE_SHARE * sum(used):
         raise AssertionError(f"train {name}: one device holds "
                              f"{max(used)} of {sum(used)} bytes")
-    del state
+    del trainer
     gc.collect()
-    return losses
+    return steps
+
+
+def metric_gaps(ref, got):
+    """Relative gaps between two runs' per-step (loss, grad norm): (step-1
+    loss, step-1 grad norm, worst loss over all steps)."""
+    def rel(a, b):
+        return abs(a - b) / abs(a)
+    return (rel(ref[0][0], got[0][0]), rel(ref[0][1], got[0][1]),
+            max(rel(a[0], b[0]) for a, b in zip(ref, got)))
 
 
 def train_phase(cfg) -> None:
-    from repro.data.pipeline import make_data
     from repro.launch.mesh import make_host_mesh
 
-    data = make_data(cfg, SEQ_LEN, GLOBAL_BATCH, SEED)
-    batches = [data.batch_at(i) for i in range(TRAIN_STEPS)]
     log(f"train {cfg.name}: {cfg.n_layers} layers, d_model {cfg.d_model}, "
         f"batch {GLOBAL_BATCH} x {SEQ_LEN} tokens, {TRAIN_STEPS} steps")
-    gspmd = train_losses(cfg, make_host_mesh(model=2), batches,
-                         pipeline=False)
-    pipe = train_losses(cfg, make_host_mesh(model=2, pipe=2), batches,
-                        pipeline=True)
-    gap = max(abs(a - b) / abs(a) for a, b in zip(gspmd, pipe))
-    log(f"train loss agreement: worst relative gap {gap:.3e} "
-        f"(bound {LOSS_RTOL})")
-    if not gap <= LOSS_RTOL:
-        raise AssertionError(f"GSPMD {gspmd} vs pipeline {pipe} losses")
+    gspmd = train_metrics(cfg, make_host_mesh(model=2))
+    pipe = train_metrics(cfg, make_host_mesh(model=2, pipe=2))
+    step1, gnorm, worst = metric_gaps(gspmd, pipe)
+    log(f"train agreement: relative gaps step-1 loss {step1:.3e} (bound "
+        f"{STEP1_LOSS_RTOL}), worst loss {worst:.3e} (bound {LOSS_RTOL}), "
+        f"step-1 grad norm {gnorm:.3e}")
+    if not (step1 <= STEP1_LOSS_RTOL and worst <= LOSS_RTOL):
+        raise AssertionError(f"GSPMD {gspmd} vs pipeline {pipe}")
 
 
 # ---------------------------------------------------------------------------

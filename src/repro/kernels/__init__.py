@@ -64,7 +64,7 @@ def _register_builtins():
                   softmax.softmax, ref.softmax),
         KernelDef("rmsnorm", rmsnorm.make_spec, rmsnorm.CONFIGS,
                   rmsnorm.rmsnorm, ref.rmsnorm),
-        KernelDef("ssd", ssd.make_spec, ssd.CONFIGS, ssd.ssd, None),
+        KernelDef("ssd", ssd.make_spec, ssd.CONFIGS, ssd.ssd, ref.ssd),
     ):
         register_kernel(kdef)
 

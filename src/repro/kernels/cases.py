@@ -1,10 +1,10 @@
 """Check cases for the registered Pallas kernels.
 
 One case per built-in kernel: inputs that span ``TILES`` blocks on every
-grid axis of the kernel's first ``CONFIGS`` entry, the Pallas call at that
-config's block sizes, and its oracle from :mod:`repro.kernels.ref`.
-``chip_smoke.py`` runs every case on the chip; the tests compile them for
-a described TPU and run them in interpret mode.
+grid axis of the kernel's first ``CONFIGS`` entry, and the registry's
+Pallas call (at that config's block sizes) and oracle.  ``chip_smoke.py``
+runs every case on the chip; the tests compile them for a described TPU
+and run them in interpret mode.
 """
 
 from __future__ import annotations
@@ -17,8 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import (bmm, flash_attention, fused_ff, get_kernel,
-                           matmul_leakyrelu, ref, rmsnorm, softmax, ssd)
+from repro.kernels import get_kernel
 
 TILES = 3      # blocks per grid axis: every index_map sees a non-zero step
 # relative_error bound against the oracle run at "highest" matmul
@@ -26,8 +25,6 @@ TILES = 3      # blocks per grid axis: every index_map sees a non-zero step
 # round f32 operands to bf16 too, so a correct kernel lands well under
 # 1e-2; a wrong block index or tiling gives errors of order 1.
 REL_TOL = 2e-2
-BUILTIN = ("matmul_leakyrelu", "fused_ff", "bmm", "flash_attention",
-           "softmax", "rmsnorm", "ssd")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -52,6 +49,14 @@ def _mats(*shapes, dtype=jnp.bfloat16):
     return make
 
 
+def _rmsnorm_inputs(rows: int, cols: int):
+    def make(key):
+        k1, k2 = jax.random.split(key)
+        return (_normal(k1, (rows, cols), jnp.bfloat16),
+                _normal(k2, (cols,), jnp.bfloat16))
+    return make
+
+
 def _ssd_inputs(bh: int, s: int, p: int, n: int):
     def make(key):
         ks = jax.random.split(key, 4)
@@ -62,68 +67,44 @@ def _ssd_inputs(bh: int, s: int, p: int, n: int):
     return make
 
 
-def _ssd_ref(x, a, b, c):
-    y = ref.ssd_chunk(x[:, :, None, :], a[:, :, None], b[:, :, None, :],
-                      c[:, :, None, :])
-    return y[:, :, 0, :]
+def _gemm(c, t):
+    return _mats((t * c["bm"], t * c["bk"]), (t * c["bk"], t * c["bn"]))
+
+
+def _attention(c, t):
+    shape = (1, t, t * max(c["bq"], c["bk"]), c["d"])  # B*H = t rows
+    return _mats(shape, shape, shape)
+
+
+# kernel -> (inputs builder (config, tiles) -> key -> inputs, the config
+# keys its Pallas call takes as block sizes)
+_INPUTS: Dict[str, Tuple[Callable, Tuple[str, ...]]] = {
+    "matmul_leakyrelu": (_gemm, ("bm", "bn", "bk")),
+    "fused_ff": (lambda c, t: _mats((t * c["bm"], t * c["bk"]),
+                                    (t * c["bk"], t * c["bn"]),
+                                    (t * c["bk"], t * c["bn"])),
+                 ("bm", "bn", "bk")),
+    "bmm": (lambda c, t: _mats((t, t * c["bm"], t * c["bk"]),
+                               (t, t * c["bk"], t * c["bn"])),
+            ("bm", "bn", "bk")),
+    "flash_attention": (_attention, ("bq", "bk")),
+    "softmax": (lambda c, t: _mats((t * c["br"], c["cols"])), ("br",)),
+    "rmsnorm": (lambda c, t: _rmsnorm_inputs(t * c["br"], c["cols"]),
+                ("br",)),
+    "ssd": (lambda c, t: _ssd_inputs(t, t * c["chunk"], c["p"], c["n"]),
+            ("chunk",)),
+}
+BUILTIN = tuple(_INPUTS)
 
 
 def kernel_case(name: str, interpret: bool = False) -> KernelCase:
     """The check case of built-in kernel ``name`` at its first config."""
-    cfg: Dict = get_kernel(name).configs[0]
-    t = TILES
-    if name == "matmul_leakyrelu":
-        bm, bn, bk = cfg["bm"], cfg["bn"], cfg["bk"]
-        return KernelCase(name, _mats((t * bm, t * bk), (t * bk, t * bn)),
-                          functools.partial(matmul_leakyrelu.matmul_leakyrelu,
-                                            bm=bm, bn=bn, bk=bk,
-                                            interpret=interpret),
-                          ref.matmul_leakyrelu)
-    if name == "fused_ff":
-        bm, bn, bk = cfg["bm"], cfg["bn"], cfg["bk"]
-        return KernelCase(name, _mats((t * bm, t * bk), (t * bk, t * bn),
-                                      (t * bk, t * bn)),
-                          functools.partial(fused_ff.fused_ff, bm=bm, bn=bn,
-                                            bk=bk, interpret=interpret),
-                          ref.fused_ff)
-    if name == "bmm":
-        bm, bn, bk = cfg["bm"], cfg["bn"], cfg["bk"]
-        return KernelCase(name, _mats((t, t * bm, t * bk),
-                                      (t, t * bk, t * bn)),
-                          functools.partial(bmm.bmm, bm=bm, bn=bn, bk=bk,
-                                            interpret=interpret),
-                          ref.bmm)
-    if name == "flash_attention":
-        bq, bk, d = cfg["bq"], cfg["bk"], cfg["d"]
-        shape = (1, t, t * max(bq, bk), d)       # B*H = t parallel rows
-        return KernelCase(name, _mats(shape, shape, shape),
-                          functools.partial(flash_attention.flash_attention,
-                                            bq=bq, bk=bk,
-                                            interpret=interpret),
-                          ref.flash_attention)
-    if name == "softmax":
-        return KernelCase(name, _mats((t * cfg["br"], cfg["cols"])),
-                          functools.partial(softmax.softmax, br=cfg["br"],
-                                            interpret=interpret),
-                          ref.softmax)
-    if name == "rmsnorm":
-        br, cols = cfg["br"], cfg["cols"]
-
-        def make(key):
-            k1, k2 = jax.random.split(key)
-            return (_normal(k1, (t * br, cols), jnp.bfloat16),
-                    _normal(k2, (cols,), jnp.bfloat16))
-        return KernelCase(name, make,
-                          functools.partial(rmsnorm.rmsnorm, br=br,
-                                            interpret=interpret),
-                          ref.rmsnorm)
-    if name == "ssd":
-        chunk = cfg["chunk"]
-        return KernelCase(name, _ssd_inputs(t, t * chunk, cfg["p"], cfg["n"]),
-                          functools.partial(ssd.ssd, chunk=chunk,
-                                            interpret=interpret),
-                          _ssd_ref)
-    raise KeyError(f"no check case for kernel {name!r}")
+    kdef = get_kernel(name)
+    cfg = kdef.configs[0]
+    inputs, block_keys = _INPUTS[name]
+    run = functools.partial(kdef.pallas_fn, interpret=interpret,
+                            **{k: cfg[k] for k in block_keys})
+    return KernelCase(name, inputs(cfg, TILES), run, kdef.ref_fn)
 
 
 def kernel_cases(interpret: bool = False) -> List[KernelCase]:

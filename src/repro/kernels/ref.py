@@ -95,3 +95,11 @@ def ssd_chunk(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
           jnp.moveaxis(bf, 1, 0), jnp.moveaxis(cf, 1, 0))
     _, ys = jax.lax.scan(step, state0, xs)
     return jnp.moveaxis(ys, 0, 1).astype(x.dtype)
+
+
+def ssd(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array) -> jax.Array:
+    """:func:`ssd_chunk` in the Pallas ``ssd`` kernel's layout, one head
+    per row: x (BH, S, P), a (BH, S), b/c (BH, S, N) -> y (BH, S, P)."""
+    y = ssd_chunk(x[:, :, None, :], a[:, :, None], b[:, :, None, :],
+                  c[:, :, None, :])
+    return y[:, :, 0, :]

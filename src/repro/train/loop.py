@@ -26,11 +26,9 @@ from repro.ckpt import checkpoint
 from repro.configs.base import ModelConfig
 from repro.data.pipeline import make_data
 from repro.dist import sharding as shd
-from repro.models import encdec, lm
 from repro.optim import adamw as adamw_fn, linear_warmup_cosine
-from repro.train.step import (TrainState, init_ef_state,
-                              make_sharded_train_step, make_train_step,
-                              wants_ef)
+from repro.train.step import (init_train_state, make_sharded_train_step,
+                              make_train_step)
 
 
 class InjectedFailure(RuntimeError):
@@ -76,31 +74,24 @@ class Trainer:
         self.metrics_log: List[Dict] = []
         self._ckpt = checkpoint.AsyncCheckpointer()
 
-        model = encdec if cfg.family == "encdec" else lm
-        key = jax.random.PRNGKey(tcfg.seed)
-        params = model.init_model(cfg, key)
-        state = TrainState(params=params, opt_state=self.opt.init(params),
-                           step=jax.numpy.zeros((), jax.numpy.int32))
-
         # a mesh with a pipe axis >= 2 selects the shard_map pipeline step:
         # gpipe microbatches over `pipe`, compressed psum over `pod` (the
         # config opts in via pipeline_stages / compress_pod_grads — see
-        # repro.launch.train, which sizes the mesh from them)
+        # repro.launch.train, which sizes the mesh from them).  The state
+        # is born in that step's layout; error-feedback residuals ride in
+        # it so they are checkpointed (a restart must not reset them).
         self.use_pipeline = mesh is not None and shd.pipe_size(mesh) > 1
-        if self.use_pipeline and wants_ef(cfg, mesh):
-            # error-feedback residuals ride in the train state so they are
-            # checkpointed (a restart must not reset accumulated residuals);
-            # the spec tree lets them mirror TP weight shards on
-            # `model > 1` meshes
-            state = state._replace(
-                ef=init_ef_state(params, mesh,
-                                 spec_tree=model.model_spec(cfg)))
+        state = init_train_state(cfg, self.opt,
+                                 jax.random.PRNGKey(tcfg.seed), mesh)
+        # a restore on a mesh lands each leaf in the same layout
+        shardings = (None if mesh is None
+                     else jax.tree.map(lambda a: a.sharding, state))
 
         self.start_step = 0
         if tcfg.ckpt_dir and checkpoint.latest_step(tcfg.ckpt_dir) is not None:
             try:
-                state, self.start_step = checkpoint.restore(tcfg.ckpt_dir,
-                                                            state)
+                state, self.start_step = checkpoint.restore(
+                    tcfg.ckpt_dir, state, shardings=shardings)
             except (ValueError, TypeError) as e:
                 if state.ef is None:
                     # template has no ef leaves but restore still failed —
@@ -115,7 +106,8 @@ class Trainer:
                 # ef leaves): restore everything else and restart the
                 # error-feedback residuals from zero
                 bare, self.start_step = checkpoint.restore(
-                    tcfg.ckpt_dir, state._replace(ef=None))
+                    tcfg.ckpt_dir, state._replace(ef=None),
+                    shardings=shardings and shardings._replace(ef=None))
                 state = bare._replace(ef=state.ef)
                 print("[train] checkpoint carries no error-feedback "
                       "residuals; reinitialized ef to zero")

@@ -52,6 +52,57 @@ def loss_fn(params: Dict, batch: Dict, cfg: ModelConfig,
     return loss, {"loss": loss}
 
 
+def _model_of(cfg: ModelConfig):
+    return encdec if cfg.family == "encdec" else lm
+
+
+def train_state_specs(cfg: ModelConfig, mesh, *, ef: bool = False
+                      ) -> "TrainState":
+    """PartitionSpec tree of the TrainState the train step on ``mesh``
+    takes.  A ``pipe`` axis >= 2 selects the shard_map pipeline step's
+    layout (:func:`repro.dist.sharding.sharded_param_specs`, plus the
+    error-feedback residuals' specs when ``ef``); any other mesh the dist
+    layer's logical-axis layout (FSDP over ``data`` x TP over ``model``).
+    Optimizer moments mirror the parameters; counters are replicated."""
+    spec_tree = _model_of(cfg).model_spec(cfg)
+    if shd.pipe_size(mesh) > 1:
+        p_specs = shd.sharded_param_specs(spec_tree, shd.STAGE_KEYS, mesh)
+    else:
+        p_specs = shd.logical_specs(spec_tree, mesh)
+    ef_specs = (shd.sharded_ef_specs(spec_tree, shd.STAGE_KEYS, mesh)
+                if ef else None)
+    return TrainState(params=p_specs,
+                      opt_state=AdamState(step=P(), mu=p_specs, nu=p_specs),
+                      step=P(), ef=ef_specs)
+
+
+def init_train_state(cfg: ModelConfig, opt: Optimizer, key,
+                     mesh=None) -> TrainState:
+    """A fresh TrainState.  With ``mesh``, every leaf is created in place
+    under :func:`train_state_specs` -- the layout the step on ``mesh``
+    takes -- and never whole on one device first: at published widths the
+    parameters and moments alone outgrow one chip.  The pipeline step's
+    error-feedback residuals come along when its compressed pod reduction
+    is on (:func:`wants_ef`)."""
+    model = _model_of(cfg)
+
+    def init():
+        params = model.init_model(cfg, key)
+        return TrainState(params, opt.init(params), jnp.zeros((), jnp.int32))
+
+    if mesh is None:
+        return init()
+    specs = train_state_specs(cfg, mesh)
+    shardings = jax.tree.map(
+        lambda s: jax.sharding.NamedSharding(mesh, s), specs,
+        is_leaf=lambda x: isinstance(x, P))
+    state = jax.jit(init, out_shardings=shardings)()
+    if wants_ef(cfg, mesh):
+        state = state._replace(ef=init_ef_state(
+            state.params, mesh, spec_tree=model.model_spec(cfg)))
+    return state
+
+
 def make_train_step(cfg: ModelConfig, opt: Optimizer, mesh=None,
                     num_microbatches: int = 1):
     """Returns train_step(state, batch) -> (state, metrics).
@@ -66,8 +117,7 @@ def make_train_step(cfg: ModelConfig, opt: Optimizer, mesh=None,
 
     param_sh = batch_of = None
     if mesh is not None:
-        model = encdec if cfg.family == "encdec" else lm
-        param_sh = shd.param_shardings(model.model_spec(cfg), mesh)
+        param_sh = shd.param_shardings(_model_of(cfg).model_spec(cfg), mesh)
 
         def batch_of(batch):
             return jax.tree.map(
@@ -287,10 +337,8 @@ def make_sharded_train_step(cfg: ModelConfig, opt: Optimizer, mesh, *,
         return jnp.where(owns, nll, 0.0)
 
     # --- in/out specs + per-leaf reduction plan ----------------------------
-    p_specs = shd.sharded_param_specs(lm.model_spec(cfg), stage_keys, mesh)
-    opt_specs = AdamState(step=P(), mu=p_specs, nu=p_specs)
-    ef_specs = (shd.sharded_ef_specs(lm.model_spec(cfg), stage_keys, mesh)
-                if compress_pod else None)
+    state_specs = train_state_specs(cfg, mesh, ef=compress_pod)
+    p_specs = state_specs.params
 
     # per-leaf reduction plans, read straight off the specs.  Gradients are
     # *partial* over every pipeline/TP axis the leaf is NOT sharded on
@@ -375,8 +423,6 @@ def make_sharded_train_step(cfg: ModelConfig, opt: Optimizer, mesh, *,
         metrics = {"loss": loss, "grad_norm": gnorm,
                    "step": state.step + 1}
         return TrainState(params, opt_state, state.step + 1, ef), metrics
-    state_specs = TrainState(params=p_specs, opt_state=opt_specs,
-                             step=P(), ef=ef_specs)
     metric_specs = {"loss": P(), "grad_norm": P(), "step": P()}
     bspec = P(shd.dp_axes(mesh))
 

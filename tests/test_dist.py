@@ -152,6 +152,59 @@ def test_sharded_train_step_runs(subproc):
     assert "SHARDED-TRAIN-OK" in out
 
 
+# Trainer on a mesh creates its state in the step's layout: no leaf is
+# ever whole on device 0 unless the layout replicates it, device 0 holds
+# a fraction of the state, and a restore from a checkpoint lands in the
+# same layout.  The sharded run trains like the single-device one.
+_TRAINER_SHARDED_CODE = """
+import tempfile
+import jax, numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.configs import get_config
+from repro.launch.mesh import make_host_mesh
+from repro.train import TrainConfig, Trainer
+from repro.train.step import train_state_specs
+cfg = get_config("stablelm-3b", reduced=True).replace(n_layers=4)
+mesh = make_host_mesh(model=2, pipe=PIPE_N)
+ckpt = tempfile.mkdtemp()
+tcfg = TrainConfig(steps=2, seq_len=16, global_batch=8, warmup=0,
+                   ckpt_every=1, ckpt_dir=ckpt)
+
+def check_layout(state):
+    want = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                        train_state_specs(cfg, mesh),
+                        is_leaf=lambda x: isinstance(x, P))
+    for a, s in zip(jax.tree.leaves(state), jax.tree.leaves(want)):
+        assert a.sharding.is_equivalent_to(s, a.ndim), (a.sharding, s)
+    dev0 = mesh.devices.flat[0]
+    on0 = sum(sh.data.nbytes for a in jax.tree.leaves(state)
+              for sh in a.addressable_shards if sh.device == dev0)
+    total = sum(a.nbytes for a in jax.tree.leaves(state))
+    assert on0 < 0.5 * total, (on0, total)
+    return on0 / total
+
+t = Trainer(cfg, tcfg, mesh=mesh)
+assert t.use_pipeline == (PIPE_N > 1)
+share = check_layout(t.state)
+log = t.run()
+ref = Trainer(cfg, TrainConfig(steps=2, seq_len=16, global_batch=8,
+                               warmup=0)).run()
+for a, b in zip(log, ref):
+    assert abs(a["loss"] - b["loss"]) / b["loss"] < 1e-4, (a, b)
+resumed = Trainer(cfg, tcfg, mesh=mesh)
+assert resumed.start_step == 2
+check_layout(resumed.state)
+print("TRAINER-SHARDED-OK", share)
+"""
+
+
+@pytest.mark.parametrize("pipe", [1, 2])
+def test_trainer_state_is_born_sharded(subproc, pipe):
+    out = subproc(_TRAINER_SHARDED_CODE.replace("PIPE_N", str(pipe)),
+                  n_devices=4)
+    assert "TRAINER-SHARDED-OK" in out
+
+
 _HOST_MESH_PIPE_CODE = """
 import jax
 from repro.launch.mesh import make_host_mesh

@@ -93,6 +93,15 @@ def _engine_mode(args, cfg) -> None:
     print(f"[serve] engine: {eng['passes']} passes, lane utilization "
           f"{eng['lane_utilization']:.2f}, {eng['stalls']} stalls, "
           f"{eng['preemptions']} preemptions")
+
+    def per(key: str, n: int) -> float:      # ms of counter ``key`` each
+        return eng[key] / n * 1e3 if n else float("nan")
+
+    print(f"[serve] stages: queue wait "
+          f"{per('queue_wait_s', eng['admissions']):.2f} ms, prefill "
+          f"{per('prefill_s', eng['first_tokens']):.2f} ms a request; host "
+          f"{per('host_s', eng['passes']):.2f} ms, sync "
+          f"{per('sync_s', eng['passes']):.2f} ms a pass")
     if engine.paged:
         pool = report["stats"]["pool"]
         print(f"[serve] paged kv: max_active {eng['max_active']}, "

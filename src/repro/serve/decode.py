@@ -174,18 +174,20 @@ def _attn_decode(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict,
     S = cache["k"].shape[1]
     ring = window < NO_WINDOW and S <= window
     if ring:
-        k_cache = jnp.concatenate([cache["k"][:, 1:], k.astype(cache["k"].dtype)],
-                                  axis=1)
-        v_cache = jnp.concatenate([cache["v"][:, 1:], v.astype(cache["v"].dtype)],
-                                  axis=1)
+        with jax.named_scope("kv_write"):
+            k_cache = jnp.concatenate(
+                [cache["k"][:, 1:], k.astype(cache["k"].dtype)], axis=1)
+            v_cache = jnp.concatenate(
+                [cache["v"][:, 1:], v.astype(cache["v"].dtype)], axis=1)
         if ragged:
             k_positions = positions - (S - 1) + jnp.arange(S)[None]  # (B,S)
         else:
             k_positions = pos - (S - 1) + jnp.arange(S)
         mask = k_positions >= 0
     else:
-        k_cache = nn.update_cache(cache["k"], k, pos)
-        v_cache = nn.update_cache(cache["v"], v, pos)
+        with jax.named_scope("kv_write"):
+            k_cache = nn.update_cache(cache["k"], k, pos)
+            v_cache = nn.update_cache(cache["v"], v, pos)
         if ragged:
             k_positions = jnp.arange(S)[None]                        # (B,S)
             mask = (k_positions <= positions) & \
@@ -194,7 +196,8 @@ def _attn_decode(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict,
             k_positions = jnp.arange(S)
             mask = (k_positions <= pos) & (k_positions > pos - window)
 
-    o = _masked_decode_attn(q, k_cache, v_cache, mask)
+    with jax.named_scope("attend"):
+        o = _masked_decode_attn(q, k_cache, v_cache, mask)
     out = nn.out_project(p, o)
     return out, {"k": k_cache, "v": v_cache, **{kk: vv for kk, vv in
                                                 cache.items()
@@ -217,19 +220,55 @@ def _attn_decode_paged(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict,
     B = x.shape[0]
     positions = _positions(pos, B)
     q, k, v = _project_qkv(cfg, p, x, positions)
-    k_pages = paged_update_cache(cache["k"], k, block_table, pos,
-                                 write_mask=write_mask)
-    v_pages = paged_update_cache(cache["v"], v, block_table, pos,
-                                 write_mask=write_mask)
+    with jax.named_scope("kv_write"):
+        k_pages = paged_update_cache(cache["k"], k, block_table, pos,
+                                     write_mask=write_mask)
+        v_pages = paged_update_cache(cache["v"], v, block_table, pos,
+                                     write_mask=write_mask)
     width = max_seq if window >= NO_WINDOW else min(window, max_seq)
-    o = paged_decode_attention(q, k_pages, v_pages, block_table, pos,
-                               window=window, width=width)
+    with jax.named_scope("attend"):
+        o = paged_decode_attention(q, k_pages, v_pages, block_table, pos,
+                                   window=window, width=width)
     return nn.out_project(p, o), {"k": k_pages, "v": v_pages}
 
 
 # ---------------------------------------------------------------------------
 # decode step
 # ---------------------------------------------------------------------------
+#
+# Named scopes (``layer<i>/weights``, ``layer<i>/attn/kv_write``,
+# ``layer<i>/attn/attend``, ``layer<i>/mlp``, ``head``) land in the compiled
+# HLO's ``op_name`` metadata, so a device trace's ops map back to source.
+
+def _dense_layer_params(cfg: ModelConfig, params: Dict, li: int,
+                        dense_head: int) -> Dict:
+    """Layer ``li``'s slice of the stacked dense/moe layer weights."""
+    with jax.named_scope("weights"):
+        if cfg.family == "moe" and li >= dense_head:
+            return _layer_params(params["layers"], li - dense_head)
+        if cfg.family == "moe":
+            return _layer_params(params["dense_layers"], li)
+        return _layer_params(params["layers"], li)
+
+
+def _mlp_block(cfg: ModelConfig, p: Dict, x: jax.Array, routed: bool,
+               mesh) -> jax.Array:
+    """The residual MLP (routed experts in a moe layer past the dense head)."""
+    with jax.named_scope("mlp"):
+        h = _apply_norm(cfg, p["ln2"], x)
+        if cfg.family == "moe" and routed:
+            return x + nn.apply_moe(p["moe"], h, moe_config(cfg), mesh=mesh)
+        return x + _apply_mlp(cfg, p["mlp"], h)
+
+
+def _head(cfg: ModelConfig, params: Dict, x: jax.Array) -> jax.Array:
+    """Final norm and output head: (B, 1, d) -> logits (B, vocab)."""
+    with jax.named_scope("head"):
+        x = _apply_norm(cfg, params["final_norm"], x)
+        logits = (nn.unembed(params["embed"], x) if cfg.tie_embeddings
+                  else nn.apply_lm_head(params["lm_head"], x))
+        return logits[:, 0]
+
 
 def decode_step(params: Dict, caches: List, token: jax.Array, pos,
                 cfg: ModelConfig, mesh=None,
@@ -252,26 +291,18 @@ def decode_step(params: Dict, caches: List, token: jax.Array, pos,
     if cfg.family in ("dense", "moe"):
         dense_head = cfg.first_dense_layers if cfg.family == "moe" else 0
         for li in range(cfg.n_layers):
-            if cfg.family == "moe" and li >= dense_head:
-                p = _layer_params(params["layers"], li - dense_head)
-            elif cfg.family == "moe":
-                p = _layer_params(params["dense_layers"], li)
-            else:
-                p = _layer_params(params["layers"], li)
-            h = _apply_norm(cfg, p["ln1"], x)
-            if cfg.mla:
-                a, new_caches[li] = apply_mla_decode(p["attn"], h,
-                                                     caches[li], pos,
-                                                     mla_config(cfg))
-            else:
-                a, new_caches[li] = _attn_decode(cfg, p["attn"], h,
-                                                 caches[li], pos, wins[li])
-            x = x + a
-            h = _apply_norm(cfg, p["ln2"], x)
-            if cfg.family == "moe" and li >= dense_head:
-                x = x + nn.apply_moe(p["moe"], h, moe_config(cfg), mesh=mesh)
-            else:
-                x = x + _apply_mlp(cfg, p["mlp"], h)
+            with jax.named_scope(f"layer{li}"):
+                p = _dense_layer_params(cfg, params, li, dense_head)
+                with jax.named_scope("attn"):
+                    h = _apply_norm(cfg, p["ln1"], x)
+                    if cfg.mla:
+                        a, new_caches[li] = apply_mla_decode(
+                            p["attn"], h, caches[li], pos, mla_config(cfg))
+                    else:
+                        a, new_caches[li] = _attn_decode(
+                            cfg, p["attn"], h, caches[li], pos, wins[li])
+                    x = x + a
+                x = _mlp_block(cfg, p, x, li >= dense_head, mesh)
 
     elif cfg.family == "ssm":
         for li in range(cfg.n_layers):
@@ -319,10 +350,7 @@ def decode_step(params: Dict, caches: List, token: jax.Array, pos,
     else:
         raise ValueError(cfg.family)
 
-    x = _apply_norm(cfg, params["final_norm"], x)
-    logits = (nn.unembed(params["embed"], x) if cfg.tie_embeddings
-              else nn.apply_lm_head(params["lm_head"], x))
-    return logits[:, 0], new_caches
+    return _head(cfg, params, x), new_caches
 
 
 def paged_decode_step(params: Dict, caches: List, block_table: jax.Array,
@@ -350,27 +378,21 @@ def paged_decode_step(params: Dict, caches: List, block_table: jax.Array,
         wins = [int(w) for w in window_schedule(cfg)]
         dense_head = cfg.first_dense_layers if cfg.family == "moe" else 0
         for li in range(cfg.n_layers):
-            if cfg.family == "moe" and li >= dense_head:
-                p = _layer_params(params["layers"], li - dense_head)
-            elif cfg.family == "moe":
-                p = _layer_params(params["dense_layers"], li)
-            else:
-                p = _layer_params(params["layers"], li)
-            h = _apply_norm(cfg, p["ln1"], x)
-            if cfg.mla:
-                a, new_caches[li] = apply_mla_paged_decode(
-                    p["attn"], h, caches[li], block_table, pos,
-                    mla_config(cfg), width=max_seq, write_mask=write_mask)
-            else:
-                a, new_caches[li] = _attn_decode_paged(
-                    cfg, p["attn"], h, caches[li], block_table, pos,
-                    wins[li], max_seq, write_mask)
-            x = x + a
-            h = _apply_norm(cfg, p["ln2"], x)
-            if cfg.family == "moe" and li >= dense_head:
-                x = x + nn.apply_moe(p["moe"], h, moe_config(cfg), mesh=mesh)
-            else:
-                x = x + _apply_mlp(cfg, p["mlp"], h)
+            with jax.named_scope(f"layer{li}"):
+                p = _dense_layer_params(cfg, params, li, dense_head)
+                with jax.named_scope("attn"):
+                    h = _apply_norm(cfg, p["ln1"], x)
+                    if cfg.mla:
+                        a, new_caches[li] = apply_mla_paged_decode(
+                            p["attn"], h, caches[li], block_table, pos,
+                            mla_config(cfg), width=max_seq,
+                            write_mask=write_mask)
+                    else:
+                        a, new_caches[li] = _attn_decode_paged(
+                            cfg, p["attn"], h, caches[li], block_table, pos,
+                            wins[li], max_seq, write_mask)
+                    x = x + a
+                x = _mlp_block(cfg, p, x, li >= dense_head, mesh)
 
     elif cfg.family == "ssm":
         for li in range(cfg.n_layers):
@@ -403,7 +425,4 @@ def paged_decode_step(params: Dict, caches: List, block_table: jax.Array,
     else:
         raise ValueError(cfg.family)
 
-    x = _apply_norm(cfg, params["final_norm"], x)
-    logits = (nn.unembed(params["embed"], x) if cfg.tie_embeddings
-              else nn.apply_lm_head(params["lm_head"], x))
-    return logits[:, 0], new_caches
+    return _head(cfg, params, x), new_caches

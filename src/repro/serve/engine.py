@@ -21,6 +21,13 @@ request gets the next free slot.  Every engine step:
    youngest request is returned to its tenant queue with its generated
    tokens as teacher-forced resume state (recompute preemption).
 
+Each stage runs inside a host span ``repro.serve.<stage>`` (:func:`span`;
+``step`` holds ``evict``, ``admit``, ``pass`` and ``preempt``; ``pass``
+holds ``pool``, ``cow_copy``, ``assemble``, ``dispatch``, ``sync`` and
+``apply``), which a profiler trace puts on the device planes' clock.  The
+stage clocks in ``counters`` (``queue_wait_s``, ``prefill_s``, ``sync_s``,
+``host_s``) run whether or not a trace does.
+
 Kernel schedules come from the cache index via
 :func:`repro.sched.lowering.schedule_plan` (re-exported here) —
 nearest-bucket pure lookups at construction time, **zero**
@@ -61,6 +68,12 @@ from repro.serve.scheduler import (DEFAULT_TENANT, FairScheduler, Request,
 # engines in a sweep share tracing/compilation instead of re-jitting per
 # instance.
 _STEP_FNS: Dict = {}
+
+
+def span(name: str):
+    """A host span ``repro.serve.<name>`` in the profiler's trace, on the
+    device planes' clock; costs about a microsecond when no trace runs."""
+    return jax.profiler.TraceAnnotation("repro.serve." + name)
 
 
 def _cfg_key(cfg: ModelConfig) -> str:
@@ -247,13 +260,19 @@ class ServeEngine:
 
         self._active: List[SlotState] = []
         self.finished: List[Request] = []
-        self.counters = {"engine_steps": 0, "passes": 0, "lane_tokens": 0,
+        # Stage clocks (seconds): ``queue_wait_s`` sums submit (or requeue)
+        # to admission over ``admissions``; ``prefill_s`` sums admission to
+        # first token over ``first_tokens``; ``sync_s`` is the host blocked
+        # on the device for a pass's tokens, ``host_s`` the rest of step().
+        self.counters = {"passes": 0, "lane_tokens": 0,
                          "admissions": 0, "stalls": 0, "preemptions": 0,
-                         "truncations": 0, "max_active": 0,
+                         "max_active": 0,
                          "prefix_hits": 0, "cow_forks": 0,
                          "preempt_spills": 0, "resume_uploads": 0,
                          "schedule_fallbacks": sum(
-                             1 for art in self.plan.values() if art is None)}
+                             1 for art in self.plan.values() if art is None),
+                         "queue_wait_s": 0.0, "prefill_s": 0.0,
+                         "first_tokens": 0, "sync_s": 0.0, "host_s": 0.0}
 
     @classmethod
     def from_config(cls, cfg: ModelConfig, **kwargs) -> "ServeEngine":
@@ -297,25 +316,34 @@ class ServeEngine:
         while any slot is still teacher-forcing its prompt — every pass
         advances *all* eligible slots, so prompt catch-up never drops
         lane occupancy and never stalls the running decodes."""
-        self._evict()
-        self._admit()
-        self.counters["max_active"] = max(self.counters["max_active"],
-                                          len(self._active))
-        for s in self._active:
-            s.stalled = False
-        advanced = 0
-        for _ in range(1 + self.prefill_chunk):
-            n = self._pass()
-            advanced += n
-            if n == 0 or not any(s.in_prefill and not s.done
-                                 and not s.stalled for s in self._active):
-                break
-        self._evict()
-        if advanced == 0 and self._active:
-            self._preempt_youngest()
-        self.counters["engine_steps"] += 1
-        if self.debug_invariants:
-            self.pool.check()
+        t0 = time.perf_counter()
+        sync0 = self.counters["sync_s"]
+        with span("step"):
+            with span("evict"):
+                self._evict()
+            with span("admit"):
+                self._admit()
+            self.counters["max_active"] = max(self.counters["max_active"],
+                                              len(self._active))
+            for s in self._active:
+                s.stalled = False
+            advanced = 0
+            for _ in range(1 + self.prefill_chunk):
+                with span("pass"):
+                    n = self._pass()
+                advanced += n
+                if n == 0 or not any(s.in_prefill and not s.done
+                                     and not s.stalled for s in self._active):
+                    break
+            with span("evict"):
+                self._evict()
+            if advanced == 0 and self._active:
+                with span("preempt"):
+                    self._preempt_youngest()
+            if self.debug_invariants:
+                self.pool.check()
+        self.counters["host_s"] += (time.perf_counter() - t0
+                                    - (self.counters["sync_s"] - sync0))
         return advanced
 
     def run(self, max_steps: int = 1_000_000) -> List[Request]:
@@ -387,6 +415,10 @@ class ServeEngine:
                     req.id, len(req.prompt) + len(req.resume_tokens))
                 self._active.append(SlotState.admit(table.slot, req))
             fresh.append(table.slot)
+            req.admit_time = time.monotonic()
+            queued = (req.submit_time if req.queued_time is None
+                      else req.queued_time)
+            self.counters["queue_wait_s"] += req.admit_time - queued
             self.counters["admissions"] += 1
         if fresh:
             idx = np.full((self.max_batch,), self.scratch_slot, np.int32)
@@ -399,58 +431,60 @@ class ServeEngine:
     def _pass(self) -> int:
         cand: List[SlotState] = []
         forks: List = []
-        for s in self._active:
-            if s.done or s.stalled:
-                continue
-            if self.paged:
-                write = s.pos >= s.write_start
-                if self.pool.can_advance(s.request.id, s.pos, write=write):
-                    pair = self.pool.advance(s.request.id, s.pos, write=write)
-                    if pair is not None:
-                        forks.append(pair)
+        with span("pool"):
+            for s in self._active:
+                if s.done or s.stalled:
+                    continue
+                if self.paged:
+                    write = s.pos >= s.write_start
+                    if self.pool.can_advance(s.request.id, s.pos,
+                                             write=write):
+                        pair = self.pool.advance(s.request.id, s.pos,
+                                                 write=write)
+                        if pair is not None:
+                            forks.append(pair)
+                        cand.append(s)
+                    else:
+                        s.stalled = True
+                        self.counters["stalls"] += 1
+                elif self.pool.can_ensure(s.request.id, s.needs_tokens()):
+                    self.pool.ensure(s.request.id, s.needs_tokens())
                     cand.append(s)
                 else:
                     s.stalled = True
                     self.counters["stalls"] += 1
-            elif self.pool.can_ensure(s.request.id, s.needs_tokens()):
-                self.pool.ensure(s.request.id, s.needs_tokens())
-                cand.append(s)
-            else:
-                s.stalled = True
-                self.counters["stalls"] += 1
         if forks:
-            self._copy_blocks(forks)
-        if self.paged:
-            asm = assemble_paged(
-                cand, self.max_batch, self.scratch_slot,
-                self.pool.blocks_per_slot,
-                lambda s: self.pool.table(s.request.id).blocks)
-            if asm is None:
-                return 0
-            idx, table, tok, pos, wstart, stepped = asm
+            with span("cow_copy"):
+                self._copy_blocks(forks)
+        with span("assemble"):
+            if self.paged:
+                asm = assemble_paged(
+                    cand, self.max_batch, self.scratch_slot,
+                    self.pool.blocks_per_slot,
+                    lambda s: self.pool.table(s.request.id).blocks)
+            else:
+                asm = assemble(cand, self.max_batch, self.scratch_slot)
+        if asm is None:
+            return 0
+        *lanes, stepped = asm     # paged: idx, table, tok, pos, wstart
+        with span("dispatch"):
             nxt, self.caches = self._step_fn(
-                self.params, self.caches, jnp.asarray(idx),
-                jnp.asarray(table), jnp.asarray(tok), jnp.asarray(pos),
-                jnp.asarray(wstart))
-        else:
-            asm = assemble(cand, self.max_batch, self.scratch_slot)
-            if asm is None:
-                return 0
-            idx, tok, pos, stepped = asm
-            nxt, self.caches = self._step_fn(
-                self.params, self.caches, jnp.asarray(idx), jnp.asarray(tok),
-                jnp.asarray(pos))
-        nxt = np.asarray(nxt)
-        now = time.monotonic()
-        for lane, s in enumerate(stepped):
-            appended = s.apply(int(nxt[lane]), self.max_seq)
-            if appended and s.request.first_token_time is None:
-                s.request.first_token_time = now
-            if s.request.truncated:
-                self.counters["truncations"] += 1
-            if self.share_prefix:
-                self.pool.commit(s.request.id, s.tokens, s.pos,
-                                 prompt_len=s.prompt_len)
+                self.params, self.caches, *(jnp.asarray(a) for a in lanes))
+        t = time.perf_counter()
+        with span("sync"):
+            nxt = np.asarray(nxt)
+        self.counters["sync_s"] += time.perf_counter() - t
+        with span("apply"):
+            now = time.monotonic()
+            for lane, s in enumerate(stepped):
+                appended = s.apply(int(nxt[lane]), self.max_seq)
+                if appended and s.request.first_token_time is None:
+                    s.request.first_token_time = now
+                    self.counters["prefill_s"] += now - s.request.admit_time
+                    self.counters["first_tokens"] += 1
+                if self.share_prefix:
+                    self.pool.commit(s.request.id, s.tokens, s.pos,
+                                     prompt_len=s.prompt_len)
         self.counters["passes"] += 1
         self.counters["lane_tokens"] += len(stepped)
         return len(stepped)
@@ -524,7 +558,6 @@ class ServeEngine:
                 req.finish_time = time.monotonic()
                 self.scheduler.release(req, served_tokens=len(generated))
                 self.finished.append(req)
-                self.counters["truncations"] += 1
                 return
             # Copy-free preemption: spill the pages block-by-block, free
             # the device blocks, resume later by remap + upload — no
@@ -548,7 +581,6 @@ class ServeEngine:
             req.finish_time = time.monotonic()
             self.scheduler.release(req, served_tokens=len(generated))
             self.finished.append(req)
-            self.counters["truncations"] += 1
             return
         req.resume_tokens = generated
         req.preemptions += 1

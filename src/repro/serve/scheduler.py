@@ -50,6 +50,8 @@ class Request:
     submit_time: float = dataclasses.field(default_factory=time.monotonic)
     # Filled by the engine:
     output: List[int] = dataclasses.field(default_factory=list)
+    admit_time: Optional[float] = None      # the latest admission
+    queued_time: Optional[float] = None     # put back by a preemption
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     truncated: bool = False
@@ -141,7 +143,9 @@ class FairScheduler:
         return request
 
     def requeue_front(self, request: Request) -> None:
-        """Put a preempted request back at the head of its tenant queue."""
+        """Put a preempted request back at the head of its tenant queue;
+        its next wait is timed from now (``queued_time``)."""
+        request.queued_time = time.monotonic()
         self._tenants[request.tenant].queue.appendleft(request)
 
     def pending(self) -> int:

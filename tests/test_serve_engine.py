@@ -93,6 +93,42 @@ def test_preempted_requests_resume_bitexact():
     engine.pool.check()
 
 
+def test_stage_counters_and_admission_stamps():
+    """The engine's stage clocks after ``run()``: one first token per
+    request, waits and prefills never negative, host and sync time inside
+    the wall time of the ``step()`` calls, and a request queued behind a
+    full batch admitted after it was submitted.  A preempted request is
+    timed from its requeue (``queued_time``), not from its submit."""
+    import time
+
+    cfg, params = _model("qwen1.5-4b")
+    engine = ServeEngine.from_config(cfg, params=params, max_batch=2,
+                                     max_seq=MAX_SEQ, block_size=8,
+                                     kv_blocks=7, prefill_chunk=4)
+    rng = np.random.default_rng(2)
+    reqs = [engine.submit(rng.integers(0, cfg.vocab, 10).tolist(), 30)
+            for _ in range(3)]
+    wall = 0.0
+    while engine.active or engine.scheduler.pending():
+        t = time.perf_counter()
+        engine.step()
+        wall += time.perf_counter() - t
+    c = engine.counters
+    assert c["first_tokens"] == len(reqs)
+    assert c["queue_wait_s"] >= 0 and c["prefill_s"] >= 0
+    assert c["host_s"] > 0 and c["sync_s"] > 0
+    assert c["host_s"] + c["sync_s"] <= wall
+    assert "engine_steps" not in c and "truncations" not in c
+    assert reqs[2].admit_time > reqs[2].submit_time   # behind the batch
+    preempted = [r for r in reqs if r.preemptions]
+    assert preempted, "pool never pressured"
+    for r in reqs:
+        if r.preemptions:      # admit_time is the latest admission
+            assert r.admit_time >= r.queued_time > r.submit_time
+        else:
+            assert r.first_token_time >= r.admit_time >= r.submit_time
+
+
 def test_gang_admission_is_static_batching():
     """admission='gang' (the bench baseline) only admits into an idle
     engine and still produces the exact sequential streams."""

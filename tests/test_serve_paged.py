@@ -332,3 +332,72 @@ def test_paged_serve_hot_path_zero_measurements(tmp_path, stall_db,
     engine.submit([1, 2, 3, 4], 4)       # shares the admission prefix
     engine.run()
     assert calls == {"run": 0, "time": 0, "autotune": 0}
+
+
+# ---------------------------------------------------------------------------
+# the engine's own spans and named scopes
+# ---------------------------------------------------------------------------
+
+STAGES = {"step": None, "evict": "step", "admit": "step", "pass": "step",
+          "preempt": "step", "pool": "pass", "cow_copy": "pass",
+          "assemble": "pass", "dispatch": "pass", "sync": "pass",
+          "apply": "pass"}
+
+
+def _program_spans(path):
+    from jax.profiler import ProfileData
+    return [(ev.name[len("repro.serve."):], int(ev.start_ns), int(ev.end_ns))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("repro.serve.")]
+
+
+def test_profiled_step_writes_every_stage_span_nested(tmp_path):
+    """Under the profiler, ``step()`` writes a ``repro.serve.<stage>`` span
+    for each stage, each inside its parent: a second request on a live
+    prompt forks its partial page (``cow_copy``), and 7 blocks for two
+    requests growing to 6 each make the engine preempt."""
+    import glob
+
+    cfg, params = _model("qwen1.5-4b")
+    engine = ServeEngine.from_config(cfg, params=params, max_batch=2,
+                                     max_seq=24, block_size=4, kv_blocks=7,
+                                     prefill_chunk=1, paged=True)
+    prompt = list(range(1, 11))              # 2 full blocks + a partial
+    engine.generate(prompt, 2)               # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    a = engine.submit(prompt, 12)
+    while a.first_token_time is None:
+        engine.step()
+    engine.submit(prompt, 12)
+    engine.run()
+    jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = _program_spans(path)
+    assert engine.counters["cow_forks"] and engine.counters["preemptions"]
+    assert {n for n, _, _ in spans} == set(STAGES)
+    for name, s, e in spans:
+        parent = STAGES[name]
+        if parent is not None:
+            assert any(n == parent and ps <= s and e <= pe
+                       for n, ps, pe in spans), f"{name} outside {parent}"
+
+
+def test_paged_step_hlo_carries_named_scopes():
+    """The compiled paged step keeps the decode step's named scopes in its
+    ``op_name`` metadata, per layer and for the head."""
+    from repro.serve.engine import _paged_step_fns
+
+    cfg, params = _model("qwen1.5-4b")
+    engine = ServeEngine.from_config(cfg, params=params, max_batch=2,
+                                     max_seq=MAX_SEQ, block_size=8,
+                                     paged=True)
+    step = _paged_step_fns(cfg, None, MAX_SEQ)[0]
+    lane = jnp.zeros((2,), jnp.int32)
+    table = jnp.zeros((2, engine.pool.blocks_per_slot), jnp.int32)
+    text = step.lower(params, engine.caches, lane, table, lane, lane,
+                      lane).compile().as_text()
+    for scope in ("layer0/weights", "layer0/attn/kv_write",
+                  "layer0/attn/attend", "layer1/mlp", "/head/"):
+        assert scope in text, scope

@@ -282,10 +282,12 @@ def kv_pool_shardings(cfg, caches, mesh, kinds=None):
 
     **Paged** (``kinds`` = ``serve.decode.paged_cache_kinds(cfg)``, one
     entry per cache in the list): block-major page leaves
-    ``(num_blocks, block_size, ...)`` shard KV heads over the model axis
-    and NEVER shard the block or in-block position dims — every lane
-    gathers arbitrary physical blocks through its table, so a sharded
-    block dim would turn each gather into an all-to-all.  MLA latent
+    ``(num_blocks, block_size, ...)`` NEVER shard the block or in-block
+    position dims — every lane gathers arbitrary physical blocks through
+    its table, so a sharded block dim would turn each gather into an
+    all-to-all.  K/V pages ``(num_blocks, block_size, KH * D)`` shard the
+    merged trailing dim over the model axis when the KV heads divide it
+    (contiguous chunks are then whole heads), else replicate.  MLA latent
     pages replicate their trailing dim (it is the attention contraction
     — same rule as the dense path).  ``"slot"`` entries (recurrent state
     rows) keep the decode-cache rules."""
@@ -294,10 +296,10 @@ def kv_pool_shardings(cfg, caches, mesh, kinds=None):
 
     def paged_leaf_spec(x) -> P:
         shape = x.shape
-        if len(shape) == 4 and shape[2] == cfg.n_kv_heads \
-                and shape[3] == cfg.head_dim:
+        if not cfg.mla and len(shape) == 3 \
+                and shape[2] == cfg.n_kv_heads * cfg.head_dim:
             h_ax = "model" if cfg.n_kv_heads % model_size(mesh) == 0 else None
-            return P(None, None, h_ax, None)
+            return P(None, None, h_ax)
         return P(*([None] * len(shape)))
 
     return [decode_cache_shardings(cfg, c, mesh) if kind == "slot"

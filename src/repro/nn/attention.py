@@ -265,14 +265,17 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            scale: Optional[float] = None) -> jax.Array:
     """One-token attention through the block table.
 
-    q: (B, 1, H, D); k/v pages: (num_blocks, block_size, KH, D);
-    block_table: (B, blocks_per_slot) physical ids; pos: (B,) per-row
-    ragged positions.  ``window``/``width`` must be static ints (they pick
-    the gather shape — one compile per layer geometry): bounded windows
-    gather the ``width``-sized trailing window, global attention gathers
-    absolute positions ``[0, width)``.  The gathered operands — and hence
-    the outputs — are bit-identical to the dense slot path's whenever
+    q: (B, 1, H, D); k/v pages: (num_blocks, block_size, KH * D), every
+    KV head of a position side by side in one row, split into
+    (B, S, KH, D) only after the gather; block_table: (B, blocks_per_slot)
+    physical ids; pos: (B,) per-row ragged positions.
+    ``window``/``width`` must be static ints (they pick the gather shape —
+    one compile per layer geometry): bounded windows gather the
+    ``width``-sized trailing window, global attention gathers absolute
+    positions ``[0, width)``.  The gathered operands — and hence the
+    outputs — are bit-identical to the dense slot path's whenever
     ``width`` matches the dense layer's cache length."""
+    B, _, _, D = q.shape
     pos = jnp.asarray(pos, jnp.int32)
     positions = pos[:, None]
     if window < NO_WINDOW and width is not None and width <= window:
@@ -287,4 +290,6 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         v_g = gather_pages(v_pages, block_table, S)
         k_positions = jnp.arange(S)[None]
         mask = (k_positions <= positions) & (k_positions > positions - window)
-    return masked_decode_attention(q, k_g, v_g, mask, scale=scale)
+    return masked_decode_attention(q, k_g.reshape(B, S, -1, D),
+                                   v_g.reshape(B, S, -1, D), mask,
+                                   scale=scale)

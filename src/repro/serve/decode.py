@@ -101,7 +101,11 @@ def paged_cache_kinds(cfg: ModelConfig) -> List[str]:
 
 def _paged_attn_cache(cfg: ModelConfig, num_blocks: int,
                       block_size: int) -> Dict:
-    shape = (num_blocks, block_size, cfg.n_kv_heads, cfg.head_dim)
+    # Each page row holds every KV head side by side, (KH * D): the page
+    # write, the gather and the step's boundary then share one layout, so
+    # the compiled step updates the pages in place instead of re-laying
+    # them out (the heads are split off only after the gather).
+    shape = (num_blocks, block_size, cfg.n_kv_heads * cfg.head_dim)
     return {"k": jnp.zeros(shape, _cache_dtype(cfg)),
             "v": jnp.zeros(shape, _cache_dtype(cfg))}
 
@@ -214,17 +218,17 @@ def _attn_decode_paged(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict,
                        window: int, max_seq: int,
                        write_mask: jax.Array) -> Tuple[jax.Array, Dict]:
     """The paged analogue of :func:`_attn_decode`: scatter this token's K/V
-    into the physical pages through the block table, then attend over a
-    gather whose width matches the dense layer's cache length — so the
-    outputs are bit-identical to the slot path's."""
+    into the physical pages (rows of ``KH * D``) through the block table,
+    then attend over a gather whose width matches the dense layer's cache
+    length — so the outputs are bit-identical to the slot path's."""
     B = x.shape[0]
     positions = _positions(pos, B)
     q, k, v = _project_qkv(cfg, p, x, positions)
     with jax.named_scope("kv_write"):
-        k_pages = paged_update_cache(cache["k"], k, block_table, pos,
-                                     write_mask=write_mask)
-        v_pages = paged_update_cache(cache["v"], v, block_table, pos,
-                                     write_mask=write_mask)
+        k_pages = paged_update_cache(cache["k"], k.reshape(B, 1, -1),
+                                     block_table, pos, write_mask=write_mask)
+        v_pages = paged_update_cache(cache["v"], v.reshape(B, 1, -1),
+                                     block_table, pos, write_mask=write_mask)
     width = max_seq if window >= NO_WINDOW else min(window, max_seq)
     with jax.named_scope("attend"):
         o = paged_decode_attention(q, k_pages, v_pages, block_table, pos,

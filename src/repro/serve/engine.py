@@ -113,7 +113,11 @@ def _paged_step_fns(cfg: ModelConfig, mesh, max_seq: int):
     """(step, reset) for the paged path.  ``"paged"`` cache entries pass
     through whole (lanes address them via the block table); ``"slot"``
     entries (recurrent state) gather/scatter by slot exactly as the dense
-    path — with idle lanes dropped by the same out-of-range trick."""
+    path — with idle lanes dropped by the same out-of-range trick.
+
+    ``step`` donates the caches (argument 1): the pages are written in
+    place, and the caller must rebind its cache list to the returned one,
+    as :meth:`ServeEngine._pass` does."""
     key = (_cfg_key(cfg), None if mesh is None else id(mesh),
            "paged", int(max_seq))
     if key not in _STEP_FNS:
@@ -138,14 +142,23 @@ def _paged_step_fns(cfg: ModelConfig, mesh, max_seq: int):
                     out.append(n)
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), out
 
+        slots = [i for i, kind in enumerate(kinds) if kind == "slot"]
+        zero_rows = jax.jit(
+            lambda rows, idx: jax.tree.map(lambda a: a.at[idx].set(0), rows),
+            donate_argnums=(0,))
+
         def reset(caches, idx):
             # Only recurrent slot rows need zeroing on admission; page
-            # contents are never read unmasked before being written.
-            return [jax.tree.map(lambda a: a.at[idx].set(0), c)
-                    if kind == "slot" else c
+            # contents are never read unmasked before being written, so
+            # the pages stay outside the jit: an identity output would
+            # copy the whole pool.
+            if not slots:
+                return caches
+            rows = iter(zero_rows([caches[i] for i in slots], idx))
+            return [next(rows) if kind == "slot" else c
                     for c, kind in zip(caches, kinds)]
 
-        _STEP_FNS[key] = (jax.jit(step), jax.jit(reset))
+        _STEP_FNS[key] = (jax.jit(step, donate_argnums=(1,)), reset)
     return _STEP_FNS[key]
 
 

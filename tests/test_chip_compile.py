@@ -12,6 +12,7 @@ workers each import every test file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -59,21 +60,53 @@ def test_kernel_compiles_for_v5e(one_chip, name):
         f"{name} did not lower to a Mosaic kernel"
 
 
+def _compile_paged_step(sharding, cfg, max_batch, max_seq, block, blocks):
+    """The engine's paged step compiled for ``sharding``'s device, and its
+    abstract caches."""
+    step, _ = _paged_step_fns(cfg, None, max_seq)
+    params = _on(sharding, jax.eval_shape(
+        lambda: lm.init_model(cfg, jax.random.PRNGKey(0))))
+    caches = _on(sharding, jax.eval_shape(
+        lambda: init_paged_caches(cfg, blocks, block, max_batch)))
+    lane = jax.ShapeDtypeStruct((max_batch,), jnp.int32, sharding=sharding)
+    table = jax.ShapeDtypeStruct((max_batch, -(-max_seq // block)),
+                                 jnp.int32, sharding=sharding)
+    compiled = step.lower(params, caches, lane, table, lane, lane,
+                          lane).compile()
+    return compiled, caches
+
+
 def test_paged_decode_step_compiles_at_qwen_widths(one_chip):
     cfg = get_config("qwen1.5-4b").replace(n_layers=2)
     max_batch, max_seq, block = 8, 512, 16
-    step, _ = _paged_step_fns(cfg, None, max_seq)
-    params = _on(one_chip, jax.eval_shape(
-        lambda: lm.init_model(cfg, jax.random.PRNGKey(0))))
-    caches = _on(one_chip, jax.eval_shape(
-        lambda: init_paged_caches(cfg, max_batch * max_seq // block, block,
-                                  max_batch)))
-    lane = jax.ShapeDtypeStruct((max_batch,), jnp.int32, sharding=one_chip)
-    table = jax.ShapeDtypeStruct((max_batch, max_seq // block), jnp.int32,
-                                 sharding=one_chip)
-    compiled = step.lower(params, caches, lane, table, lane, lane,
-                          lane).compile()
+    compiled, _ = _compile_paged_step(one_chip, cfg, max_batch, max_seq,
+                                      block, max_batch * max_seq // block)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert 0 < total < V5E_HBM_BYTES
+
+
+_COPY = re.compile(r"^\s*%?\S+ = \w+\[([\d,]*)\]\S* copy\(", re.M)
+
+
+def test_paged_decode_step_updates_pages_in_place(one_chip):
+    """At the benchmark cell's geometry (10 lanes, 320 blocks of 16) the
+    step neither re-lays out a page array (no ``copy`` whose result has a
+    page array's shape, whole or flattened) nor writes a new one: the
+    cache outputs alias the donated inputs."""
+    cfg = get_config("qwen1.5-4b").replace(n_layers=2)
+    max_batch, max_seq, block, blocks = 10, 512, 16, 320
+    compiled, caches = _compile_paged_step(one_chip, cfg, max_batch,
+                                           max_seq, block, blocks)
+    kh, d = cfg.n_kv_heads, cfg.head_dim
+    page_forms = {(blocks, block, kh * d), (blocks * block, kh * d),
+                  (blocks, block, kh, d), (blocks * block, kh, d)}
+    text = compiled.as_text()
+    copies = [tuple(int(n) for n in dims.split(",") if n)
+              for dims in _COPY.findall(text)]
+    assert copies, "no copy found: the pattern no longer reads the HLO"
+    assert not [c for c in copies if c in page_forms], copies
+    page_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(caches))
+    assert compiled.memory_analysis().alias_size_in_bytes >= page_bytes

@@ -152,6 +152,45 @@ def test_sharded_train_step_runs(subproc):
     assert "SHARDED-TRAIN-OK" in out
 
 
+# The paged engine on a (data=2, model=2) mesh: the K/V pages keep their
+# head sharding through donated steps, copy-on-write forks, a spill and
+# its resume, and every stream equals the mesh-less engine's.
+_PAGED_MESH_CODE = """
+import jax
+from jax.sharding import PartitionSpec as P
+from repro.configs import get_config
+from repro.launch.mesh import make_host_mesh
+from repro.models import lm
+from repro.serve import ServeEngine
+cfg = get_config("qwen1.5-4b", reduced=True)
+params = lm.init_model(cfg, jax.random.PRNGKey(0))
+prompt = list(range(1, 11))
+outs = []
+for mesh in (None, make_host_mesh(model=2)):
+    e = ServeEngine.from_config(cfg, params=params, max_batch=2, max_seq=24,
+                                block_size=4, kv_blocks=7, prefill_chunk=1,
+                                paged=True, mesh=mesh)
+    placed = jax.tree.leaves(e.caches)
+    a = e.submit(prompt, 12)
+    while a.first_token_time is None:
+        e.step()
+    e.submit(prompt, 12)
+    e.submit([5, 6, 7], 6)
+    e.run()
+    assert e.counters["cow_forks"] and e.counters["preempt_spills"]
+    outs.append([r.output for r in sorted(e.finished, key=lambda r: r.id)])
+assert all(leaf.sharding.spec == P(None, None, "model")
+           for leaf in placed + jax.tree.leaves(e.caches))
+assert outs[0] == outs[1]
+print("PAGED-MESH-OK")
+"""
+
+
+def test_paged_engine_on_mesh_keeps_head_sharded_pages(subproc):
+    out = subproc(_PAGED_MESH_CODE, n_devices=4)
+    assert "PAGED-MESH-OK" in out
+
+
 # Trainer on a mesh creates its state in the step's layout: no leaf is
 # ever whole on device 0 unless the layout replicates it, device 0 holds
 # a fraction of the state, and a restore from a checkpoint lands in the

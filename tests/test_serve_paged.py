@@ -42,14 +42,16 @@ def _ref_one(params, cfg, prompt, n, max_seq=MAX_SEQ):
 # paged == dense == generate(), across cache families
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("arch", ["qwen1.5-4b", "gemma3-1b", "mamba2-1.3b"])
+@pytest.mark.parametrize("arch", ["qwen1.5-4b", "gemma3-1b", "mamba2-1.3b",
+                                  "zamba2-2.7b"])
 def test_paged_bitexact_vs_sequential_generate(arch):
     """The block-table indirection must not change a single logit:
     per-request streams under paged continuous batching (staggered
     arrivals, slot churn) match the one-request-at-a-time static path.
     Covers absolute caches (qwen), ring-buffer windows re-expressed as
-    trailing page windows (gemma), and slot-major recurrent state riding
-    next to paged attention leaves (mamba)."""
+    trailing page windows (gemma), slot-major recurrent state (mamba),
+    and both riding together in the hybrid's shared attention block
+    (zamba)."""
     cfg, params = _model(arch)
     engine = ServeEngine.from_config(cfg, params=params, max_batch=3,
                                      max_seq=MAX_SEQ, block_size=8,
@@ -116,6 +118,44 @@ def test_spill_preemption_resumes_bitexact_without_recompute(arch):
         assert list(req.prompt) + list(req.output) == ref
         assert not req.truncated
         assert not req.resume_tokens, "spill resume must not recompute"
+
+
+def test_donated_pages_serve_as_the_slot_engine_through_cow_and_spill():
+    """The paged step donates its pages and writes them in place: the
+    engine's previous page arrays are consumed by each pass, and with
+    copy-on-write forks, spills and resumes in between (eager updates of
+    the rebound pages) every stream equals the slot engine's."""
+    cfg, params = _model("qwen1.5-4b")
+    prompt = list(range(1, 11))              # 2 full blocks + a partial
+    kw = dict(params=params, max_batch=2, max_seq=24, block_size=4,
+              prefill_chunk=1)
+    slot = ServeEngine.from_config(cfg, **kw)
+    engine = ServeEngine.from_config(cfg, kv_blocks=7, paged=True,
+                                     debug_invariants=True, **kw)
+    step, donated = engine._step_fn, []
+
+    def checked(params, caches, *lanes):
+        out = step(params, caches, *lanes)
+        donated.append(all(x.is_deleted() for x in jax.tree.leaves(caches)))
+        return out
+
+    engine._step_fn = checked
+    outs = []
+    for eng in (slot, engine):
+        a = eng.submit(prompt, 12)
+        while a.first_token_time is None:
+            eng.step()
+        eng.submit(prompt, 12)
+        eng.run()
+        outs.append([r.output for r in sorted(eng.finished,
+                                              key=lambda r: r.id)])
+    assert donated and all(donated), "the paged step must donate its pages"
+    assert engine.counters["cow_forks"] > 0
+    assert engine.counters["preempt_spills"] > 0
+    assert engine.counters["resume_uploads"] > 0
+    assert outs[1] == outs[0]
+    assert outs[1][0] == _ref_one(params, cfg, prompt, 12, max_seq=24)
+    engine.pool.check()
 
 
 # ---------------------------------------------------------------------------

@@ -6,6 +6,7 @@ are covered without the 8-device subprocess harness of test_dist.py.
 """
 
 import jax
+import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.dist import sharding as shd
@@ -225,3 +226,39 @@ def test_decode_cache_shardings_covers_all_families():
         caches = jax.eval_shape(lambda: init_caches(cfg, 2, 64))
         sh = shd.decode_cache_shardings(cfg, caches, mesh)
         assert jax.tree.structure(sh) == jax.tree.structure(caches)
+
+
+# ---------------------------------------------------------------------------
+# kv_pool_shardings: paged K/V pages (num_blocks, block_size, KH * D)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("arch,kv_heads,model,head_ax", [
+    ("qwen1.5-4b", 4, 2, "model"),       # 2 whole heads per chunk
+    ("qwen1.5-4b", 4, 4, "model"),
+    ("qwen1.5-4b", 3, 2, None),          # heads do not divide: replicate
+    ("gemma3-1b", 1, 2, None),           # MQA
+    ("zamba2-2.7b", 4, 2, "model"),      # hybrid: shared-attention pages
+])
+def test_kv_pool_shardings_merged_page_heads(arch, kv_heads, model, head_ax):
+    from repro.configs import get_config
+    from repro.serve.decode import init_paged_caches, paged_cache_kinds
+    cfg = get_config(arch, reduced=True).replace(n_kv_heads=kv_heads)
+    caches = jax.eval_shape(lambda: init_paged_caches(cfg, 12, 4, 2))
+    kinds = paged_cache_kinds(cfg)
+    sh = shd.kv_pool_shardings(cfg, caches, _mesh((2, model),
+                                                  ("data", "model")), kinds)
+    assert jax.tree.structure(sh) == jax.tree.structure(caches)
+    pages = [s for c, kind in zip(sh, kinds) if kind == "paged"
+             for s in jax.tree.leaves(c)]
+    assert pages
+    assert all(s.spec == P(None, None, head_ax) for s in pages)
+
+
+def test_kv_pool_shardings_mla_latent_pages_replicate():
+    from repro.configs import get_config
+    from repro.serve.decode import init_paged_caches, paged_cache_kinds
+    cfg = get_config("deepseek-v2-lite-16b", reduced=True)
+    caches = jax.eval_shape(lambda: init_paged_caches(cfg, 12, 4, 2))
+    sh = shd.kv_pool_shardings(cfg, caches, _mesh((1, 2), ("data", "model")),
+                               paged_cache_kinds(cfg))
+    assert all(s.spec == P(None, None, None) for s in jax.tree.leaves(sh))

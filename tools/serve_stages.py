@@ -16,7 +16,9 @@ shape, and per op instance its events, seconds and bytes (written to
 
 ``hlo`` compiles the paged step at the cell's geometry, writes its
 optimized HLO to ``chiprun_out/step-<cell>.hlo.txt`` and lists its copies by
-named scope (``repro.serve.decode``), with their bytes; given a traced
+named scope (``repro.serve.decode``), with their bytes (``copy`` apart from
+the asynchronous ``copy-start``/``copy-done`` transfers), and the compiler's
+argument, output, temporary and aliased bytes; given a traced
 ``run``'s stages file, it joins the traced copies to them, per pass.
 
 ``span-cost`` times an inactive ``repro.serve`` span and a clock read; with
@@ -258,6 +260,30 @@ def scope(op_name: str) -> str:
     return re.sub(r"\[\d+\]", "[*]", text) or "(none)"
 
 
+def count_copies(instrs) -> dict:
+    """Copies among ``entry_instructions``: ``copy`` (a new buffer in
+    another layout or place, on the compute units) apart from the
+    asynchronous transfers between memory spaces (``copy-start`` and its
+    ``copy-done``, whose result is the data moved), and all of them by
+    opcode, named scope and result."""
+    static = defaultdict(lambda: [0, 0])
+    for name, op, op_name, nbytes, result in instrs:
+        if op.startswith("copy"):
+            k = f"{op} {scope(op_name)} {result}"
+            static[k][0] += 1
+            static[k][1] += nbytes or 0
+
+    def total(opcode, i):
+        return sum(v[i] for k, v in static.items()
+                   if k.split(" ", 1)[0] == opcode)
+    return {"copies_in_step": total("copy", 0),
+            "copy_bytes_in_step": total("copy", 1),
+            "async_copies_in_step": total("copy-start", 0),
+            "async_copy_bytes_in_step": total("copy-done", 1),
+            "copies_by_scope": dict(sorted(static.items(),
+                                           key=lambda kv: -kv[1][1]))}
+
+
 def step_shapes(found):
     """Abstract arguments of the paged step at the cell's geometry."""
     import functools
@@ -297,20 +323,15 @@ def cmd_hlo(argv) -> int:
     pcfg, geo, shapes = step_shapes(found)
     step = _paged_step_fns(pcfg, None, geo["max_seq"])[0]
     t = time.monotonic()
-    text = step.lower(*shapes).compile().as_text()
+    compiled = step.lower(*shapes).compile()
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
     print(f"[hlo] compiled in {time.monotonic() - t:.1f}s; "
           f"{_dump(f'step-{args.workload}.hlo.txt', text)}", flush=True)
     instrs = entry_instructions(text)
-    static = defaultdict(lambda: [0, 0])
-    for name, op, op_name, nbytes, result in instrs:
-        if op.startswith("copy"):
-            k = f"{op} {scope(op_name)} {result}"
-            static[k][0] += 1
-            static[k][1] += nbytes or 0
-    out = {"copies_in_step": sum(v[0] for v in static.values()),
-           "copy_bytes_in_step": sum(v[1] for v in static.values()),
-           "copies_by_scope": dict(sorted(static.items(),
-                                          key=lambda kv: -kv[1][1]))}
+    out = count_copies(instrs)
+    out["memory_bytes"] = {k: getattr(mem, f"{k}_size_in_bytes")
+                           for k in ("argument", "output", "temp", "alias")}
     if args.stages:
         with open(args.stages) as f:
             run = json.load(f)

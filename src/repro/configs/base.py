@@ -37,6 +37,7 @@ class ModelConfig:
     qkv_bias: bool = False
     qk_norm: bool = False
     rope_theta: float = 10_000.0
+    rope_fraction: float = 1.0         # share of each head's dims rotated
     window: Optional[int] = None       # sliding-window size for local layers
     global_every: int = 0              # every Nth layer is global (gemma 5:1 -> 6)
 
@@ -113,6 +114,11 @@ class ModelConfig:
     @property
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
+
+    @property
+    def rope_dims(self) -> int:
+        """Leading dims of each head that rotary embedding rotates."""
+        return int(self.head_dim * self.rope_fraction)
 
     @property
     def ssm_heads(self) -> int:

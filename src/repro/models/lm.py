@@ -7,6 +7,7 @@ block once (the MaxText pattern — essential for the 512-device dry-run).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Dict, Optional
 
 import jax
@@ -171,8 +172,8 @@ def apply_attn(cfg: ModelConfig, p: Dict, x: jax.Array, *,
         q = nn.apply_rmsnorm(p["q_norm"], q)
         k = nn.apply_rmsnorm(p["k_norm"], k)
     positions = q_offset + jnp.arange(S)
-    q = nn.apply_rope(q, positions[None, :], cfg.rope_theta)
-    k = nn.apply_rope(k, positions[None, :], cfg.rope_theta)
+    q = nn.apply_rope(q, positions[None, :], cfg.rope_theta, cfg.rope_dims)
+    k = nn.apply_rope(k, positions[None, :], cfg.rope_theta, cfg.rope_dims)
     o = nn.chunked_attention(q, k, v, causal=causal, window=window,
                              chunk=cfg.attn_chunk, q_offset=q_offset)
     return nn.out_project(p, o, tp_axis=tp_axis)
@@ -253,18 +254,34 @@ def embed_forward(params: Dict, tokens: jax.Array,
 # full forward (train / prefill)
 # ---------------------------------------------------------------------------
 
-def _sp_constraint(cfg: ModelConfig, x: jax.Array, mesh):
-    """Sequence-parallel residual stream: between blocks, activations live
-    sequence-sharded on the model axis so norms/router/elementwise work is
-    1/TP of the replicated cost and the TP collectives become
-    all-gather/reduce-scatter pairs (Megatron-SP)."""
-    if not (cfg.seq_parallel and mesh is not None
-            and "model" in mesh.axis_names):
+def _stream_layout(cfg: ModelConfig, x: jax.Array, mesh):
+    """The residual stream's layout between blocks: rows split over the
+    data axes, so that each data shard runs its own rows through every
+    layer and the FSDP weights are gathered for it (left free, the
+    partitioner may instead keep every row on every chip, split the
+    hidden dim over ``data`` and all-reduce the activations of each
+    matmul and norm).  With ``seq_parallel`` the sequence is also split
+    over the model axis so norms/router/elementwise work is 1/TP of the
+    replicated cost and the TP collectives become
+    all-gather/reduce-scatter pairs (Megatron-SP).  Rows that do not
+    divide the data axes (a microbatch under accumulation, say) are left
+    to the partitioner, with a note when the step is traced."""
+    if mesh is None:
         return x
     from jax.sharding import PartitionSpec as P
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+    if cfg.seq_parallel and "model" in mesh.axis_names:
+        return jax.lax.with_sharding_constraint(
+            x, jax.sharding.NamedSharding(mesh, P(dp, "model", None)))
+    n = math.prod(mesh.shape[a] for a in dp)
+    if not dp or x.shape[0] % n:
+        if dp:
+            print(f"[model] {x.shape[0]} rows do not divide the data axes "
+                  f"({n}): the residual stream's layout is left to the "
+                  f"partitioner")
+        return x
     return jax.lax.with_sharding_constraint(
-        x, jax.sharding.NamedSharding(mesh, P(dp, "model", None)))
+        x, jax.sharding.NamedSharding(mesh, P(dp, None, None)))
 
 
 def _scan_layers(cfg: ModelConfig, block, stacked: Dict, x: jax.Array,
@@ -275,7 +292,7 @@ def _scan_layers(cfg: ModelConfig, block, stacked: Dict, x: jax.Array,
         body = functools.partial(body, tp_axis=tp_axis)  # it when in use
 
     def scan_fn(carry, xs):
-        carry = _sp_constraint(cfg, carry, mesh)
+        carry = _stream_layout(cfg, carry, mesh)
         if windows is not None:
             layer_p, win = xs
             out = body(layer_p, carry, window=win)

@@ -16,6 +16,7 @@ local:global stacks inside a scan over layers.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional
 
 import jax
@@ -73,6 +74,8 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     Online softmax over KV chunks (flash-attention recurrence in XLA ops —
     the Pallas kernel version of the same math lives in repro.kernels).
+    The backward recomputes each chunk's probabilities rather than storing
+    them, so its memory is O(Sq·chunk) for one chunk at a time.
     """
     B, Sq, H, D = q.shape
     Sk, KH = k.shape[1], k.shape[2]
@@ -90,6 +93,12 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kc = jnp.moveaxis(k.reshape(B, n_chunks, chunk, KH, D), 1, 0)
     vc = jnp.moveaxis(v.reshape(B, n_chunks, chunk, KH, Dv), 1, 0)
 
+    # Under autodiff the chunk body is recomputed in the backward from
+    # (q, the chunk's k and v, the carried running max and sum): only the
+    # small (m, l, acc) carry is saved per chunk, never the chunk's
+    # (Sq x chunk) float32 logits and probabilities.  The forward's
+    # arithmetic is the same either way.
+    @functools.partial(jax.checkpoint, prevent_cse=False)
     def body(carry, xs):
         m, ell, acc = carry
         kb, vb, cidx = xs

@@ -158,8 +158,8 @@ def _project_qkv(cfg: ModelConfig, p: Dict, x: jax.Array,
     if cfg.qk_norm:
         q = nn.apply_rmsnorm(p["q_norm"], q)
         k = nn.apply_rmsnorm(p["k_norm"], k)
-    q = nn.apply_rope(q, positions, cfg.rope_theta)
-    k = nn.apply_rope(k, positions, cfg.rope_theta)
+    q = nn.apply_rope(q, positions, cfg.rope_theta, cfg.rope_dims)
+    k = nn.apply_rope(k, positions, cfg.rope_theta, cfg.rope_dims)
     return q, k, v
 
 

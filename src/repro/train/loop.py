@@ -9,6 +9,13 @@ fault-tolerance contract the brief requires:
     fires when a step exceeds ``straggler_factor``× the running median —
     on synchronous SPMD the mitigation is checkpoint + elastic remesh,
     and the elastic path is restore(shardings=new_mesh) (tested).
+
+Each :meth:`Trainer.step` runs inside a host span ``repro.train.step``
+with ``data`` (the host makes the batch), ``put`` (the batch placed in the
+step's layout), ``dispatch`` (the jitted call) and ``sync`` (the host
+blocks on the step's metrics) inside it, and adds to ``Trainer.counters``:
+``steps``, ``tokens``, ``host_s`` (the step less its ``sync``), ``data_s``
+and ``sync_s``.
 """
 
 from __future__ import annotations
@@ -21,18 +28,25 @@ from typing import Callable, Dict, List, Optional
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding
 
 from repro.ckpt import checkpoint
 from repro.configs.base import ModelConfig
 from repro.data.pipeline import make_data
 from repro.dist import sharding as shd
 from repro.optim import adamw as adamw_fn, linear_warmup_cosine
-from repro.train.step import (init_train_state, make_sharded_train_step,
-                              make_train_step)
+from repro.train.step import (decay_mask, init_train_state,
+                              make_sharded_train_step, make_train_step)
 
 
 class InjectedFailure(RuntimeError):
     pass
+
+
+def span(name: str):
+    """A host span ``repro.train.<name>`` in the profiler's trace; costs
+    about a microsecond when no trace runs."""
+    return jax.profiler.TraceAnnotation("repro.train." + name)
 
 
 def pipeline_microbatch_clamp(n_micro: int, global_batch: int, mesh):
@@ -62,17 +76,24 @@ class TrainConfig:
 
 
 class Trainer:
+    """``params`` (optional) are the initial parameters, in the model's
+    tree, placed in the step's layout; by default they are drawn from
+    ``tcfg.seed``.  A checkpoint in ``tcfg.ckpt_dir`` wins over both."""
+
     def __init__(self, cfg: ModelConfig, tcfg: TrainConfig, mesh=None,
-                 straggler_hook: Optional[Callable[[int, float], None]] = None):
+                 straggler_hook: Optional[Callable[[int, float], None]] = None,
+                 params: Optional[Dict] = None):
         self.cfg, self.tcfg, self.mesh = cfg, tcfg, mesh
         self.data = make_data(cfg, tcfg.seq_len, tcfg.global_batch, tcfg.seed)
         sched = linear_warmup_cosine(tcfg.lr, tcfg.warmup, tcfg.steps)
         self.opt = adamw_fn(sched, weight_decay=tcfg.weight_decay,
-                               max_grad_norm=1.0)
+                            max_grad_norm=1.0, mask=decay_mask(cfg))
         self.straggler_hook = straggler_hook
         self.step_times: List[float] = []
         self.metrics_log: List[Dict] = []
         self._ckpt = checkpoint.AsyncCheckpointer()
+        self.counters = {"steps": 0, "tokens": 0, "host_s": 0.0,
+                         "data_s": 0.0, "sync_s": 0.0}
 
         # a mesh with a pipe axis >= 2 selects the shard_map pipeline step:
         # gpipe microbatches over `pipe`, compressed psum over `pod` (the
@@ -81,8 +102,8 @@ class Trainer:
         # is born in that step's layout; error-feedback residuals ride in
         # it so they are checkpointed (a restart must not reset them).
         self.use_pipeline = mesh is not None and shd.pipe_size(mesh) > 1
-        state = init_train_state(cfg, self.opt,
-                                 jax.random.PRNGKey(tcfg.seed), mesh)
+        key = None if params is not None else jax.random.PRNGKey(tcfg.seed)
+        state = init_train_state(cfg, self.opt, key, mesh, params=params)
         # a restore on a mesh lands each leaf in the same layout
         shardings = (None if mesh is None
                      else jax.tree.map(lambda a: a.sharding, state))
@@ -113,6 +134,7 @@ class Trainer:
                       "residuals; reinitialized ef to zero")
             state = jax.tree.map(jax.numpy.asarray, state)
         self.state = state
+        self.next_step = self.start_step
 
         if self.use_pipeline:
             if tcfg.num_microbatches > 1:
@@ -139,6 +161,10 @@ class Trainer:
             step_fn = make_train_step(cfg, self.opt, mesh=mesh,
                                       num_microbatches=tcfg.num_microbatches)
         self.train_step = jax.jit(step_fn, donate_argnums=0)
+        self._batch_sharding = None
+        if mesh is not None and not self.use_pipeline:
+            self._batch_sharding = lambda x: NamedSharding(
+                mesh, shd.batch_spec(mesh, x.shape[0], ndim=x.ndim))
 
     def run(self) -> List[Dict]:
         t = self.tcfg
@@ -153,26 +179,57 @@ class Trainer:
             if t.ckpt_dir:
                 self._ckpt.wait()
 
+    def _put(self, batch: Dict[str, np.ndarray]) -> Dict:
+        if self._batch_sharding is None:
+            return jax.device_put(batch)
+        return {k: jax.device_put(v, self._batch_sharding(v))
+                for k, v in batch.items()}
+
+    def step(self) -> Dict:
+        """Run step ``next_step``: its batch, the jitted step, its metrics
+        (as floats, with ``step`` and ``seconds``), a checkpoint when one is
+        due.  The host blocks until the step's metrics are back."""
+        t = self.tcfg
+        step = self.next_step
+        if t.fail_at_step is not None and step == t.fail_at_step:
+            raise InjectedFailure(f"injected failure at step {step}")
+        t0 = time.perf_counter()
+        with span("step"):
+            with span("data"):
+                batch = self.data.batch_at(step)
+            t1 = time.perf_counter()
+            with span("put"):
+                placed = self._put(batch)
+            with span("dispatch"):
+                self.state, metrics = self.train_step(self.state, placed)
+            t2 = time.perf_counter()
+            with span("sync"):
+                metrics = {k: float(np.asarray(v)) for k, v in metrics.items()}
+        t3 = time.perf_counter()
+        dt = t3 - t0
+        c = self.counters
+        c["steps"] += 1
+        c["tokens"] += int(batch["tokens"].size)
+        c["data_s"] += t1 - t0
+        c["sync_s"] += t3 - t2
+        c["host_s"] += dt - (t3 - t2)
+        self.step_times.append(dt)
+        if len(self.step_times) >= 5:
+            med = statistics.median(self.step_times[-50:])
+            if dt > t.straggler_factor * med \
+                    and self.straggler_hook is not None:
+                self.straggler_hook(step, dt)
+        metrics.update(step=step, seconds=dt)
+        self.metrics_log.append(metrics)
+        self.next_step = step + 1
+        if t.ckpt_dir and self.next_step % t.ckpt_every == 0:
+            self._ckpt.save(t.ckpt_dir, self.next_step, self.state)
+        return metrics
+
     def _run(self) -> List[Dict]:
         t = self.tcfg
-        for step in range(self.start_step, t.steps):
-            if t.fail_at_step is not None and step == t.fail_at_step:
-                raise InjectedFailure(f"injected failure at step {step}")
-            batch = self.data.batch_at(step)
-            t0 = time.time()
-            self.state, metrics = self.train_step(self.state, batch)
-            metrics = {k: float(np.asarray(v)) for k, v in metrics.items()}
-            dt = time.time() - t0
-            self.step_times.append(dt)
-            if len(self.step_times) >= 5:
-                med = statistics.median(self.step_times[-50:])
-                if dt > self.tcfg.straggler_factor * med \
-                        and self.straggler_hook is not None:
-                    self.straggler_hook(step, dt)
-            metrics.update(step=step, seconds=dt)
-            self.metrics_log.append(metrics)
-            if t.ckpt_dir and (step + 1) % t.ckpt_every == 0:
-                self._ckpt.save(t.ckpt_dir, step + 1, self.state)
+        while self.next_step < t.steps:
+            self.step()
         if t.ckpt_dir:
             self._ckpt.wait()
             checkpoint.save(t.ckpt_dir, t.steps, self.state)

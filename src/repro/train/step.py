@@ -19,6 +19,7 @@ from repro.dist import compress
 from repro.dist import sharding as shd
 from repro.dist.pipeline import get_schedule
 from repro.models import encdec, lm
+from repro.nn.core import ParamSpec
 from repro.optim.adamw import AdamState, Optimizer, apply_updates
 from repro.utils.tree import global_norm
 
@@ -76,27 +77,44 @@ def train_state_specs(cfg: ModelConfig, mesh, *, ef: bool = False
                       step=P(), ef=ef_specs)
 
 
+def decay_mask(cfg: ModelConfig):
+    """AdamW's weight-decay mask for the model's tree: matrices only, a
+    leaf of a stacked layer group being a matrix when each layer's slice
+    is (its axes other than ``layers``); norm scales and biases are left
+    out, stacked or not."""
+    spec_tree = _model_of(cfg).model_spec(cfg)
+    mask = jax.tree.map(
+        lambda s: sum(a != "layers" for a in s.axes) >= 2, spec_tree,
+        is_leaf=lambda x: isinstance(x, ParamSpec))
+    return lambda params: mask
+
+
 def init_train_state(cfg: ModelConfig, opt: Optimizer, key,
-                     mesh=None) -> TrainState:
+                     mesh=None, params: Optional[Dict] = None) -> TrainState:
     """A fresh TrainState.  With ``mesh``, every leaf is created in place
     under :func:`train_state_specs` -- the layout the step on ``mesh``
     takes -- and never whole on one device first: at published widths the
     parameters and moments alone outgrow one chip.  The pipeline step's
     error-feedback residuals come along when its compressed pod reduction
-    is on (:func:`wants_ef`)."""
+    is on (:func:`wants_ef`).  ``params`` (the model's tree, anywhere)
+    replaces the random initialization from ``key``: it is placed in the
+    same layout, and the optimizer moments start at zero."""
     model = _model_of(cfg)
 
-    def init():
-        params = model.init_model(cfg, key)
+    def init(params):
+        if params is None:
+            params = model.init_model(cfg, key)
         return TrainState(params, opt.init(params), jnp.zeros((), jnp.int32))
 
     if mesh is None:
-        return init()
+        return init(params)
     specs = train_state_specs(cfg, mesh)
     shardings = jax.tree.map(
         lambda s: jax.sharding.NamedSharding(mesh, s), specs,
         is_leaf=lambda x: isinstance(x, P))
-    state = jax.jit(init, out_shardings=shardings)()
+    if params is not None:
+        params = jax.device_put(params, shardings.params)
+    state = jax.jit(init, out_shardings=shardings, donate_argnums=0)(params)
     if wants_ef(cfg, mesh):
         state = state._replace(ef=init_ef_state(
             state.params, mesh, spec_tree=model.model_spec(cfg)))

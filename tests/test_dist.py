@@ -152,6 +152,40 @@ def test_sharded_train_step_runs(subproc):
     assert "SHARDED-TRAIN-OK" in out
 
 
+# The GSPMD train step on (data=2, model=2): each data shard keeps its own
+# rows through the layers.  Left free, the partitioner keeps all 4 rows on
+# every device and all-reduces whole-batch activations f32[4,32,...].
+_ROWS_SPLIT_CODE = """
+import re
+import jax
+import numpy as np
+from jax.sharding import AxisType, Mesh, NamedSharding
+from repro.configs import get_config
+from repro.dist import sharding as shd
+from repro.optim import adamw
+from repro.train.step import init_train_state, make_train_step
+mesh = Mesh(np.asarray(jax.devices()).reshape(2, 2), ("data", "model"),
+            axis_types=(AxisType.Auto,) * 2)
+cfg = get_config("stablelm-3b", reduced=True)
+opt = adamw(1e-3)
+state = init_train_state(cfg, opt, jax.random.PRNGKey(0), mesh)
+rows = NamedSharding(mesh, shd.batch_spec(mesh, 4, 2))
+batch = {k: jax.device_put(np.zeros((4, 32), np.int32), rows)
+         for k in ("tokens", "labels")}
+hlo = jax.jit(make_train_step(cfg, opt, mesh=mesh)).lower(
+    state, batch).compile().as_text()
+reduced = re.findall(r"= (\\S+) all-reduce(?:-start)?\\(", hlo)
+print("D_MODEL", cfg.d_model, "ALL-REDUCE", sorted(set(reduced)))
+"""
+
+
+def test_train_step_keeps_rows_split_over_data(subproc):
+    out = subproc(_ROWS_SPLIT_CODE, n_devices=4)
+    d_model, shapes = out.split("D_MODEL ", 1)[1].split(" ALL-REDUCE ", 1)
+    assert f"[2,32,{d_model}]" in shapes, shapes
+    assert "[4,32," not in shapes, shapes
+
+
 # The paged engine on a (data=2, model=2) mesh: the K/V pages keep their
 # head sharding through donated steps, copy-on-write forks, a spill and
 # its resume, and every stream equals the mesh-less engine's.

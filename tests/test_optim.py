@@ -69,3 +69,29 @@ def test_bf16_params_keep_f32_moments():
     updates, _ = opt.update({"w": jnp.ones((4, 4), jnp.bfloat16)},
                             state, params)
     assert updates["w"].dtype == jnp.bfloat16
+
+
+@pytest.mark.parametrize("arch", ["stablelm-3b", "mamba2-1.3b"])
+def test_trainer_decays_matrices_only_stacked_or_not(arch):
+    """The Trainer's mask: a stacked per-layer leaf decays when each
+    layer's slice is a matrix, so the layers' norm scales and biases
+    (stacked to 2-D) take no weight decay, as the final norm's do not."""
+    import functools
+    from repro.configs import get_config
+    from repro.models import lm
+    from repro.train.step import decay_mask
+
+    def path_str(path):
+        return "/".join(str(k.key) for k in path)
+
+    cfg = get_config(arch, reduced=True)
+    shapes = jax.eval_shape(functools.partial(lm.init_model, cfg),
+                            jax.random.PRNGKey(0))
+    mask = decay_mask(cfg)(shapes)
+    got = {path_str(p): m for p, m in
+           jax.tree_util.tree_flatten_with_path(mask)[0]}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(shapes)[0]:
+        name = path_str(path)
+        per_layer = leaf.ndim - name.startswith("layers/")
+        assert got[name] == (per_layer >= 2), name
+    assert not got["layers/ln1/scale"] and got["embed/table"]

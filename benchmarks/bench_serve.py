@@ -27,6 +27,7 @@ import tempfile
 import time
 
 import jax
+import jax.numpy as jnp
 
 from benchmarks.common import emit
 from repro.configs import get_config
@@ -74,6 +75,11 @@ PAGED_KV_BLOCKS = 8
 PAGED_BURST = 12
 
 
+def _own(params):
+    """A copy for one engine: an engine consumes the weights it is given."""
+    return jax.tree.map(jnp.copy, params)
+
+
 def _paged_capacity_cell(cfg, params):
     traffic = TrafficConfig(
         qps=1000.0, n_requests=PAGED_BURST, n_tenants=1,
@@ -85,7 +91,8 @@ def _paged_capacity_cell(cfg, params):
     rows, cells = [], {}
     for paged in (True, False):
         engine = ServeEngine.from_config(
-            cfg, params=params, max_batch=8, max_seq=MAX_SEQ, block_size=8,
+            cfg, params=_own(params), max_batch=8, max_seq=MAX_SEQ,
+            block_size=8,
             kv_blocks=PAGED_KV_BLOCKS, tenants=[Tenant("t0")], paged=paged,
             debug_invariants=True)
         # Warm the prefix cache the way a real deployment does: one
@@ -143,7 +150,8 @@ def run(timesteps: int = 48):
         for admission in ("continuous", "gang"):
             for plans in (False, True):
                 engine = ServeEngine.from_config(
-                    cfg, params=params, max_batch=MAX_BATCH, max_seq=MAX_SEQ,
+                    cfg, params=_own(params), max_batch=MAX_BATCH,
+                    max_seq=MAX_SEQ,
                     block_size=8, tenants=tenants(), admission=admission,
                     schedule_cache=plan_cache if plans else None)
                 report = run_load(engine, traffic)

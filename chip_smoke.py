@@ -211,10 +211,14 @@ def serve_phase(cfg, n_requests: int = N_REQUESTS) -> None:
                              f"{[r.output for r in runs]}")
     log(f"serve replay: {len(runs)} runs of one prompt, identical tokens")
 
+    # The engine consumed its weights in laying them out per layer: free
+    # it, and make the seed's stacked weights again for the forward pass.
+    del engine, params
+    params = lm.init_model(cfg, jax.random.PRNGKey(SEED))
     fwd = jax.jit(functools.partial(lm.forward, cfg=cfg))
     worst = 0.0
     for req in (loaded[0], loaded[-1], first, second):
-        gaps = logit_gaps(fwd, engine.params, req)
+        gaps = logit_gaps(fwd, params, req)
         worst = max(worst, float(gaps.max()))
         log(f"serve request {req.id}: {len(req.output)} tokens, logit gap "
             f"worst {gaps.max():.4f} mean {gaps.mean():.4f} sigma, exact "

@@ -151,8 +151,9 @@ def lowerable(cfg: ModelConfig, shape_name: str, mesh):
                 return lm.forward(params, batch["tokens"], cfg, mesh=mesh)
         return prefill, (p_sds, batch_sds)
 
-    # decode kinds: one new token against a cache of length `seq`
-    p_sds, _ = params_sds(cfg, mesh)
+    # decode kinds: one new token against a cache of length `seq`, with
+    # the weights in the serving form (one pytree per layer)
+    p_sds = serve_decode.serving_params(params_sds(cfg, mesh)[0])
     caches_sds = jax.eval_shape(
         functools.partial(serve_decode.init_caches, cfg, batch, seq))
     caches_sds = _with_sharding(

@@ -4,17 +4,23 @@ Decode is deliberately *unrolled* over layers (unlike the scanned training
 path): each layer owns its cache pytree, so per-layer cache shapes can
 differ — gemma's local layers keep a bounded ``window``-sized ring buffer
 while its global layers keep the full sequence; mamba layers keep an O(1)
-recurrent state.  The decode HLO is tiny per layer, so unrolling stays
-cheap to compile while making the memory roofline of ``decode_32k`` /
-``long_500k`` honest.
+recurrent state.
+
+The steps take their weights in the serving form :func:`serving_params`
+makes once, at load: every stacked layer group as a list of per-layer
+pytrees.  A step that sliced layer ``i`` out of a stacked array instead
+made the compiler re-lay out every layer's attention matrices on every
+pass (a slice fusion per matrix and a copy per layer, growing with depth).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro import nn
 from repro.configs.base import ModelConfig
@@ -28,9 +34,66 @@ from repro.nn.ssm import apply_ssm_decode, init_ssm_cache
 
 _NEG = -1e30
 
+# The stacked layer groups the decode steps index by layer; embedding, head,
+# final norm, ``shared_block`` and the encoder's ``enc_layers`` stay as the
+# model makes them.
+_LAYER_GROUPS = ("layers", "dense_layers", "dec_layers")
 
-def _layer_params(stacked: Dict, i: int) -> Dict:
-    return jax.tree.map(lambda a: a[i], stacked)
+
+# ---------------------------------------------------------------------------
+# the serving form of the weights
+# ---------------------------------------------------------------------------
+
+def _less_layer(sharding):
+    """A stacked leaf's sharding without its leading layer axis."""
+    if isinstance(sharding, NamedSharding):
+        return NamedSharding(sharding.mesh, PartitionSpec(*sharding.spec[1:]),
+                             memory_kind=sharding.memory_kind)
+    return sharding
+
+
+@functools.lru_cache(maxsize=None)
+def _unstacker(sharding):
+    return jax.jit(lambda a: [a[i] for i in range(a.shape[0])],
+                   out_shardings=sharding)
+
+
+def _unstack(a, consume: bool) -> List:
+    """One stacked leaf -> its per-layer leaves, abstract or on device."""
+    if isinstance(a, jax.ShapeDtypeStruct):
+        s = jax.ShapeDtypeStruct(a.shape[1:], a.dtype,
+                                 sharding=_less_layer(a.sharding))
+        return [s] * a.shape[0]
+    if isinstance(a, jax.core.Tracer):
+        return [a[i] for i in range(a.shape[0])]
+    parts = _unstacker(_less_layer(a.sharding))(a)
+    if consume:
+        jax.block_until_ready(parts)
+        a.delete()
+    return parts
+
+
+def serving_params(params: Dict, *, consume: bool = False) -> Dict:
+    """The weights as the decode steps take them: each stacked layer group
+    in ``params`` (``layers``, ``dense_layers``, ``dec_layers``) becomes a
+    list of per-layer pytrees (leaf ``a`` -> ``a[i]`` in entry ``i``); the
+    other entries pass through.
+
+    Arrays on a device are split one stacked leaf at a time, each part
+    keeping the leaf's sharding less the layer axis; with ``consume`` each
+    stacked leaf is deleted as soon as its parts exist, so the layout never
+    holds more than one leaf twice (the caller's arrays are gone after).
+    Abstract leaves (``ShapeDtypeStruct``) give abstract parts, and traced
+    ones are indexed inside the trace."""
+    out = dict(params)
+    for key in _LAYER_GROUPS:
+        if key not in params:
+            continue
+        leaves, treedef = jax.tree.flatten(params[key])
+        cols = [_unstack(a, consume) for a in leaves]
+        out[key] = [treedef.unflatten([c[i] for c in cols])
+                    for i in range(len(cols[0]))]
+    return out
 
 
 def _cache_dtype(cfg: ModelConfig):
@@ -240,19 +303,17 @@ def _attn_decode_paged(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict,
 # decode step
 # ---------------------------------------------------------------------------
 #
-# Named scopes (``layer<i>/weights``, ``layer<i>/attn/kv_write``,
-# ``layer<i>/attn/attend``, ``layer<i>/mlp``, ``head``) land in the compiled
-# HLO's ``op_name`` metadata, so a device trace's ops map back to source.
+# Both steps take ``params`` in the serving form (:func:`serving_params`).
+# Named scopes (``layer<i>/attn/kv_write``, ``layer<i>/attn/attend``,
+# ``layer<i>/mlp``, ``head``) land in the compiled HLO's ``op_name``
+# metadata, so a device trace's ops map back to source.
 
-def _dense_layer_params(cfg: ModelConfig, params: Dict, li: int,
-                        dense_head: int) -> Dict:
-    """Layer ``li``'s slice of the stacked dense/moe layer weights."""
-    with jax.named_scope("weights"):
-        if cfg.family == "moe" and li >= dense_head:
-            return _layer_params(params["layers"], li - dense_head)
-        if cfg.family == "moe":
-            return _layer_params(params["dense_layers"], li)
-        return _layer_params(params["layers"], li)
+def _dense_layer_params(params: Dict, li: int, dense_head: int) -> Dict:
+    """Layer ``li``'s weights: a moe model's first ``dense_head`` layers
+    are its ``dense_layers``, the rest its ``layers``."""
+    if li < dense_head:
+        return params["dense_layers"][li]
+    return params["layers"][li - dense_head]
 
 
 def _mlp_block(cfg: ModelConfig, p: Dict, x: jax.Array, routed: bool,
@@ -296,7 +357,7 @@ def decode_step(params: Dict, caches: List, token: jax.Array, pos,
         dense_head = cfg.first_dense_layers if cfg.family == "moe" else 0
         for li in range(cfg.n_layers):
             with jax.named_scope(f"layer{li}"):
-                p = _dense_layer_params(cfg, params, li, dense_head)
+                p = _dense_layer_params(params, li, dense_head)
                 with jax.named_scope("attn"):
                     h = _apply_norm(cfg, p["ln1"], x)
                     if cfg.mla:
@@ -310,7 +371,7 @@ def decode_step(params: Dict, caches: List, token: jax.Array, pos,
 
     elif cfg.family == "ssm":
         for li in range(cfg.n_layers):
-            p = _layer_params(params["layers"], li)
+            p = params["layers"][li]
             h = _apply_norm(cfg, p["ln1"], x)
             y, new_caches[li] = apply_ssm_decode(p["ssm"], h, caches[li],
                                                  ssm_config(cfg))
@@ -321,7 +382,7 @@ def decode_step(params: Dict, caches: List, token: jax.Array, pos,
         shared = params["shared_block"]
         g = 0
         for li in range(cfg.n_layers):
-            p = _layer_params(params["layers"], li)
+            p = params["layers"][li]
             h = _apply_norm(cfg, p["ln1"], x)
             y, new_caches[li] = apply_ssm_decode(p["ssm"], h, caches[li],
                                                  ssm_config(cfg))
@@ -338,7 +399,7 @@ def decode_step(params: Dict, caches: List, token: jax.Array, pos,
 
     elif cfg.family == "encdec":
         for li in range(cfg.dec_layers):
-            p = _layer_params(params["dec_layers"], li)
+            p = params["dec_layers"][li]
             h = _apply_norm(cfg, p["ln1"], x)
             a, new_caches[li] = _attn_decode(cfg, p["attn"], h, caches[li],
                                              pos, NO_WINDOW)
@@ -383,7 +444,7 @@ def paged_decode_step(params: Dict, caches: List, block_table: jax.Array,
         dense_head = cfg.first_dense_layers if cfg.family == "moe" else 0
         for li in range(cfg.n_layers):
             with jax.named_scope(f"layer{li}"):
-                p = _dense_layer_params(cfg, params, li, dense_head)
+                p = _dense_layer_params(params, li, dense_head)
                 with jax.named_scope("attn"):
                     h = _apply_norm(cfg, p["ln1"], x)
                     if cfg.mla:
@@ -400,7 +461,7 @@ def paged_decode_step(params: Dict, caches: List, block_table: jax.Array,
 
     elif cfg.family == "ssm":
         for li in range(cfg.n_layers):
-            p = _layer_params(params["layers"], li)
+            p = params["layers"][li]
             h = _apply_norm(cfg, p["ln1"], x)
             y, new_caches[li] = apply_ssm_decode(p["ssm"], h, caches[li],
                                                  ssm_config(cfg))
@@ -411,7 +472,7 @@ def paged_decode_step(params: Dict, caches: List, block_table: jax.Array,
         shared = params["shared_block"]
         g = 0
         for li in range(cfg.n_layers):
-            p = _layer_params(params["layers"], li)
+            p = params["layers"][li]
             h = _apply_norm(cfg, p["ln1"], x)
             y, new_caches[li] = apply_ssm_decode(p["ssm"], h, caches[li],
                                                  ssm_config(cfg))

@@ -24,9 +24,10 @@ request gets the next free slot.  Every engine step:
 Each stage runs inside a host span ``repro.serve.<stage>`` (:func:`span`;
 ``step`` holds ``evict``, ``admit``, ``pass`` and ``preempt``; ``pass``
 holds ``pool``, ``cow_copy``, ``assemble``, ``dispatch``, ``sync`` and
-``apply``), which a profiler trace puts on the device planes' clock.  The
-stage clocks in ``counters`` (``queue_wait_s``, ``prefill_s``, ``sync_s``,
-``host_s``) run whether or not a trace does.
+``apply``; ``layout`` runs once, at construction), which a profiler trace
+puts on the device planes' clock.  The stage clocks in ``counters``
+(``queue_wait_s``, ``prefill_s``, ``sync_s``, ``host_s``, ``layout_s``) run
+whether or not a trace does.
 
 Kernel schedules come from the cache index via
 :func:`repro.sched.lowering.schedule_plan` (re-exported here) —
@@ -59,7 +60,8 @@ from repro.sched.lowering import schedule_plan  # noqa: F401  (serve-facing API)
 from repro.serve.batching import (NEVER_WRITE, SlotState, assemble,
                                   assemble_paged)
 from repro.serve.decode import (decode_step, init_caches, init_paged_caches,
-                                paged_cache_kinds, paged_decode_step)
+                                paged_cache_kinds, paged_decode_step,
+                                serving_params)
 from repro.serve.pool import KVBlockPool, PoolCapacityError, PoolError  # noqa: F401
 from repro.serve.scheduler import (DEFAULT_TENANT, FairScheduler, Request,
                                    Tenant)
@@ -191,6 +193,12 @@ class ServeEngine:
     ``admission="gang"`` degrades the engine to static batching (admit
     only into an idle engine, wait for the whole gang to finish) — the
     baseline ``bench_serve.py`` compares continuous batching against.
+
+    The engine takes ownership of ``params``: it lays them out once in the
+    serving form (:func:`~repro.serve.decode.serving_params`, after placing
+    them on ``mesh`` if one is given), deleting each stacked layer array as
+    its per-layer arrays are made.  A caller that uses its weights after
+    building an engine passes a copy.
     """
 
     def __init__(self, cfg: ModelConfig, *, params: Optional[Dict] = None,
@@ -235,7 +243,14 @@ class ServeEngine:
         if params is None:
             from repro.models import lm
             params = lm.init_model(cfg, jax.random.PRNGKey(rng_seed))
-        self.params = params
+        if mesh is not None:
+            from repro.models import lm
+            params = jax.device_put(
+                params, shd.param_shardings(lm.model_spec(cfg), mesh))
+        t = time.perf_counter()
+        with span("layout"):
+            self.params = serving_params(params, consume=True)
+        layout_s = time.perf_counter() - t
         if self.paged:
             self._kinds = paged_cache_kinds(cfg)
             self.caches = init_paged_caches(cfg, self.pool.num_blocks,
@@ -245,9 +260,6 @@ class ServeEngine:
             self._kinds = None
             self.caches = init_caches(cfg, self.max_batch + 1, self.max_seq)
         if mesh is not None:
-            from repro.models import lm
-            self.params = jax.device_put(
-                self.params, shd.param_shardings(lm.model_spec(cfg), mesh))
             self.caches = jax.device_put(
                 self.caches, shd.kv_pool_shardings(cfg, self.caches, mesh,
                                                    kinds=self._kinds))
@@ -276,7 +288,8 @@ class ServeEngine:
         # Stage clocks (seconds): ``queue_wait_s`` sums submit (or requeue)
         # to admission over ``admissions``; ``prefill_s`` sums admission to
         # first token over ``first_tokens``; ``sync_s`` is the host blocked
-        # on the device for a pass's tokens, ``host_s`` the rest of step().
+        # on the device for a pass's tokens, ``host_s`` the rest of step();
+        # ``layout_s`` is the weights' layout at construction.
         self.counters = {"passes": 0, "lane_tokens": 0,
                          "admissions": 0, "stalls": 0, "preemptions": 0,
                          "max_active": 0,
@@ -285,7 +298,8 @@ class ServeEngine:
                          "schedule_fallbacks": sum(
                              1 for art in self.plan.values() if art is None),
                          "queue_wait_s": 0.0, "prefill_s": 0.0,
-                         "first_tokens": 0, "sync_s": 0.0, "host_s": 0.0}
+                         "first_tokens": 0, "sync_s": 0.0, "host_s": 0.0,
+                         "layout_s": layout_s}
 
     @classmethod
     def from_config(cls, cfg: ModelConfig, **kwargs) -> "ServeEngine":
@@ -674,7 +688,9 @@ def generate(params: Dict, cfg: ModelConfig, prompt: jax.Array,
 
     With ``mesh`` given, params and caches are placed by the dist-layer
     rules before the token loop, so the scanned decode step runs sharded
-    (head-sharded KV for GQA, sequence-sharded for MQA/long-context)."""
+    (head-sharded KV for GQA, sequence-sharded for MQA/long-context).
+    The weights are split per layer (:func:`serving_params`) before the
+    loop too, into new arrays: the caller's stay as they were."""
     B, P = prompt.shape
     total = P + max_new_tokens
     max_seq = max_seq or total
@@ -689,6 +705,7 @@ def generate(params: Dict, cfg: ModelConfig, prompt: jax.Array,
         prompt = jax.device_put(
             prompt, jax.sharding.NamedSharding(
                 mesh, shd.batch_spec(mesh, B)))
+    params = serving_params(params)
     tokens0 = jnp.concatenate(
         [prompt, jnp.zeros((B, max_new_tokens), jnp.int32)], axis=1)
 

@@ -22,7 +22,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels import cases
 from repro.models import lm
-from repro.serve.decode import init_paged_caches
+from repro.serve.decode import init_paged_caches, serving_params
 from repro.serve.engine import _paged_step_fns
 
 V5E_HBM_BYTES = 16 * 1024 ** 3
@@ -66,11 +66,11 @@ def test_kernel_compiles_for_v5e(one_chip, name):
 
 
 def _compile_paged_step(sharding, cfg, max_batch, max_seq, block, blocks):
-    """The engine's paged step compiled for ``sharding``'s device, and its
-    abstract caches."""
+    """The engine's paged step compiled for ``sharding``'s device, with the
+    weights in the serving form, and its abstract caches."""
     step, _ = _paged_step_fns(cfg, None, max_seq)
-    params = _on(sharding, jax.eval_shape(
-        lambda: lm.init_model(cfg, jax.random.PRNGKey(0))))
+    params = serving_params(_on(sharding, jax.eval_shape(
+        lambda: lm.init_model(cfg, jax.random.PRNGKey(0)))))
     caches = _on(sharding, jax.eval_shape(
         lambda: init_paged_caches(cfg, blocks, block, max_batch)))
     lane = jax.ShapeDtypeStruct((max_batch,), jnp.int32, sharding=sharding)
@@ -115,6 +115,35 @@ def test_paged_decode_step_updates_pages_in_place(one_chip):
     page_bytes = sum(a.size * a.dtype.itemsize
                      for a in jax.tree.leaves(caches))
     assert compiled.memory_analysis().alias_size_in_bytes >= page_bytes
+
+
+def test_paged_decode_step_weight_relayouts_do_not_grow_with_depth(
+        one_chip):
+    """At the benchmark cell's geometry the step slices no stacked weight
+    (no ``slice_bitcast_fusion``) and re-lays out the same few weight
+    matrices (layer 0's, prefetched) at 4 layers and at 8: slicing stacked
+    weights inside the step gave 2 slice fusions and 8 copies at 4 layers,
+    one copy per attention matrix a layer.  ``tools/serve_stages.py``'s
+    count of weight relayouts reads the same from the compiled text."""
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))), "tools"))
+    import serve_stages
+
+    counts = []
+    for n_layers in (4, 8):
+        cfg = get_config("qwen1.5-4b").replace(n_layers=n_layers)
+        compiled, _ = _compile_paged_step(one_chip, cfg, 10, 512, 16, 320)
+        text = compiled.as_text()
+        assert "slice_bitcast_fusion" not in text
+        weights = {a.shape for a in jax.tree.leaves(jax.eval_shape(
+            lambda: lm.init_model(cfg, jax.random.PRNGKey(0)))["layers"])}
+        weights = {s[1:] for s in weights if len(s) >= 3}
+        copies = [tuple(int(n) for n in dims.split(",") if n)
+                  for dims in _COPY.findall(text)]
+        counts.append(len([c for c in copies if c in weights]))
+        assert serve_stages.weight_relayouts(text)["count"] == counts[-1]
+    assert counts[0] == counts[1] <= 2, counts
 
 
 def test_train_step_at_4k_fits_a_v5e_2x2(topo):

@@ -201,7 +201,8 @@ params = lm.init_model(cfg, jax.random.PRNGKey(0))
 prompt = list(range(1, 11))
 outs = []
 for mesh in (None, make_host_mesh(model=2)):
-    e = ServeEngine.from_config(cfg, params=params, max_batch=2, max_seq=24,
+    own = jax.tree.map(jax.numpy.copy, params)
+    e = ServeEngine.from_config(cfg, params=own, max_batch=2, max_seq=24,
                                 block_size=4, kv_blocks=7, prefill_chunk=1,
                                 paged=True, mesh=mesh)
     placed = jax.tree.leaves(e.caches)
@@ -223,6 +224,42 @@ print("PAGED-MESH-OK")
 def test_paged_engine_on_mesh_keeps_head_sharded_pages(subproc):
     out = subproc(_PAGED_MESH_CODE, n_devices=4)
     assert "PAGED-MESH-OK" in out
+
+
+# The engine on a (model=2) mesh lays its weights out per layer with each
+# leaf's sharding less the layer axis: the head-sharded attention and MLP
+# matrices stay split over ``model``.
+_LAYOUT_MESH_CODE = """
+import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.configs import get_config
+from repro.dist import sharding as shd
+from repro.launch.mesh import make_host_mesh
+from repro.models import lm
+from repro.serve import ServeEngine
+cfg = get_config("qwen1.5-4b", reduced=True)
+mesh = make_host_mesh(model=2)
+stacked = shd.param_shardings(lm.model_spec(cfg), mesh)["layers"]
+e = ServeEngine.from_config(cfg, max_batch=2, max_seq=24, block_size=4,
+                            paged=True, mesh=mesh)
+assert len(e.params["layers"]) == cfg.n_layers
+split = 0
+for layer in e.params["layers"]:
+    for a, sh in zip(jax.tree.leaves(layer), jax.tree.leaves(stacked)):
+        assert a.sharding == NamedSharding(mesh, P(*sh.spec[1:])), (
+            a.sharding, sh)
+        split += "model" in sh.spec
+assert split >= 4 * cfg.n_layers, split
+e.submit([1, 2, 3], 4)
+e.run()
+assert len(e.finished[0].output) == 4
+print("LAYOUT-MESH-OK")
+"""
+
+
+def test_engine_weights_per_layer_keep_their_sharding(subproc):
+    out = subproc(_LAYOUT_MESH_CODE, n_devices=2)
+    assert "LAYOUT-MESH-OK" in out
 
 
 # Trainer on a mesh creates its state in the step's layout: no leaf is

@@ -11,7 +11,7 @@ from repro.configs import ARCHS, get_config
 from repro.data.pipeline import make_data
 from repro.models import for_config
 from repro.optim import adamw, constant_schedule
-from repro.serve import decode_step, init_caches
+from repro.serve import decode_step, init_caches, serving_params
 from repro.train.step import TrainState, make_train_step
 
 SEQ, BATCH = 32, 2
@@ -58,6 +58,7 @@ def test_decode_step_smoke(arch, trained):
     caches = init_caches(cfg, BATCH, SEQ)
     token = jnp.zeros((BATCH, 1), jnp.int32)
     fn = jax.jit(lambda p, c, t, pos: decode_step(p, c, t, pos, cfg))
+    params = serving_params(params)
     logits, caches = fn(params, caches, token, 0)
     assert logits.shape == (BATCH, cfg.vocab)
     assert bool(jnp.isfinite(logits).all())
@@ -75,8 +76,10 @@ def test_decode_consistent_with_forward(arch, trained):
     tokens = jnp.asarray(rng.integers(0, cfg.vocab, (1, 12)), jnp.int32)
     full = lm.forward(params, tokens, cfg)
     caches = init_caches(cfg, 1, 12)
+    per_layer = serving_params(params)
     for pos in range(8):
-        logits, caches = decode_step(params, caches, tokens[:, pos:pos + 1],
+        logits, caches = decode_step(per_layer, caches,
+                                     tokens[:, pos:pos + 1],
                                      pos, cfg)
         np.testing.assert_allclose(np.asarray(logits),
                                    np.asarray(full[:, pos]), atol=2e-3,

@@ -507,8 +507,10 @@ def test_serve_engine_on_missing_baseline_vs_strict(tmp_path, monkeypatch):
 
     # baseline mode: every kernel serves the -O3 baseline, counted, and
     # serving never touches a Machine
-    engine = ServeEngine.from_config(cfg, params=params, max_batch=2,
-                                     max_seq=32, schedule_cache=empty_cache,
+    engine = ServeEngine.from_config(cfg, params=jax.tree.map(jax.numpy.copy,
+                                                              params),
+                                     max_batch=2, max_seq=32,
+                                     schedule_cache=empty_cache,
                                      on_missing="baseline")
     assert engine.plan and all(a is None for a in engine.plan.values())
     assert engine.counters["schedule_fallbacks"] == len(engine.plan) > 0

@@ -14,7 +14,7 @@ from repro.models import lm
 from repro.serve import (FairScheduler, KVBlockPool, PoolCapacityError,
                          PoolError, Request, ServeEngine, Tenant,
                          TrafficConfig, generate, run_load)
-from repro.serve.decode import decode_step, init_caches
+from repro.serve.decode import decode_step, init_caches, serving_params
 
 MAX_SEQ = 48
 
@@ -22,6 +22,12 @@ MAX_SEQ = 48
 def _model(arch):
     cfg = get_config(arch, reduced=True)
     return cfg, lm.init_model(cfg, jax.random.PRNGKey(0))
+
+
+def _own(params):
+    """A copy of the weights for an engine, which consumes what it is
+    given (the tests reuse their weights for the reference)."""
+    return jax.tree.map(jnp.copy, params)
 
 
 def _ref_generation(params, cfg, prompt, n):
@@ -46,7 +52,7 @@ def test_continuous_batching_bitexact_vs_sequential_generate(arch):
     (gemma), and recurrent SSM state (mamba — exercises the slot-reset
     path when a freed slot is reused)."""
     cfg, params = _model(arch)
-    engine = ServeEngine.from_config(cfg, params=params, max_batch=3,
+    engine = ServeEngine.from_config(cfg, params=_own(params), max_batch=3,
                                      max_seq=MAX_SEQ, block_size=8,
                                      prefill_chunk=2)
     rng = np.random.default_rng(0)
@@ -75,7 +81,7 @@ def test_preempted_requests_resume_bitexact():
     requests get requeued with their generated prefix teacher-forced, and
     still finish bit-exact vs the sequential reference."""
     cfg, params = _model("qwen1.5-4b")
-    engine = ServeEngine.from_config(cfg, params=params, max_batch=2,
+    engine = ServeEngine.from_config(cfg, params=_own(params), max_batch=2,
                                      max_seq=MAX_SEQ, block_size=8,
                                      kv_blocks=7, prefill_chunk=4)
     rng = np.random.default_rng(2)
@@ -133,7 +139,7 @@ def test_gang_admission_is_static_batching():
     """admission='gang' (the bench baseline) only admits into an idle
     engine and still produces the exact sequential streams."""
     cfg, params = _model("qwen1.5-4b")
-    engine = ServeEngine.from_config(cfg, params=params, max_batch=2,
+    engine = ServeEngine.from_config(cfg, params=_own(params), max_batch=2,
                                      max_seq=MAX_SEQ, admission="gang")
     jobs = [([1, 2, 3], 5), ([4, 5, 6, 7], 4), ([8, 9], 6)]
     reqs = [engine.submit(p, n) for p, n in jobs]
@@ -150,6 +156,45 @@ def test_gang_admission_is_static_batching():
 
 
 # ---------------------------------------------------------------------------
+# the serving form of the weights
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("arch", ["qwen1.5-4b", "deepseek-v2-lite-16b",
+                                  "zamba2-2.7b"])
+def test_serving_params_split_each_stacked_group_per_layer(arch):
+    """Dense, moe with MLA and a leading dense layer, and hybrid: each
+    stacked group becomes a list with one tree per layer whose leaves are
+    ``a[i]`` of the stacked leaves; other entries pass through.  With
+    ``consume`` the stacked leaves are deleted, and only they."""
+    cfg, params = _model(arch)
+    groups = [k for k in ("layers", "dense_layers") if k in params]
+    assert groups == (["layers", "dense_layers"]
+                      if arch.startswith("deepseek") else ["layers"])
+    got = serving_params(_own(params), consume=False)
+    assert set(got) == set(params)
+    for key in params:
+        if key not in groups:
+            assert jax.tree.all(jax.tree.map(
+                lambda a, b: bool((a == b).all()), got[key], params[key]))
+            continue
+        stacked = jax.tree.leaves(params[key])
+        assert isinstance(got[key], list)
+        assert len(got[key]) == stacked[0].shape[0]
+        for i, layer in enumerate(got[key]):
+            assert (jax.tree.structure(layer)
+                    == jax.tree.structure(jax.tree.map(lambda a: a[0],
+                                                       params[key])))
+            for a, b in zip(stacked, jax.tree.leaves(layer)):
+                np.testing.assert_array_equal(np.asarray(a[i]),
+                                              np.asarray(b))
+    own = _own(params)
+    serving_params(own, consume=True)
+    for key in own:
+        assert all(a.is_deleted() == (key in groups)
+                   for a in jax.tree.leaves(own[key])), key
+
+
+# ---------------------------------------------------------------------------
 # ragged per-row positions in decode_step
 # ---------------------------------------------------------------------------
 
@@ -161,6 +206,7 @@ def test_vector_pos_decode_step_matches_scalar(arch):
     B, steps = 3, 5
     rng = np.random.default_rng(0)
     toks = rng.integers(0, cfg.vocab, (steps, B, 1), dtype=np.int32)
+    params = serving_params(params)
     c_s = init_caches(cfg, B, MAX_SEQ)
     c_v = init_caches(cfg, B, MAX_SEQ)
     for pos in range(steps):

@@ -25,6 +25,12 @@ def _model(arch):
     return cfg, lm.init_model(cfg, jax.random.PRNGKey(0))
 
 
+def _own(params):
+    """A copy of the weights for an engine, which consumes what it is
+    given (the tests reuse their weights for the reference)."""
+    return jax.tree.map(jnp.copy, params)
+
+
 def _refs(params, cfg, prompts, n, max_seq=MAX_SEQ):
     """Batched static-path reference (equal lengths -> one compile)."""
     out = generate(params, cfg, jnp.asarray(prompts, jnp.int32), n,
@@ -53,7 +59,7 @@ def test_paged_bitexact_vs_sequential_generate(arch):
     and both riding together in the hybrid's shared attention block
     (zamba)."""
     cfg, params = _model(arch)
-    engine = ServeEngine.from_config(cfg, params=params, max_batch=3,
+    engine = ServeEngine.from_config(cfg, params=_own(params), max_batch=3,
                                      max_seq=MAX_SEQ, block_size=8,
                                      prefill_chunk=2, paged=True,
                                      debug_invariants=True)
@@ -80,7 +86,7 @@ def test_paged_mla_decode_bitexact():
     """The MLA paged path (latent c_kv + shared k_rope pages) matches the
     dense MLA decode stream."""
     cfg, params = _model("deepseek-v2-lite-16b")
-    engine = ServeEngine.from_config(cfg, params=params, max_batch=2,
+    engine = ServeEngine.from_config(cfg, params=_own(params), max_batch=2,
                                      max_seq=32, block_size=8, paged=True,
                                      debug_invariants=True)
     jobs = [([1, 2, 3, 4, 5], 6), ([9, 8, 7], 5)]
@@ -127,11 +133,10 @@ def test_donated_pages_serve_as_the_slot_engine_through_cow_and_spill():
     the rebound pages) every stream equals the slot engine's."""
     cfg, params = _model("qwen1.5-4b")
     prompt = list(range(1, 11))              # 2 full blocks + a partial
-    kw = dict(params=params, max_batch=2, max_seq=24, block_size=4,
-              prefill_chunk=1)
-    slot = ServeEngine.from_config(cfg, **kw)
-    engine = ServeEngine.from_config(cfg, kv_blocks=7, paged=True,
-                                     debug_invariants=True, **kw)
+    kw = dict(max_batch=2, max_seq=24, block_size=4, prefill_chunk=1)
+    slot = ServeEngine.from_config(cfg, params=_own(params), **kw)
+    engine = ServeEngine.from_config(cfg, params=_own(params), kv_blocks=7,
+                                     paged=True, debug_invariants=True, **kw)
     step, donated = engine._step_fn, []
 
     def checked(params, caches, *lanes):
@@ -169,7 +174,7 @@ def test_prefix_sharing_cow_under_concurrent_decode():
     write copy-on-write-forks the shared partial block, and all three
     concurrent streams stay bit-exact."""
     cfg, params = _model("qwen1.5-4b")
-    engine = ServeEngine.from_config(cfg, params=params, max_batch=3,
+    engine = ServeEngine.from_config(cfg, params=_own(params), max_batch=3,
                                      max_seq=MAX_SEQ, block_size=8,
                                      paged=True, debug_invariants=True)
     rng = np.random.default_rng(7)
@@ -223,7 +228,7 @@ def test_shared_prefix_trace_generator_is_seeded_and_layered():
 def test_block_size_one():
     """One token per page: every advance grows the table by one block."""
     cfg, params = _model("qwen1.5-4b")
-    engine = ServeEngine.from_config(cfg, params=params, max_batch=2,
+    engine = ServeEngine.from_config(cfg, params=_own(params), max_batch=2,
                                      max_seq=24, block_size=1, paged=True,
                                      debug_invariants=True)
     jobs = [([3, 1, 4, 1, 5], 6), ([2, 7], 5)]
@@ -238,7 +243,7 @@ def test_max_seq_not_multiple_of_block_size():
     """max_seq=42 over 8-token blocks: the last block is only partially
     addressable; truncation still lands exactly at max_seq."""
     cfg, params = _model("qwen1.5-4b")
-    engine = ServeEngine.from_config(cfg, params=params, max_batch=2,
+    engine = ServeEngine.from_config(cfg, params=_own(params), max_batch=2,
                                      max_seq=42, block_size=8, paged=True,
                                      debug_invariants=True)
     req = engine.submit([5, 4, 3, 2, 1, 0], 60)        # must truncate
@@ -257,7 +262,7 @@ def test_prompt_exactly_fills_last_block():
     whole prompt is full blocks the partial-tail registration is a
     no-op (everything shareable is already keyed)."""
     cfg, params = _model("qwen1.5-4b")
-    engine = ServeEngine.from_config(cfg, params=params, max_batch=2,
+    engine = ServeEngine.from_config(cfg, params=_own(params), max_batch=2,
                                      max_seq=MAX_SEQ, block_size=8,
                                      paged=True, debug_invariants=True)
     rng = np.random.default_rng(11)
@@ -424,6 +429,32 @@ def test_profiled_step_writes_every_stage_span_nested(tmp_path):
                        for n, ps, pe in spans), f"{name} outside {parent}"
 
 
+def test_engine_lays_out_its_weights_once_under_a_span(tmp_path):
+    """Building the engine lays out the weights it is given per layer,
+    inside one ``repro.serve.layout`` span, timed in ``layout_s``; the
+    stacked arrays passed in are consumed, and no step lays them out
+    again."""
+    import glob
+
+    cfg, params = _model("qwen1.5-4b")
+    given = _own(params)
+    jax.profiler.start_trace(str(tmp_path))
+    engine = ServeEngine.from_config(cfg, params=given, max_batch=2,
+                                     max_seq=MAX_SEQ, block_size=8,
+                                     paged=True)
+    jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert [n for n, _, _ in _program_spans(path)] == ["layout"]
+    assert engine.counters["layout_s"] > 0
+    assert all(a.is_deleted() for a in jax.tree.leaves(given["layers"]))
+    assert len(engine.params["layers"]) == cfg.n_layers
+    engine.submit([1, 2, 3], 4)
+    engine.run()
+    assert engine.counters["layout_s"] == engine.stats()["engine"][
+        "layout_s"]
+    assert engine.finished[0].output == _ref_one(params, cfg, [1, 2, 3], 4)
+
+
 def test_paged_step_hlo_carries_named_scopes():
     """The compiled paged step keeps the decode step's named scopes in its
     ``op_name`` metadata, per layer and for the head."""
@@ -436,8 +467,8 @@ def test_paged_step_hlo_carries_named_scopes():
     step = _paged_step_fns(cfg, None, MAX_SEQ)[0]
     lane = jnp.zeros((2,), jnp.int32)
     table = jnp.zeros((2, engine.pool.blocks_per_slot), jnp.int32)
-    text = step.lower(params, engine.caches, lane, table, lane, lane,
+    text = step.lower(engine.params, engine.caches, lane, table, lane, lane,
                       lane).compile().as_text()
-    for scope in ("layer0/weights", "layer0/attn/kv_write",
-                  "layer0/attn/attend", "layer1/mlp", "/head/"):
+    for scope in ("layer0/attn/kv_write", "layer0/attn/attend",
+                  "layer1/mlp", "/head/"):
         assert scope in text, scope

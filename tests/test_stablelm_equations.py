@@ -131,7 +131,8 @@ def test_paged_decode_of_reduced_stablelm_agrees_with_its_forward():
     with LayerNorm, partial rotary and the untied head."""
     cfg = get_config("stablelm-3b", reduced=True)
     params = lm.init_model(cfg, jax.random.PRNGKey(3))
-    engine = ServeEngine.from_config(cfg, params=params, max_batch=2,
+    own = jax.tree.map(jnp.copy, params)
+    engine = ServeEngine.from_config(cfg, params=own, max_batch=2,
                                      max_seq=40, block_size=8,
                                      prefill_chunk=2, paged=True)
     rng = np.random.default_rng(5)

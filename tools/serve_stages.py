@@ -18,7 +18,8 @@ shape, and per op instance its events, seconds and bytes (written to
 optimized HLO to ``chiprun_out/step-<cell>.hlo.txt`` and lists its copies by
 named scope (``repro.serve.decode``), with their bytes (``copy`` apart from
 the asynchronous ``copy-start``/``copy-done`` transfers), and the compiler's
-argument, output, temporary and aliased bytes; given a traced
+argument, output, temporary and aliased bytes, and the fusions and copies
+that re-lay out weights (``weight_relayouts``); given a traced
 ``run``'s stages file, it joins the traced copies to them, per pass.
 
 ``span-cost`` times an inactive ``repro.serve`` span and a clock read; with
@@ -221,24 +222,31 @@ _OPCODE = re.compile(r"\}?\s*([a-z][\w\-]*)\(")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
-def entry_instructions(hlo: str):
-    """(name, opcode, op_name, output bytes, result) of each instruction of
-    the entry computation of an HLO module's text; ``result`` is the result
-    shape without its layout, ``@S<n>`` where it lives in memory space n."""
+def _entry_lines(hlo: str):
+    """(name, right-hand side, opcode match) of each instruction of the
+    entry computation of an HLO module's text."""
     from bench import stages
-    out, inside = [], False
+    inside = False
     for line in hlo.splitlines():
         if line.startswith("ENTRY"):
             inside = True
             continue
         if inside and line.startswith("}"):
-            break
+            return
         m = _INSTR.match(line) if inside else None
-        if not m:
-            continue
-        name, rhs = m.groups()
-        op = _OPCODE.search(rhs, stages._close(rhs)
-                            if rhs.startswith("(") else 0)
+        if m:
+            name, rhs = m.groups()
+            yield name, rhs, _OPCODE.search(
+                rhs, stages._close(rhs) if rhs.startswith("(") else 0)
+
+
+def entry_instructions(hlo: str):
+    """(name, opcode, op_name, output bytes, result) of each instruction of
+    the entry computation of an HLO module's text; ``result`` is the result
+    shape without its layout, ``@S<n>`` where it lives in memory space n."""
+    from bench import stages
+    out = []
+    for name, rhs, op in _entry_lines(hlo):
         end = op.start(1) if op else len(rhs)
         result = re.sub(r"\{[^}]*\}", "", rhs[:end]).strip()
         space = re.search(r"S\((\d+)\)", rhs[:end])
@@ -284,6 +292,57 @@ def count_copies(instrs) -> dict:
                                            key=lambda kv: -kv[1][1]))}
 
 
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_SHAPE = re.compile(r"(\w+)\[([\d,]*)\]")
+# ops that move or rename data without computing on it
+_MOVES = ("bitcast", "get-tuple-element", "tuple", "slice-start",
+          "slice-done", "async-start", "async-done", "copy-start",
+          "copy-done", "custom-call")
+
+
+def weight_relayouts(hlo: str) -> dict:
+    """Fusions and copies of the entry computation that re-lay out weights:
+    one of its operands comes from a ``params`` argument of the step (as
+    it is, or through moves: bitcasts, tuple elements, asynchronous slices
+    and copies) and its result is, or holds, an array of a weight's shape
+    (a ``params`` argument's shape, or a stacked one's less its layer
+    axis).  Count and result bytes, in all and by instruction name less its
+    number (``slice_bitcast_fusion``, ``copy``)."""
+    from bench import stages
+    operands = {}
+    for name, rhs, op in _entry_lines(hlo):
+        if op:
+            args = rhs[op.end(1):]
+            operands[name] = _OPERAND.findall(args[:stages._close(args)])
+    instrs = entry_instructions(hlo)
+    derived, shapes = set(), set()
+    for name, op, op_name, _, result in instrs:
+        if op == "parameter" and op_name.startswith("params"):
+            derived.add(name)
+            for dtype, dims in _SHAPE.findall(result):
+                d = dims.split(",")
+                if len(d) >= 2:
+                    shapes.add((dtype, tuple(d)))
+                if len(d) >= 3:
+                    shapes.add((dtype, tuple(d[1:])))
+    by_name = defaultdict(lambda: [0, 0])
+    for name, op, _, nbytes, result in instrs:
+        if not derived.intersection(operands.get(name, ())):
+            continue
+        if op in _MOVES:
+            derived.add(name)
+        elif op in ("copy", "fusion") and any(
+                (dtype, tuple(dims.split(","))) in shapes
+                for dtype, dims in _SHAPE.findall(result)):
+            derived.add(name)
+            k = by_name[re.sub(r"\.\d+$", "", name)]
+            k[0] += 1
+            k[1] += nbytes or 0
+    return {"count": sum(v[0] for v in by_name.values()),
+            "bytes": sum(v[1] for v in by_name.values()),
+            "by_name": dict(by_name)}
+
+
 def step_shapes(found):
     """Abstract arguments of the paged step at the cell's geometry."""
     import functools
@@ -293,12 +352,12 @@ def step_shapes(found):
 
     from bench.drivers.common import program_config
     from repro.models import lm
-    from repro.serve.decode import init_paged_caches
+    from repro.serve.decode import init_paged_caches, serving_params
     c = found["config"]
     geo = c["engine"]
     pcfg = program_config(c)
-    params = jax.eval_shape(functools.partial(lm.init_model, pcfg),
-                            jax.random.PRNGKey(0))
+    params = serving_params(jax.eval_shape(
+        functools.partial(lm.init_model, pcfg), jax.random.PRNGKey(0)))
     caches = jax.eval_shape(functools.partial(
         init_paged_caches, pcfg, geo["kv_blocks"], geo["block_size"],
         geo["max_batch"]))
@@ -330,6 +389,7 @@ def cmd_hlo(argv) -> int:
           f"{_dump(f'step-{args.workload}.hlo.txt', text)}", flush=True)
     instrs = entry_instructions(text)
     out = count_copies(instrs)
+    out["weight_relayouts_in_step"] = weight_relayouts(text)
     out["memory_bytes"] = {k: getattr(mem, f"{k}_size_in_bytes")
                            for k in ("argument", "output", "temp", "alias")}
     if args.stages:

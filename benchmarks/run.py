@@ -9,51 +9,7 @@ trajectory artifact.
 
 import argparse
 import json
-import os
 import time
-
-SERVE_BASELINE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                              "BENCH_serve_baseline.json")
-
-
-def _serve_paged_ratio(report):
-    """Dense-normalized paged throughput from the ``serve_paged`` rows:
-    delivered tokens *per engine pass*, paged over dense, on the same
-    trace and block budget.  Pass counts are deterministic (no wall
-    clock in the capacity cell), so a >10% drop is a real efficiency
-    regression — broken prefix sharing or recompute-style preemption
-    inflates the paged pass count immediately."""
-    rows = next((r.get("rows") or [] for r in report
-                 if r["suite"] == "serve_load" and r["ok"]), [])
-    cells = {r[2]: float(r[7]) / float(r[11]) for r in rows
-             if r and r[0] == "serve_paged" and float(r[11])}
-    if "paged" not in cells or not cells.get("dense"):
-        return None
-    return cells["paged"] / cells["dense"]
-
-
-def _check_serve_baseline(report, path):
-    """Fail the run when the paged/dense serve throughput ratio regresses
-    more than 10% against the committed baseline."""
-    ratio = _serve_paged_ratio(report)
-    if ratio is None:
-        print("# serve baseline: no serve_paged rows this run, skipping")
-        return True
-    if not os.path.exists(path):
-        print(f"# serve baseline: {path} missing, skipping "
-              f"(current paged/dense ratio {ratio:.3f})")
-        return True
-    with open(path) as f:
-        base = json.load(f)["paged_over_dense_tokens_per_pass"]
-    floor = 0.9 * base
-    ok = ratio >= floor
-    print(f"# serve baseline: paged/dense tokens-per-pass {ratio:.3f} vs "
-          f"committed {base:.3f} (floor {floor:.3f}) -> "
-          f"{'ok' if ok else 'REGRESSION'}")
-    if not ok:
-        print(f"BENCH-FAIL,serve_regression,paged/dense ratio {ratio:.3f} "
-              f"fell more than 10% below baseline {base:.3f}")
-    return ok
 
 
 def _jsonable(obj):
@@ -84,10 +40,6 @@ def main() -> None:
                     help="skip the RL-training benches (fig8 / §5.7)")
     ap.add_argument("--out", default=None,
                     help="write a JSON summary of every suite here")
-    ap.add_argument("--serve-baseline", default=SERVE_BASELINE,
-                    help="committed serve-throughput baseline JSON; the run "
-                         "fails if the paged/dense tokens/s ratio drops "
-                         "more than 10%% below it")
     args = ap.parse_args()
 
     from benchmarks import (bench_autotune, bench_evaluator, bench_fleet,
@@ -145,15 +97,13 @@ def main() -> None:
         report.append(entry)
         print(f"# {name} took {entry['seconds']:.1f}s", flush=True)
 
-    serve_ok = _check_serve_baseline(report, args.serve_baseline)
     if args.out:
         with open(args.out, "w") as f:
-            json.dump({"fast": args.fast, "suites": report,
-                       "serve_paged_over_dense": _serve_paged_ratio(report)},
-                      f, indent=2, allow_nan=False)
+            json.dump({"fast": args.fast, "suites": report}, f, indent=2,
+                      allow_nan=False)
         print(f"\n# wrote {args.out} "
               f"({sum(r['ok'] for r in report)}/{len(report)} suites ok)")
-    if not all(r["ok"] for r in report) or not serve_ok:
+    if not all(r["ok"] for r in report):
         raise SystemExit(1)
 
 

@@ -11,7 +11,7 @@ One chip, three phases:
   against its oracle in ``repro.kernels.ref``;
 * serve: qwen1.5-4b at its published widths (40 layers, d_model 2560,
   vocab 151936, bf16, random weights from a fixed seed) behind
-  ``ServeEngine.from_config(..., paged=True)``, driven by
+  ``ServeEngine.from_config`` (paged KV cache), driven by
   ``serve.loadgen.run_load`` with two tenants and shared prompt prefixes,
   then one request repeated so that prefix sharing and the copy-on-write
   page copy run; every run of that prompt must yield the same tokens, and
@@ -155,7 +155,7 @@ def serve_phase(cfg, n_requests: int = N_REQUESTS) -> None:
     engine = ServeEngine.from_config(
         cfg, params=params, max_batch=MAX_BATCH, max_seq=MAX_SEQ,
         block_size=BLOCK, kv_blocks=MAX_BATCH * MAX_SEQ // BLOCK,
-        tenants=[Tenant(f"t{i}") for i in range(N_TENANTS)], paged=True)
+        tenants=[Tenant(f"t{i}") for i in range(N_TENANTS)])
     jax.block_until_ready((engine.params, engine.caches))
     log(f"serve {cfg.name}: {cfg.n_layers} layers, d_model {cfg.d_model}, "
         f"vocab {cfg.vocab}, {cfg.dtype}; set-up {time.monotonic() - t0:.1f}s")

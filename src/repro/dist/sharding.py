@@ -269,19 +269,11 @@ def decode_cache_shardings(cfg, caches, mesh):
     return jax.tree.map(lambda x: NamedSharding(mesh, leaf_spec(x)), caches)
 
 
-def kv_pool_shardings(cfg, caches, mesh, kinds=None):
-    """Placement for the serve engine's KV cache (dense or paged).
+def kv_pool_shardings(cfg, caches, mesh, kinds):
+    """Placement for the serve engine's paged KV cache.
 
-    **Dense** (``kinds=None``): the pool's backing arrays are the decode
-    caches with the slot dimension in the batch position
-    (``max_batch + 1`` rows: the slots plus the scratch row the padded
-    step writes), so they place under exactly the decode-cache rules —
-    slot rows across data axes when divisible, KV heads across the model
-    axis for GQA, sequence for MQA/long-context, latent/conv leaves by
-    their own rules.
-
-    **Paged** (``kinds`` = ``serve.decode.paged_cache_kinds(cfg)``, one
-    entry per cache in the list): block-major page leaves
+    ``kinds`` is ``serve.decode.paged_cache_kinds(cfg)``, one entry per
+    cache in the list.  Block-major page leaves
     ``(num_blocks, block_size, ...)`` NEVER shard the block or in-block
     position dims — every lane gathers arbitrary physical blocks through
     its table, so a sharded block dim would turn each gather into an
@@ -289,11 +281,9 @@ def kv_pool_shardings(cfg, caches, mesh, kinds=None):
     merged trailing dim over the model axis when the KV heads divide it
     (contiguous chunks are then whole heads), else replicate.  MLA latent
     pages replicate their trailing dim (it is the attention contraction
-    — same rule as the dense path).  ``"slot"`` entries (recurrent state
-    rows) keep the decode-cache rules."""
-    if kinds is None:
-        return decode_cache_shardings(cfg, caches, mesh)
-
+    — same rule as :func:`decode_cache_shardings`).  ``"slot"`` entries
+    (recurrent state rows, ``max_batch + 1`` of them with the scratch row)
+    keep the decode-cache rules."""
     def paged_leaf_spec(x) -> P:
         shape = x.shape
         if not cfg.mla and len(shape) == 3 \

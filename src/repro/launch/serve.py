@@ -3,27 +3,21 @@
 The launcher is built around :class:`repro.serve.engine.ServeEngine`:
 it spins up the continuous-batching engine, replays a seeded
 Poisson-arrival trace at ``--qps`` across ``--tenants`` weighted
-tenants, and reports p50/p99 latency, TTFT, tokens/s, the per-tenant
-fairness table, and the resolved ``kernel@bucket [target]`` schedule
-plan (pure cache-index lookups — no autotune at serve time).
-
-The pre-engine invocation (``--batch/--prompt-len/--new-tokens`` without
-``--qps``) still runs the one-shot static-batch :func:`repro.serve.generate`
-path, with a deprecation note pointing at the engine flags.
+tenants, and reports p50/p99 latency, TTFT, tokens/s, the paged KV
+pool's sharing and spills, the per-tenant fairness table, and the
+resolved ``kernel@bucket [target]`` schedule plan (pure cache-index
+lookups — no autotune at serve time).
 """
 
 from __future__ import annotations
 
 import argparse
-import time
 
 import jax
-import numpy as np
 
 from repro.configs import ARCHS, get_config
 from repro.launch.compile_cache import use_compile_cache
 from repro.models import for_config
-from repro.serve import generate
 
 
 def _print_plan(engine) -> None:
@@ -46,7 +40,7 @@ def _print_fairness(engine) -> None:
                                        for c in cols))
 
 
-def _engine_mode(args, cfg) -> None:
+def _serve(args, cfg) -> None:
     from repro.serve import ServeEngine, Tenant, TrafficConfig, run_load
 
     weights = ([float(w) for w in args.tenant_weights.split(",")]
@@ -62,7 +56,7 @@ def _engine_mode(args, cfg) -> None:
         cfg, params=params, max_batch=args.max_batch, max_seq=max_seq,
         block_size=args.block_size, kv_blocks=args.kv_blocks,
         tenants=tenants, schedule_cache=args.schedule_cache,
-        paged=args.paged, debug_invariants=args.debug_invariants,
+        debug_invariants=args.debug_invariants,
         on_missing="raise" if args.strict_schedules else "baseline")
     _print_plan(engine)
     if engine.counters.get("schedule_fallbacks"):
@@ -78,8 +72,7 @@ def _engine_mode(args, cfg) -> None:
         prefix_tokens=args.prefix_tokens, prefix_groups=args.prefix_groups)
     print(f"[serve] {args.arch}: {args.requests} requests @ {args.qps} qps, "
           f"{args.tenants} tenants, max_batch={args.max_batch}, "
-          f"max_seq={max_seq}, kv_blocks={engine.pool.num_blocks}, "
-          f"kv={'paged' if engine.paged else 'dense slots'}"
+          f"max_seq={max_seq}, kv_blocks={engine.pool.num_blocks}"
           + (f", shared prefix {args.prefix_tokens} tokens x "
              f"{args.prefix_groups} groups" if args.prefix_tokens else ""))
     report = run_load(engine, traffic)
@@ -102,49 +95,16 @@ def _engine_mode(args, cfg) -> None:
           f"{per('prefill_s', eng['first_tokens']):.2f} ms a request; host "
           f"{per('host_s', eng['passes']):.2f} ms, sync "
           f"{per('sync_s', eng['passes']):.2f} ms a pass")
-    if engine.paged:
-        pool = report["stats"]["pool"]
-        print(f"[serve] paged kv: max_active {eng['max_active']}, "
-              f"prefix hits {eng['prefix_hits']} "
-              f"({pool['shared_tokens_reused']} tokens reused), "
-              f"cow forks {eng['cow_forks']}, "
-              f"spills {eng['preempt_spills']}, "
-              f"high water {pool['high_water_blocks']}/"
-              f"{engine.pool.num_blocks} blocks, "
-              f"peak kv {engine.peak_kv_bytes() / 1e6:.1f} MB")
+    pool = report["stats"]["pool"]
+    print(f"[serve] paged kv: max_active {eng['max_active']}, "
+          f"prefix hits {eng['prefix_hits']} "
+          f"({pool['shared_tokens_reused']} tokens reused), "
+          f"cow forks {eng['cow_forks']}, "
+          f"spills {eng['preempt_spills']}, "
+          f"high water {pool['high_water_blocks']}/"
+          f"{engine.pool.num_blocks} blocks, "
+          f"peak kv {engine.peak_kv_bytes() / 1e6:.1f} MB")
     _print_fairness(engine)
-
-
-def _legacy_mode(args, cfg) -> None:
-    print("[serve] note: the flat --batch static path is deprecated; use "
-          "--qps/--tenants/--max-batch/--kv-blocks to run the "
-          "continuous-batching engine (ServeEngine.from_config)")
-    if args.schedule_cache:
-        from repro.launch.specs import kernel_fleet
-        from repro.serve.engine import schedule_plan
-        on_missing = "raise" if args.strict_schedules else "baseline"
-        for key, art in schedule_plan(kernel_fleet(cfg),
-                                      cache_dir=args.schedule_cache,
-                                      on_missing=on_missing).items():
-            name, bucket = key if isinstance(key, tuple) else (key, None)
-            label = name if bucket in (None, "default") else f"{name}@{bucket}"
-            state = (f"{art.speedup:.3f}x ({art.optimized_cycles:.0f} cycles)"
-                     if art is not None else "not optimized (-O3 baseline)")
-            print(f"[serve] schedule {label}: {state}")
-    model = for_config(cfg)
-    params = model.init_model(cfg, jax.random.PRNGKey(0))
-    rng = np.random.default_rng(0)
-    prompt = rng.integers(0, cfg.vocab, (args.batch, args.prompt_len),
-                          dtype=np.int32)
-    t0 = time.time()
-    out = jax.jit(lambda p, t: generate(p, cfg, t, args.new_tokens))(
-        params, prompt)
-    out.block_until_ready()
-    dt = time.time() - t0
-    toks = args.batch * args.new_tokens
-    print(f"[serve] {args.arch}: generated {toks} tokens in {dt:.2f}s "
-          f"({toks/dt:.1f} tok/s incl. compile)")
-    print("[serve] sample:", np.asarray(out[0, :24]).tolist())
 
 
 def main() -> None:
@@ -159,10 +119,8 @@ def main() -> None:
                     help="refuse to serve kernels without a cached schedule "
                          "(on_missing='raise'); default degrades them to "
                          "the -O3 baseline with a warning")
-    # engine mode
-    ap.add_argument("--qps", type=float, default=None,
-                    help="offered Poisson arrival rate; enables the "
-                         "continuous-batching engine")
+    ap.add_argument("--qps", type=float, default=8.0,
+                    help="offered Poisson arrival rate (requests/s)")
     ap.add_argument("--tenants", type=int, default=2,
                     help="number of weighted-fair tenants")
     ap.add_argument("--tenant-weights", default=None, metavar="W1,W2,...",
@@ -178,11 +136,6 @@ def main() -> None:
                     help="KV pool block granularity (tokens)")
     ap.add_argument("--requests", type=int, default=32,
                     help="trace length for the load generator")
-    ap.add_argument("--paged", action=argparse.BooleanOptionalAction,
-                    default=True,
-                    help="paged KV: block-table indirection with prefix "
-                         "sharing and copy-free preemption (default on; "
-                         "--no-paged restores the dense per-slot cache)")
     ap.add_argument("--prefix-tokens", type=int, default=0,
                     help="prepend a shared system prompt of this many tokens "
                          "to every request (Zipf-distributed over "
@@ -191,20 +144,16 @@ def main() -> None:
                     help="distinct shared prefixes for --prefix-tokens")
     ap.add_argument("--debug-invariants", action="store_true",
                     help="run KVBlockPool.check() every engine tick")
-    # shared with legacy static mode
-    ap.add_argument("--batch", type=int, default=4,
-                    help="[deprecated static path] batch rows")
-    ap.add_argument("--prompt-len", type=int, default=16)
-    ap.add_argument("--new-tokens", type=int, default=16)
+    ap.add_argument("--prompt-len", type=int, default=16,
+                    help="longest prompt in the trace (shortest is half)")
+    ap.add_argument("--new-tokens", type=int, default=16,
+                    help="longest answer in the trace (shortest is half)")
     args = ap.parse_args()
 
     cfg = get_config(args.arch, reduced=not args.full)
     if cfg.family == "encdec":
         raise SystemExit("use examples/serve_decode.py for the enc-dec arch")
-    if args.qps is not None:
-        _engine_mode(args, cfg)
-    else:
-        _legacy_mode(args, cfg)
+    _serve(args, cfg)
 
 
 if __name__ == "__main__":

@@ -1,21 +1,20 @@
 """Continuous-batching slot state and padded batch assembly.
 
-The engine owns ``max_batch`` cache rows (*slots*) plus one scratch row.
-Each active request occupies one slot; at every engine step the set of
-slots that should advance is gathered into a fixed-width ``(idx, tokens,
-pos)`` triple — inactive lanes padded onto the scratch row — so the
-jitted decode step compiles exactly once regardless of how many requests
-are in flight.
+The engine owns ``max_batch`` slots (recurrent state keeps one cache row
+per slot, plus one scratch row).  Each active request occupies one slot;
+at every engine step the set of slots that should advance is gathered
+into fixed-width lane arrays (slot index, block table, token, position,
+first writable position) — inactive lanes padded onto the scratch row —
+so the jitted decode step compiles exactly once regardless of how many
+requests are in flight.
 
 A slot's lifecycle is position-driven.  ``tokens`` holds the prompt plus
-everything generated (or teacher-forced on resume); ``pos`` is the next
-cache position to process.  A pass at position ``p`` feeds ``tokens[p]``,
+everything generated; ``pos`` is the next cache position to process.  A pass at position ``p`` feeds ``tokens[p]``,
 writes the KV cache at ``p``, and yields the model's prediction for
 ``p + 1``:
 
 * ``p + 1 < prompt_len`` → **prefill**: the prediction is discarded,
-  the next prompt token is teacher-forced.  (Resume tokens from a
-  preemption extend this teacher-forced region past the prompt.)
+  the next prompt token is teacher-forced.
 * otherwise → **decode**: the prediction is appended — the pass at
   ``p = prompt_len - 1`` emits the request's first generated token,
   which is what TTFT clocks.
@@ -46,7 +45,7 @@ class SlotState:
     """One admitted request bound to a cache row."""
     slot: int
     request: Request
-    tokens: List[int]                 # prompt + teacher-forced + generated
+    tokens: List[int]                 # prompt + generated
     prompt_len: int                   # teacher-forced prefix length
     target_len: int                   # len == done (prompt + max_new)
     pos: int = 0                      # next cache position to process
@@ -55,21 +54,19 @@ class SlotState:
     # suppressed — those positions live in blocks shared with the donor
     # request and already hold bit-identical K/V.
     write_start: int = 0
-    # Paged prefix registration bookkeeping (engine-owned).
-    registered_partial: bool = False
 
     @classmethod
     def admit(cls, slot: int, request: Request,
               shared_tokens: int = 0) -> "SlotState":
-        forced = list(request.prompt) + list(request.resume_tokens)
+        prompt = list(request.prompt)
         # With `shared_tokens` prompt positions mapped from already-resident
         # blocks, prefill skips to re-running only the last shared position
         # (recovering its logits without re-writing its KV) — the pass at
         # pos = shared_tokens - 1 behaves exactly as it would have in a
         # from-scratch prefill, so the stream stays bit-exact.
-        shared = max(0, min(int(shared_tokens), len(forced)))
-        return cls(slot=slot, request=request, tokens=list(forced),
-                   prompt_len=len(forced),
+        shared = max(0, min(int(shared_tokens), len(prompt)))
+        return cls(slot=slot, request=request, tokens=prompt,
+                   prompt_len=len(prompt),
                    target_len=len(request.prompt) + request.max_new_tokens,
                    pos=max(0, shared - 1), write_start=shared)
 
@@ -98,10 +95,6 @@ class SlotState:
     def done(self) -> bool:
         return len(self.tokens) >= self.target_len
 
-    def needs_tokens(self) -> int:
-        """Cache positions a pass at the current ``pos`` requires."""
-        return self.pos + 1
-
     def apply(self, next_token: int, max_seq: int) -> bool:
         """Account one completed pass at ``self.pos``; True when the pass
         emitted (appended) a generated token — the first such pass per
@@ -118,40 +111,18 @@ class SlotState:
         return appended
 
 
-def assemble(slots: Sequence[SlotState], max_batch: int,
-             scratch_slot: int) -> Optional[Tuple[np.ndarray, np.ndarray,
-                                                  np.ndarray, List[SlotState]]]:
-    """Build the fixed-width step arrays for the slots that advance now.
-
-    Returns ``(idx, tokens, pos, stepped)`` with all arrays of length
-    ``max_batch`` — unused lanes point at ``scratch_slot`` (duplicate
-    scatter writes there are benign: every lane writes the same garbage
-    row) — or None when nothing advances this step.
-    """
-    stepped = [s for s in slots if not s.done and not s.stalled]
-    if not stepped:
-        return None
-    idx = np.full((max_batch,), scratch_slot, dtype=np.int32)
-    tok = np.zeros((max_batch,), dtype=np.int32)
-    pos = np.zeros((max_batch,), dtype=np.int32)
-    for lane, s in enumerate(stepped):
-        idx[lane] = s.slot
-        tok[lane] = s.tokens[s.pos]
-        pos[lane] = s.pos
-    return idx, tok, pos, stepped
-
-
 def assemble_paged(slots: Sequence[SlotState], max_batch: int,
                    scratch_slot: int, blocks_per_slot: int, blocks_of
                    ) -> Optional[Tuple[np.ndarray, np.ndarray, np.ndarray,
                                        np.ndarray, np.ndarray,
                                        List[SlotState]]]:
-    """The paged analogue of :func:`assemble`: adds the padded fixed-width
-    block-table array and the per-lane first writable position.
+    """Build the fixed-width step arrays for the slots that advance now,
+    or None when nothing advances this step.
 
-    Returns ``(idx, table, tok, pos, write_start, stepped)`` — ``idx``
-    still points idle lanes at the scratch row (the *gather* of slot-major
-    recurrent rows must stay in bounds; the engine's scatter drops them),
+    Returns ``(idx, table, tok, pos, write_start, stepped)``, each array
+    ``max_batch`` lanes long — ``idx`` points idle lanes at the scratch
+    row (the *gather* of slot-major recurrent rows must stay in bounds;
+    the engine's scatter drops them),
     ``table`` is (max_batch, blocks_per_slot) physical block ids padded
     with 0 (idle lanes and positions past a request's grant are masked
     reads / suppressed writes), and ``write_start`` is ``NEVER_WRITE`` on

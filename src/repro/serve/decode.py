@@ -1,4 +1,4 @@
-"""Serving: KV caches + single-token decode steps for every family.
+"""Serving: KV caches + the single-token decode step for every family.
 
 Decode is deliberately *unrolled* over layers (unlike the scanned training
 path): each layer owns its cache pytree, so per-layer cache shapes can
@@ -6,7 +6,7 @@ differ — gemma's local layers keep a bounded ``window``-sized ring buffer
 while its global layers keep the full sequence; mamba layers keep an O(1)
 recurrent state.
 
-The steps take their weights in the serving form :func:`serving_params`
+The step takes its weights in the serving form :func:`serving_params`
 makes once, at load: every stacked layer group as a list of per-layer
 pytrees.  A step that sliced layer ``i`` out of a stacked array instead
 made the compiler re-lay out every layer's attention matrices on every
@@ -16,7 +16,7 @@ pass (a slice fusion per matrix and a copy per layer, growing with depth).
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +34,7 @@ from repro.nn.ssm import apply_ssm_decode, init_ssm_cache
 
 _NEG = -1e30
 
-# The stacked layer groups the decode steps index by layer; embedding, head,
+# The stacked layer groups the decode step indexes by layer; embedding, head,
 # final norm, ``shared_block`` and the encoder's ``enc_layers`` stay as the
 # model makes them.
 _LAYER_GROUPS = ("layers", "dense_layers", "dec_layers")
@@ -74,7 +74,7 @@ def _unstack(a, consume: bool) -> List:
 
 
 def serving_params(params: Dict, *, consume: bool = False) -> Dict:
-    """The weights as the decode steps take them: each stacked layer group
+    """The weights as the decode step takes them: each stacked layer group
     in ``params`` (``layers``, ``dense_layers``, ``dec_layers``) becomes a
     list of per-layer pytrees (leaf ``a`` -> ``a[i]`` in entry ``i``); the
     other entries pass through.
@@ -180,11 +180,11 @@ def init_paged_caches(cfg: ModelConfig, num_blocks: int, block_size: int,
     Attention/MLA entries hold ``num_blocks`` physical pages of
     ``block_size`` positions shared by every request — memory scales with
     the KV budget, not ``num_slots × max_seq``.  Windowed layers also
-    store absolute positions (their dense ring is reconstructed by a
+    store absolute positions (the slot cache's ring is reconstructed by a
     trailing-window gather): they spend ``max_seq/window`` more bytes per
-    layer than the dense ring, the price of sharing and remapping pages.
+    layer than the ring, the price of sharing and remapping pages.
     Recurrent entries keep ``num_slots + 1`` per-slot rows (scratch row
-    included) exactly as the dense path does."""
+    included)."""
     caches: List = []
     for kind in paged_cache_kinds(cfg):
         if kind == "slot":
@@ -211,7 +211,7 @@ def _positions(pos, batch: int) -> jax.Array:
 def _project_qkv(cfg: ModelConfig, p: Dict, x: jax.Array,
                  positions: jax.Array):
     """The decode-step q/k/v projection (+qk-norm, +rope) — shared by the
-    dense slot path and the paged block-table path so both produce
+    slot-cache path and the paged block-table path so both produce
     bit-identical per-token K/V before the cache write."""
     from repro.nn.core import apply_dense
     B = x.shape[0]
@@ -264,16 +264,11 @@ def _attn_decode(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict,
             mask = (k_positions <= pos) & (k_positions > pos - window)
 
     with jax.named_scope("attend"):
-        o = _masked_decode_attn(q, k_cache, v_cache, mask)
+        o = masked_decode_attention(q, k_cache, v_cache, mask)
     out = nn.out_project(p, o)
     return out, {"k": k_cache, "v": v_cache, **{kk: vv for kk, vv in
                                                 cache.items()
                                                 if kk not in ("k", "v")}}
-
-
-# The decode softmax now lives in nn.attention so the paged path shares it
-# op-for-op; kept under the historical local name for the call sites here.
-_masked_decode_attn = masked_decode_attention
 
 
 def _attn_decode_paged(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict,
@@ -282,7 +277,7 @@ def _attn_decode_paged(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict,
                        write_mask: jax.Array) -> Tuple[jax.Array, Dict]:
     """The paged analogue of :func:`_attn_decode`: scatter this token's K/V
     into the physical pages (rows of ``KH * D``) through the block table,
-    then attend over a gather whose width matches the dense layer's cache
+    then attend over a gather whose width matches the slot cache's
     length — so the outputs are bit-identical to the slot path's."""
     B = x.shape[0]
     positions = _positions(pos, B)
@@ -303,7 +298,7 @@ def _attn_decode_paged(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict,
 # decode step
 # ---------------------------------------------------------------------------
 #
-# Both steps take ``params`` in the serving form (:func:`serving_params`).
+# ``decode_step`` takes ``params`` in the serving form (:func:`serving_params`).
 # Named scopes (``layer<i>/attn/kv_write``, ``layer<i>/attn/attend``,
 # ``layer<i>/mlp``, ``head``) land in the compiled HLO's ``op_name``
 # metadata, so a device trace's ops map back to source.
@@ -335,105 +330,58 @@ def _head(cfg: ModelConfig, params: Dict, x: jax.Array) -> jax.Array:
         return logits[:, 0]
 
 
+
+
+class Pages(NamedTuple):
+    """How a pass reaches the engine's paged caches (see
+    :func:`paged_cache_kinds`): each lane's ``block_table`` row (B,
+    blocks_per_slot) of physical page ids, the (B,) ``write_mask`` that
+    suppresses the page write for idle lanes and for shared-prefix re-run
+    passes whose target position is owned by a shared block (the stored
+    value is bit-identical, so skipping the write avoids a spurious
+    copy-on-write fork without changing any attention operand), and the
+    static ``max_seq`` that sets the width of the gathered window."""
+    block_table: jax.Array
+    write_mask: jax.Array
+    max_seq: int
+
+
+def _attend_cache(cfg: ModelConfig, p: Dict, h: jax.Array, cache: Dict, pos,
+                  window: int, pages: Optional[Pages]
+                  ) -> Tuple[jax.Array, Dict]:
+    """One attention layer against its cache: a slot cache row per lane
+    when ``pages`` is None, else the block-major pages through the block
+    table.  GQA/MHA (including the hybrid's shared block) or MLA."""
+    if cfg.mla:
+        if pages is None:
+            return apply_mla_decode(p, h, cache, pos, mla_config(cfg))
+        return apply_mla_paged_decode(p, h, cache, pages.block_table, pos,
+                                      mla_config(cfg), width=pages.max_seq,
+                                      write_mask=pages.write_mask)
+    if pages is None:
+        return _attn_decode(cfg, p, h, cache, pos, window)
+    return _attn_decode_paged(cfg, p, h, cache, pages.block_table, pos,
+                              window, pages.max_seq, pages.write_mask)
+
+
 def decode_step(params: Dict, caches: List, token: jax.Array, pos,
-                cfg: ModelConfig, mesh=None,
-                enc_out: Optional[jax.Array] = None
-                ) -> Tuple[jax.Array, List]:
+                cfg: ModelConfig, *, pages: Optional[Pages] = None,
+                mesh=None) -> Tuple[jax.Array, List]:
     """token (B, 1) int32 -> logits (B, vocab); updates caches.
 
     ``pos`` is a scalar (all rows decode the same position — the static
     generate path) or a ``(B,)`` int vector of per-row positions (the
     continuous-batching ragged path; each row reads/writes its own cache
     position).  SSM/recurrent layers carry no position and advance one
-    step per call either way."""
-    x = nn.apply_embedding(params["embed"], token).astype(jnp.dtype(cfg.dtype))
-    if cfg.name.startswith("gemma"):
-        x = x * (cfg.d_model ** 0.5)   # gemma scales embeddings (as forward)
-    new_caches = list(caches)
-    wins = [int(w) for w in window_schedule(cfg)] \
-        if cfg.family in ("dense", "moe") else []
+    step per call either way.
 
-    if cfg.family in ("dense", "moe"):
-        dense_head = cfg.first_dense_layers if cfg.family == "moe" else 0
-        for li in range(cfg.n_layers):
-            with jax.named_scope(f"layer{li}"):
-                p = _dense_layer_params(params, li, dense_head)
-                with jax.named_scope("attn"):
-                    h = _apply_norm(cfg, p["ln1"], x)
-                    if cfg.mla:
-                        a, new_caches[li] = apply_mla_decode(
-                            p["attn"], h, caches[li], pos, mla_config(cfg))
-                    else:
-                        a, new_caches[li] = _attn_decode(
-                            cfg, p["attn"], h, caches[li], pos, wins[li])
-                    x = x + a
-                x = _mlp_block(cfg, p, x, li >= dense_head, mesh)
-
-    elif cfg.family == "ssm":
-        for li in range(cfg.n_layers):
-            p = params["layers"][li]
-            h = _apply_norm(cfg, p["ln1"], x)
-            y, new_caches[li] = apply_ssm_decode(p["ssm"], h, caches[li],
-                                                 ssm_config(cfg))
-            x = x + y
-
-    elif cfg.family == "hybrid":
-        k = cfg.shared_attn_every
-        shared = params["shared_block"]
-        g = 0
-        for li in range(cfg.n_layers):
-            p = params["layers"][li]
-            h = _apply_norm(cfg, p["ln1"], x)
-            y, new_caches[li] = apply_ssm_decode(p["ssm"], h, caches[li],
-                                                 ssm_config(cfg))
-            x = x + y
-            if (li + 1) % k == 0:
-                ci = cfg.n_layers + g
-                h = _apply_norm(cfg, shared["ln1"], x)
-                a, new_caches[ci] = _attn_decode(cfg, shared["attn"], h,
-                                                 caches[ci], pos, NO_WINDOW)
-                x = x + a
-                x = x + _apply_mlp(cfg, shared["mlp"],
-                                   _apply_norm(cfg, shared["ln2"], x))
-                g += 1
-
-    elif cfg.family == "encdec":
-        for li in range(cfg.dec_layers):
-            p = params["dec_layers"][li]
-            h = _apply_norm(cfg, p["ln1"], x)
-            a, new_caches[li] = _attn_decode(cfg, p["attn"], h, caches[li],
-                                             pos, NO_WINDOW)
-            x = x + a
-            h = _apply_norm(cfg, p["ln_x"], x)
-            q = nn.qkv_project(p["cross"], h, cfg.n_heads, cfg.n_kv_heads,
-                               cfg.head_dim)[0]
-            Se = caches[li]["ck"].shape[1]
-            o = _masked_decode_attn(q, caches[li]["ck"], caches[li]["cv"],
-                                    jnp.ones((Se,), bool))
-            x = x + nn.out_project(p["cross"], o)
-            x = x + _apply_mlp(cfg, p["mlp"], _apply_norm(cfg, p["ln2"], x))
-    else:
-        raise ValueError(cfg.family)
-
-    return _head(cfg, params, x), new_caches
-
-
-def paged_decode_step(params: Dict, caches: List, block_table: jax.Array,
-                      token: jax.Array, pos: jax.Array,
-                      write_mask: jax.Array, cfg: ModelConfig, max_seq: int,
-                      mesh=None) -> Tuple[jax.Array, List]:
-    """token (B, 1) int32 -> logits (B, vocab) through the block table.
-
-    The paged analogue of :func:`decode_step`: ``"paged"`` cache entries
-    (see :func:`paged_cache_kinds`) are the full block-major page arrays —
-    every lane reads/writes its own pages through its ``block_table`` row
-    at its own ragged ``pos`` — while ``"slot"`` entries arrive already
-    gathered to (B, ...) rows (the engine scatters them back).
-    ``write_mask`` (B,) suppresses the page scatter for idle lanes and for
-    shared-prefix re-run passes whose target position is owned by a
-    shared block (the stored value is bit-identical, so skipping the
-    write avoids a spurious copy-on-write fork without changing any
-    attention operand)."""
+    With ``pages`` None every cache entry holds one row per lane
+    (:func:`init_caches`).  With ``pages`` given the caches are the
+    engine's (:func:`init_paged_caches`): ``"paged"`` entries are the
+    whole page arrays, which every lane reads and writes through its
+    block-table row, and ``"slot"`` entries arrive already gathered to
+    (B, ...) rows (the engine scatters them back).  Enc-dec decodes from
+    slot caches only."""
     x = nn.apply_embedding(params["embed"], token).astype(jnp.dtype(cfg.dtype))
     if cfg.name.startswith("gemma"):
         x = x * (cfg.d_model ** 0.5)   # gemma scales embeddings (as forward)
@@ -447,15 +395,8 @@ def paged_decode_step(params: Dict, caches: List, block_table: jax.Array,
                 p = _dense_layer_params(params, li, dense_head)
                 with jax.named_scope("attn"):
                     h = _apply_norm(cfg, p["ln1"], x)
-                    if cfg.mla:
-                        a, new_caches[li] = apply_mla_paged_decode(
-                            p["attn"], h, caches[li], block_table, pos,
-                            mla_config(cfg), width=max_seq,
-                            write_mask=write_mask)
-                    else:
-                        a, new_caches[li] = _attn_decode_paged(
-                            cfg, p["attn"], h, caches[li], block_table, pos,
-                            wins[li], max_seq, write_mask)
+                    a, new_caches[li] = _attend_cache(
+                        cfg, p["attn"], h, caches[li], pos, wins[li], pages)
                     x = x + a
                 x = _mlp_block(cfg, p, x, li >= dense_head, mesh)
 
@@ -480,14 +421,30 @@ def paged_decode_step(params: Dict, caches: List, block_table: jax.Array,
             if (li + 1) % k == 0:
                 ci = cfg.n_layers + g
                 h = _apply_norm(cfg, shared["ln1"], x)
-                a, new_caches[ci] = _attn_decode_paged(
-                    cfg, shared["attn"], h, caches[ci], block_table, pos,
-                    NO_WINDOW, max_seq, write_mask)
+                a, new_caches[ci] = _attend_cache(
+                    cfg, shared["attn"], h, caches[ci], pos, NO_WINDOW, pages)
                 x = x + a
                 x = x + _apply_mlp(cfg, shared["mlp"],
                                    _apply_norm(cfg, shared["ln2"], x))
                 g += 1
+
+    elif cfg.family == "encdec" and pages is None:
+        for li in range(cfg.dec_layers):
+            p = params["dec_layers"][li]
+            h = _apply_norm(cfg, p["ln1"], x)
+            a, new_caches[li] = _attn_decode(cfg, p["attn"], h, caches[li],
+                                             pos, NO_WINDOW)
+            x = x + a
+            h = _apply_norm(cfg, p["ln_x"], x)
+            q = nn.qkv_project(p["cross"], h, cfg.n_heads, cfg.n_kv_heads,
+                               cfg.head_dim)[0]
+            Se = caches[li]["ck"].shape[1]
+            o = masked_decode_attention(q, caches[li]["ck"], caches[li]["cv"],
+                                        jnp.ones((Se,), bool))
+            x = x + nn.out_project(p["cross"], o)
+            x = x + _apply_mlp(cfg, p["mlp"], _apply_norm(cfg, p["ln2"], x))
     else:
-        raise ValueError(cfg.family)
+        raise ValueError(f"{cfg.family!r} has no "
+                         f"{'paged ' if pages is not None else ''}decode step")
 
     return _head(cfg, params, x), new_caches

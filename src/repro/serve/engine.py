@@ -1,25 +1,28 @@
-"""Serve engine: continuous batching over a slot-pooled KV cache.
+"""Serve engine: continuous batching over a paged KV cache.
 
-:class:`ServeEngine` is the production serving path.  It owns
-``max_batch`` cache rows (*slots*) plus one scratch row, a
-:class:`~repro.serve.pool.KVBlockPool` accounting for the cache
-positions those rows hold, and a
-:class:`~repro.serve.scheduler.FairScheduler` deciding which tenant's
-request gets the next free slot.  Every engine step:
+:class:`ServeEngine` is the serving path.  Its KV cache is block-major
+pages (:func:`~repro.serve.decode.init_paged_caches`) that a
+:class:`~repro.serve.pool.KVBlockPool` hands out to requests through
+block tables, with prefix sharing and copy-on-write for attention
+families; recurrent state keeps ``max_batch`` per-slot rows plus one
+scratch row.  A :class:`~repro.serve.scheduler.FairScheduler` decides
+which tenant's request gets the next free slot.  Every engine step:
 
 1. **evict** — finished slots release their pool blocks and stamp
    latency/TTFT on their :class:`~repro.serve.scheduler.Request`;
 2. **admit** — the fair scheduler fills freed slots (chunked prefill:
    new prompts are teacher-forced through the same decode step, so
    admission needs no separate prefill kernel and a long prompt never
-   blocks the running decodes);
+   blocks the running decodes; a prompt whose prefix pages are resident
+   maps them and skips their prefill);
 3. **advance** — every live slot that the pool can grow moves one
-   position through ONE fixed-shape jitted decode pass (slots gather
-   their cache rows, step at per-row positions, scatter back; idle
+   position through ONE fixed-shape jitted decode pass (lanes reach
+   their pages through the block table at per-row positions; idle
    lanes pad onto the scratch row, so the step compiles exactly once);
 4. **preempt** — if nothing could advance (pool exhausted), the
-   youngest request is returned to its tenant queue with its generated
-   tokens as teacher-forced resume state (recompute preemption).
+   youngest request's pages are copied to the host and its blocks freed;
+   it goes back to the head of its tenant queue and later resumes from
+   the same position by re-uploading them.
 
 Each stage runs inside a host span ``repro.serve.<stage>`` (:func:`span`;
 ``step`` holds ``evict``, ``admit``, ``pass`` and ``preempt``; ``pass``
@@ -39,7 +42,7 @@ static-batch convenience wrapper (one ``lax.scan`` over the same
 ``decode_step``); ``ServeEngine.generate`` is its engine-backed
 equivalent.  Under greedy decoding the two are bit-exact per request
 for batch-independent (non-MoE-capacity) configs — see
-``tests/test_serve_engine.py``.
+``tests/test_serve_paged.py``.
 """
 
 from __future__ import annotations
@@ -57,16 +60,15 @@ from repro.configs.base import ModelConfig
 from repro.dist import sharding as shd
 from repro.sched.cache import ScheduleCache
 from repro.sched.lowering import schedule_plan  # noqa: F401  (serve-facing API)
-from repro.serve.batching import (NEVER_WRITE, SlotState, assemble,
-                                  assemble_paged)
-from repro.serve.decode import (decode_step, init_caches, init_paged_caches,
-                                paged_cache_kinds, paged_decode_step,
+from repro.serve.batching import SlotState, assemble_paged
+from repro.serve.decode import (Pages, decode_step, init_caches,
+                                init_paged_caches, paged_cache_kinds,
                                 serving_params)
 from repro.serve.pool import KVBlockPool, PoolCapacityError, PoolError  # noqa: F401
 from repro.serve.scheduler import (DEFAULT_TENANT, FairScheduler, Request,
                                    Tenant)
 
-# One compiled (step, reset) pair per (config, mesh[, paged geometry]):
+# One compiled (step, reset) pair per (config, mesh, max_seq):
 # engines in a sweep share tracing/compilation instead of re-jitting per
 # instance.
 _STEP_FNS: Dict = {}
@@ -82,46 +84,18 @@ def _cfg_key(cfg: ModelConfig) -> str:
     return json.dumps(dataclasses.asdict(cfg), sort_keys=True, default=str)
 
 
-def _step_fns(cfg: ModelConfig, mesh):
-    key = (_cfg_key(cfg), None if mesh is None else id(mesh))
-    if key not in _STEP_FNS:
-        def step(params, caches, idx, tok, pos):
-            # Gather the advancing rows, step them at their own positions,
-            # scatter back.  Idle lanes (gathered from the scratch row) are
-            # routed to an out-of-range row and dropped — ONE masked
-            # scatter, no duplicate scratch-row writes to race under
-            # donated buffers.
-            rows = jax.tree.map(lambda a: a[idx], caches)
-            logits, new_rows = decode_step(params, rows, tok[:, None], pos,
-                                           cfg, mesh=mesh)
-            scratch = jax.tree.leaves(caches)[0].shape[0] - 1
-            sidx = jnp.where(idx == scratch, scratch + 1, idx)
-            caches = jax.tree.map(
-                lambda a, r: a.at[sidx].set(r.astype(a.dtype), mode="drop"),
-                caches, new_rows)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), caches
-
-        def reset(caches, idx):
-            # Zero rows for newly admitted requests: attention masks hide
-            # a previous occupant's KV, but SSM/conv recurrent state would
-            # otherwise leak across requests.
-            return jax.tree.map(lambda a: a.at[idx].set(0), caches)
-
-        _STEP_FNS[key] = (jax.jit(step), jax.jit(reset))
-    return _STEP_FNS[key]
-
-
 def _paged_step_fns(cfg: ModelConfig, mesh, max_seq: int):
-    """(step, reset) for the paged path.  ``"paged"`` cache entries pass
-    through whole (lanes address them via the block table); ``"slot"``
-    entries (recurrent state) gather/scatter by slot exactly as the dense
-    path — with idle lanes dropped by the same out-of-range trick.
+    """(step, reset) for the engine's caches.  ``"paged"`` cache entries
+    pass through whole (lanes address them via the block table);
+    ``"slot"`` entries (recurrent state) are gathered by slot and
+    scattered back, with idle lanes (gathered from the scratch row)
+    routed to an out-of-range row and dropped — one masked scatter, no
+    duplicate scratch-row writes to race under the donated buffers.
 
     ``step`` donates the caches (argument 1): the pages are written in
     place, and the caller must rebind its cache list to the returned one,
     as :meth:`ServeEngine._pass` does."""
-    key = (_cfg_key(cfg), None if mesh is None else id(mesh),
-           "paged", int(max_seq))
+    key = (_cfg_key(cfg), None if mesh is None else id(mesh), int(max_seq))
     if key not in _STEP_FNS:
         kinds = paged_cache_kinds(cfg)
 
@@ -129,9 +103,9 @@ def _paged_step_fns(cfg: ModelConfig, mesh, max_seq: int):
             write_mask = pos >= wstart
             rows = [jax.tree.map(lambda a: a[idx], c) if kind == "slot"
                     else c for c, kind in zip(caches, kinds)]
-            logits, new = paged_decode_step(params, rows, table, tok[:, None],
-                                            pos, write_mask, cfg, max_seq,
-                                            mesh=mesh)
+            logits, new = decode_step(params, rows, tok[:, None], pos, cfg,
+                                      pages=Pages(table, write_mask, max_seq),
+                                      mesh=mesh)
             out = []
             for c, n, kind in zip(caches, new, kinds):
                 if kind == "slot":
@@ -150,10 +124,11 @@ def _paged_step_fns(cfg: ModelConfig, mesh, max_seq: int):
             donate_argnums=(0,))
 
         def reset(caches, idx):
-            # Only recurrent slot rows need zeroing on admission; page
-            # contents are never read unmasked before being written, so
-            # the pages stay outside the jit: an identity output would
-            # copy the whole pool.
+            # Only recurrent slot rows need zeroing on admission (a
+            # previous occupant's SSM/conv state would otherwise leak
+            # into the new request); page contents are never read
+            # unmasked before being written, so the pages stay outside
+            # the jit: an identity output would copy the whole pool.
             if not slots:
                 return caches
             rows = iter(zero_rows([caches[i] for i in slots], idx))
@@ -194,6 +169,13 @@ class ServeEngine:
     only into an idle engine, wait for the whole gang to finish) — the
     baseline ``bench_serve.py`` compares continuous batching against.
 
+    Preemption spills the victim's pages to the host and resumes it by
+    re-uploading them (:class:`_Spill`), so no position runs twice.
+    Prefix sharing is on exactly for the attention families (``dense``,
+    ``moe``), whose page contents are a pure function of the token
+    prefix; recurrent state is per request.  ``paged`` accepts only
+    True: the dense per-slot mode was removed.
+
     The engine takes ownership of ``params``: it lays them out once in the
     serving form (:func:`~repro.serve.decode.serving_params`, after placing
     them on ``mesh`` if one is given), deleting each stacked layer array as
@@ -207,7 +189,7 @@ class ServeEngine:
                  tenants: Optional[Sequence[Tenant]] = None,
                  starvation_bound: int = 8, prefill_chunk: int = 4,
                  admission: str = "continuous",
-                 paged: bool = False, share_prefix: bool = True,
+                 paged: bool = True,
                  debug_invariants: bool = False,
                  schedule_cache: Optional[Union[ScheduleCache, str]] = None,
                  on_missing: str = "baseline",
@@ -220,6 +202,10 @@ class ServeEngine:
                              f"got {admission!r}")
         if max_batch < 1 or prefill_chunk < 0:
             raise ValueError("need max_batch >= 1 and prefill_chunk >= 0")
+        if not paged:
+            raise ValueError("the dense per-slot engine mode was removed: "
+                             "ServeEngine always serves from paged KV "
+                             "caches (drop paged=False)")
         self.cfg = cfg
         self.max_batch = int(max_batch)
         self.max_seq = int(max_seq)
@@ -227,13 +213,11 @@ class ServeEngine:
         self.admission = admission
         self.prefill_chunk = int(prefill_chunk)
         self.mesh = mesh
-        self.paged = bool(paged)
         self.debug_invariants = bool(debug_invariants)
         # Prefix sharing needs the cache content at a position to be a pure
         # function of the token prefix — true for attention/MLA pages,
         # false for recurrent state (ssm/hybrid carry per-request rows).
-        self.share_prefix = (self.paged and bool(share_prefix)
-                             and cfg.family in ("dense", "moe"))
+        self._shares_prefix = cfg.family in ("dense", "moe")
 
         self.pool = KVBlockPool(self.max_batch, self.max_seq,
                                 block_size=block_size, num_blocks=kv_blocks)
@@ -251,23 +235,15 @@ class ServeEngine:
         with span("layout"):
             self.params = serving_params(params, consume=True)
         layout_s = time.perf_counter() - t
-        if self.paged:
-            self._kinds = paged_cache_kinds(cfg)
-            self.caches = init_paged_caches(cfg, self.pool.num_blocks,
-                                            self.pool.block_size,
-                                            self.max_batch)
-        else:
-            self._kinds = None
-            self.caches = init_caches(cfg, self.max_batch + 1, self.max_seq)
+        self._kinds = paged_cache_kinds(cfg)
+        self.caches = init_paged_caches(cfg, self.pool.num_blocks,
+                                        self.pool.block_size, self.max_batch)
         if mesh is not None:
             self.caches = jax.device_put(
                 self.caches, shd.kv_pool_shardings(cfg, self.caches, mesh,
-                                                   kinds=self._kinds))
-        if self.paged:
-            self._step_fn, self._reset_fn = _paged_step_fns(cfg, mesh,
-                                                            self.max_seq)
-        else:
-            self._step_fn, self._reset_fn = _step_fns(cfg, mesh)
+                                                   self._kinds))
+        self._step_fn, self._reset_fn = _paged_step_fns(cfg, mesh,
+                                                        self.max_seq)
 
         if isinstance(schedule_cache, str):
             schedule_cache = ScheduleCache(schedule_cache)
@@ -397,9 +373,6 @@ class ServeEngine:
     # -- internals -----------------------------------------------------------
 
     def _admissible(self, req: Request) -> bool:
-        if not self.paged:
-            return self.pool.can_admit(
-                len(req.prompt) + len(req.resume_tokens))
         if req.spill is not None:
             # Re-granting just the spilled pages is not enough: the request
             # must also be able to grow into its next write position, or a
@@ -407,7 +380,7 @@ class ServeEngine:
             # (resume → everyone blocked → preempt youngest → resume …).
             return self.pool.can_resume(
                 self.pool.blocks_for(req.spill.pos + 1))
-        if self.share_prefix:
+        if self._shares_prefix:
             return self.pool.can_admit_shared(req.prompt)
         return self.pool.can_admit(len(req.prompt))
 
@@ -420,7 +393,7 @@ class ServeEngine:
             req = self.scheduler.admit_next(predicate=self._admissible)
             if req is None:
                 break
-            if self.paged and req.spill is not None:
+            if req.spill is not None:
                 table = self.pool.alloc_resume(req.id, req.spill.n_blocks)
                 self._active.append(SlotState.resume(
                     table.slot, req, tokens=req.spill.tokens,
@@ -428,18 +401,14 @@ class ServeEngine:
                     target_len=req.spill.target_len))
                 resumed.append((table, req.spill))
                 req.spill = None
-            elif self.paged and self.share_prefix:
+            elif self._shares_prefix:
                 table = self.pool.alloc_shared(req.id, req.prompt)
                 if table.shared_tokens:
                     self.counters["prefix_hits"] += 1
                 self._active.append(SlotState.admit(
                     table.slot, req, shared_tokens=table.shared_tokens))
-            elif self.paged:
-                table = self.pool.alloc(req.id, len(req.prompt))
-                self._active.append(SlotState.admit(table.slot, req))
             else:
-                table = self.pool.alloc(
-                    req.id, len(req.prompt) + len(req.resume_tokens))
+                table = self.pool.alloc(req.id, len(req.prompt))
                 self._active.append(SlotState.admit(table.slot, req))
             fresh.append(table.slot)
             req.admit_time = time.monotonic()
@@ -462,20 +431,11 @@ class ServeEngine:
             for s in self._active:
                 if s.done or s.stalled:
                     continue
-                if self.paged:
-                    write = s.pos >= s.write_start
-                    if self.pool.can_advance(s.request.id, s.pos,
-                                             write=write):
-                        pair = self.pool.advance(s.request.id, s.pos,
-                                                 write=write)
-                        if pair is not None:
-                            forks.append(pair)
-                        cand.append(s)
-                    else:
-                        s.stalled = True
-                        self.counters["stalls"] += 1
-                elif self.pool.can_ensure(s.request.id, s.needs_tokens()):
-                    self.pool.ensure(s.request.id, s.needs_tokens())
+                write = s.pos >= s.write_start
+                if self.pool.can_advance(s.request.id, s.pos, write=write):
+                    pair = self.pool.advance(s.request.id, s.pos, write=write)
+                    if pair is not None:
+                        forks.append(pair)
                     cand.append(s)
                 else:
                     s.stalled = True
@@ -484,16 +444,13 @@ class ServeEngine:
             with span("cow_copy"):
                 self._copy_blocks(forks)
         with span("assemble"):
-            if self.paged:
-                asm = assemble_paged(
-                    cand, self.max_batch, self.scratch_slot,
-                    self.pool.blocks_per_slot,
-                    lambda s: self.pool.table(s.request.id).blocks)
-            else:
-                asm = assemble(cand, self.max_batch, self.scratch_slot)
+            asm = assemble_paged(
+                cand, self.max_batch, self.scratch_slot,
+                self.pool.blocks_per_slot,
+                lambda s: self.pool.table(s.request.id).blocks)
         if asm is None:
             return 0
-        *lanes, stepped = asm     # paged: idx, table, tok, pos, wstart
+        *lanes, stepped = asm     # idx, table, tok, pos, wstart
         with span("dispatch"):
             nxt, self.caches = self._step_fn(
                 self.params, self.caches, *(jnp.asarray(a) for a in lanes))
@@ -509,7 +466,7 @@ class ServeEngine:
                     s.request.first_token_time = now
                     self.counters["prefill_s"] += now - s.request.admit_time
                     self.counters["first_tokens"] += 1
-                if self.share_prefix:
+                if self._shares_prefix:
                     self.pool.commit(s.request.id, s.tokens, s.pos,
                                      prompt_len=s.prompt_len)
         self.counters["passes"] += 1
@@ -573,47 +530,29 @@ class ServeEngine:
         victim = max(self._active,
                      key=lambda s: (s.request.submit_time, s.request.id))
         req = victim.request
-        generated = list(victim.generated)
-        if self.paged:
-            if self.pool.blocks_for(victim.pos + 1) > self.pool.num_blocks:
-                # It could never advance even owning the whole pool:
-                # finish it truncated rather than starve the queue.
-                self._active.remove(victim)
-                self.pool.free(req.id)
-                req.truncated = True
-                req.output = generated
-                req.finish_time = time.monotonic()
-                self.scheduler.release(req, served_tokens=len(generated))
-                self.finished.append(req)
-                return
-            # Copy-free preemption: spill the pages block-by-block, free
-            # the device blocks, resume later by remap + upload — no
-            # teacher-forced recompute of the prefill.
-            req.spill = self._spill(victim)
+        if self.pool.blocks_for(victim.pos + 1) > self.pool.num_blocks:
+            # It could never advance even owning the whole pool: finish
+            # it truncated rather than starve the queue.
             self._active.remove(victim)
             self.pool.free(req.id)
-            req.preemptions += 1
-            self.scheduler.release(req, served_tokens=0)
-            self.scheduler.requeue_front(req)
-            self.counters["preemptions"] += 1
-            self.counters["preempt_spills"] += 1
-            return
-        self._active.remove(victim)
-        self.pool.free(req.id)
-        if len(req.prompt) + len(generated) >= self.max_seq:
-            # Resuming would need the whole cache for teacher-forcing:
-            # finish it truncated rather than starve the queue.
+            generated = list(victim.generated)
             req.truncated = True
             req.output = generated
             req.finish_time = time.monotonic()
             self.scheduler.release(req, served_tokens=len(generated))
             self.finished.append(req)
             return
-        req.resume_tokens = generated
+        # Copy-free preemption: spill the pages block-by-block, free the
+        # device blocks, resume later by remap + upload — no teacher-forced
+        # recompute of the prefill.
+        req.spill = self._spill(victim)
+        self._active.remove(victim)
+        self.pool.free(req.id)
         req.preemptions += 1
         self.scheduler.release(req, served_tokens=0)
         self.scheduler.requeue_front(req)
         self.counters["preemptions"] += 1
+        self.counters["preempt_spills"] += 1
 
     # -- reporting -----------------------------------------------------------
 
@@ -622,19 +561,15 @@ class ServeEngine:
         return len(self._active)
 
     def kv_bytes_allocated(self) -> int:
-        """Device bytes backing the KV cache pytree.  Paged mode scales
-        with ``kv_blocks × block_size``; dense mode with
-        ``(max_batch + 1) × max_seq`` regardless of occupancy — the
-        memory-proportionality win the paged layout exists for."""
+        """Device bytes backing the KV cache pytree: the pages scale with
+        ``kv_blocks × block_size``, recurrent rows with ``max_batch + 1``."""
         return int(sum(leaf.size * jnp.dtype(leaf.dtype).itemsize
                        for leaf in jax.tree.leaves(self.caches)))
 
     def peak_kv_bytes(self) -> int:
-        """High-water KV footprint actually addressed: paged mode scales
-        the page bytes by the pool's high-water block count; dense mode
-        pins the full allocation from construction."""
-        if not self.paged:
-            return self.kv_bytes_allocated()
+        """High-water KV footprint actually addressed: the page bytes
+        scaled by the pool's high-water block count, plus the recurrent
+        slot rows."""
         paged_bytes = slot_bytes = 0
         for c, kind in zip(self.caches, self._kinds):
             n = sum(leaf.size * jnp.dtype(leaf.dtype).itemsize

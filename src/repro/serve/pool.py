@@ -1,7 +1,6 @@
 """KV-cache block pool: the serve engine's memory manager.
 
-In **paged** mode (the production path) the pool's blocks ARE the
-physical KV storage: the cache arrays are block-major
+The pool's blocks ARE the physical KV storage: the cache arrays are block-major
 ``(num_blocks, block_size, ...)`` pages (see
 :func:`repro.serve.decode.init_paged_caches`) and each request addresses
 its sequence through its :class:`BlockTable` — a list of physical block
@@ -25,9 +24,9 @@ ids.  That turns the pool from accounting into a real memory manager:
   :meth:`alloc_resume` grants fresh private blocks to upload into —
   copy-free resume, no teacher-forced recompute.
 
-In **dense** mode (``serve.engine`` with ``paged=False``) the same pool
-runs with every refcount at 1 and no registry — the original
-accounting-only behavior over slot-major cache rows.
+Families whose cache is not a pure function of the token prefix
+(``ssm`` and ``hybrid`` carry recurrent state per request) admit through
+:meth:`alloc`: every refcount stays at 1 and nothing is registered.
 
 Blocks are ``block_size`` tokens each and come from one global free list
 (``num_blocks`` total).  ``num_blocks`` may be *smaller* than
@@ -45,7 +44,7 @@ write into the last position (the old out-of-range bug).
 
 Placement of the backing cache arrays onto a device mesh goes through the
 existing dist-layer rules — :func:`repro.dist.sharding.kv_pool_shardings`
-(block-major rules for paged leaves, decode rules for slot rows).
+(block-major rules for pages, decode-cache rules for recurrent slot rows).
 """
 
 from __future__ import annotations
@@ -158,14 +157,6 @@ class KVBlockPool:
         shared_blocks, _ = self.match_prefix(prompt)
         fresh = self.blocks_for(len(prompt)) - len(shared_blocks)
         return fresh <= len(self._free_blocks)
-
-    def can_ensure(self, request_id, tokens: int) -> bool:
-        """Whether ``ensure`` for this coverage would succeed right now."""
-        t = self._tables.get(request_id)
-        if t is None or tokens > self.max_seq:
-            return False
-        need = self.blocks_for(tokens) - t.num_blocks
-        return need <= len(self._free_blocks)
 
     def can_resume(self, n_blocks: int) -> bool:
         """Whether a spilled request's pages could be re-granted now."""

@@ -56,13 +56,9 @@ class Request:
     finish_time: Optional[float] = None
     truncated: bool = False
     preemptions: int = 0
-    # Tokens to teacher-force on (re)admission beyond the prompt — set by
-    # recompute preemption (dense path) so generation resumes
-    # bit-identically.
-    resume_tokens: List[int] = dataclasses.field(default_factory=list)
-    # Paged copy-free preemption payload (engine's _Spill: host copies of
-    # the request's KV pages) — re-admission remaps and uploads instead
-    # of recomputing the prefill.
+    # Copy-free preemption payload (engine's _Spill: host copies of the
+    # request's KV pages) — re-admission remaps and uploads instead of
+    # recomputing the prefill.
     spill: Optional[object] = dataclasses.field(default=None, repr=False)
 
     @property

@@ -204,7 +204,7 @@ for mesh in (None, make_host_mesh(model=2)):
     own = jax.tree.map(jax.numpy.copy, params)
     e = ServeEngine.from_config(cfg, params=own, max_batch=2, max_seq=24,
                                 block_size=4, kv_blocks=7, prefill_chunk=1,
-                                paged=True, mesh=mesh)
+                                mesh=mesh)
     placed = jax.tree.leaves(e.caches)
     a = e.submit(prompt, 12)
     while a.first_token_time is None:
@@ -241,7 +241,7 @@ cfg = get_config("qwen1.5-4b", reduced=True)
 mesh = make_host_mesh(model=2)
 stacked = shd.param_shardings(lm.model_spec(cfg), mesh)["layers"]
 e = ServeEngine.from_config(cfg, max_batch=2, max_seq=24, block_size=4,
-                            paged=True, mesh=mesh)
+                            mesh=mesh)
 assert len(e.params["layers"]) == cfg.n_layers
 split = 0
 for layer in e.params["layers"]:

@@ -1,11 +1,11 @@
 """Paged KV serving: physical block-pool pages behind per-request block
-tables.  Covers bit-exactness of the paged decode path vs the static
-``generate()`` reference (dense/windowed/SSM/MLA families), copy-free
-spill preemption-resume, hash-based prefix sharing with copy-on-write,
-the block-geometry edge cases (block_size=1, max_seq not a multiple of
-the block size, prompts ending exactly on a block boundary), pool
-invariants under churn, and the zero-measurement guarantee on the paged
-hot path."""
+tables.  Covers bit-exactness of the engine vs the static ``generate()``
+reference (dense/windowed/SSM/hybrid/MLA families), copy-free spill
+preemption-resume, hash-based prefix sharing with copy-on-write and the
+admission capacity it buys, the block-geometry edge cases (block_size=1,
+max_seq not a multiple of the block size, prompts ending exactly on a
+block boundary), pool invariants under churn, and the zero-measurement
+guarantee on the serve hot path."""
 
 import jax
 import jax.numpy as jnp
@@ -14,8 +14,8 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import lm
-from repro.serve import (KVBlockPool, ServeEngine, TrafficConfig, generate,
-                         poisson_trace)
+from repro.serve import (KVBlockPool, ServeEngine, Tenant, TrafficConfig,
+                         generate, poisson_trace)
 
 MAX_SEQ = 48
 
@@ -45,23 +45,25 @@ def _ref_one(params, cfg, prompt, n, max_seq=MAX_SEQ):
 
 
 # ---------------------------------------------------------------------------
-# paged == dense == generate(), across cache families
+# engine == generate(), across cache families
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("prefill_chunk", [2, 4])
 @pytest.mark.parametrize("arch", ["qwen1.5-4b", "gemma3-1b", "mamba2-1.3b",
                                   "zamba2-2.7b"])
-def test_paged_bitexact_vs_sequential_generate(arch):
+def test_paged_bitexact_vs_sequential_generate(arch, prefill_chunk):
     """The block-table indirection must not change a single logit:
-    per-request streams under paged continuous batching (staggered
-    arrivals, slot churn) match the one-request-at-a-time static path.
-    Covers absolute caches (qwen), ring-buffer windows re-expressed as
-    trailing page windows (gemma), slot-major recurrent state (mamba),
-    and both riding together in the hybrid's shared attention block
-    (zamba)."""
+    per-request streams under continuous batching (staggered arrivals,
+    mixed lengths, slot churn) match the one-request-at-a-time static
+    path.  Covers absolute caches (qwen), ring-buffer windows re-expressed
+    as trailing page windows (gemma), slot-major recurrent state reset
+    when a freed slot is reused (mamba), and both riding together in the
+    hybrid's shared attention block (zamba); ``prefill_chunk`` 4 is the
+    serving cells' setting."""
     cfg, params = _model(arch)
     engine = ServeEngine.from_config(cfg, params=_own(params), max_batch=3,
                                      max_seq=MAX_SEQ, block_size=8,
-                                     prefill_chunk=2, paged=True,
+                                     prefill_chunk=prefill_chunk,
                                      debug_invariants=True)
     rng = np.random.default_rng(0)
     jobs = []
@@ -76,18 +78,19 @@ def test_paged_bitexact_vs_sequential_generate(arch):
     engine.run()
     for req, (prompt, n) in zip(reqs, jobs):
         assert req.output == _ref_one(params, cfg, prompt, n), \
-            f"request {req.id} diverged under paged decode"
+            f"request {req.id} diverged from sequential generate()"
         assert len(req.output) == n and not req.truncated
+        assert req.ttft is not None and req.latency >= req.ttft
     assert engine.pool.stats()["free_blocks"] == engine.pool.num_blocks
     engine.pool.check()
 
 
 def test_paged_mla_decode_bitexact():
     """The MLA paged path (latent c_kv + shared k_rope pages) matches the
-    dense MLA decode stream."""
+    slot-cache MLA decode stream."""
     cfg, params = _model("deepseek-v2-lite-16b")
     engine = ServeEngine.from_config(cfg, params=_own(params), max_batch=2,
-                                     max_seq=32, block_size=8, paged=True,
+                                     max_seq=32, block_size=8,
                                      debug_invariants=True)
     jobs = [([1, 2, 3, 4, 5], 6), ([9, 8, 7], 5)]
     reqs = [engine.submit(p, n) for p, n in jobs]
@@ -105,17 +108,15 @@ def test_spill_preemption_resumes_bitexact_without_recompute(arch):
     """With the pool oversubscribed, stalled victims are spilled —
     their pages copied to host and blocks freed — and later resumed by
     re-uploading into fresh blocks.  Streams stay bit-exact and no
-    request is ever teacher-force recomputed (``resume_tokens`` stays
-    empty; that is the dense path's preemption)."""
+    position runs twice: the lanes stepped are exactly the positions the
+    requests needed (prompt + generated - 1 each; nothing is shared)."""
     cfg, params = _model(arch)
     rng = np.random.default_rng(3)
     prompts = [rng.integers(0, cfg.vocab, 12).tolist() for _ in range(3)]
     refs = _refs(params, cfg, prompts, 20)
     engine = ServeEngine.from_config(cfg, params=params, max_batch=4,
                                      max_seq=MAX_SEQ, block_size=8,
-                                     kv_blocks=6, paged=True,
-                                     share_prefix=False,
-                                     debug_invariants=True)
+                                     kv_blocks=6, debug_invariants=True)
     reqs = [engine.submit(p, 20) for p in prompts]
     engine.run(max_steps=5000)
     assert engine.counters["preempt_spills"] > 0, "pool never pressured"
@@ -123,20 +124,24 @@ def test_spill_preemption_resumes_bitexact_without_recompute(arch):
     for req, ref in zip(reqs, refs):
         assert list(req.prompt) + list(req.output) == ref
         assert not req.truncated
-        assert not req.resume_tokens, "spill resume must not recompute"
+    assert engine.counters["prefix_hits"] == 0
+    assert engine.counters["lane_tokens"] == sum(len(p) + 20 - 1
+                                                 for p in prompts), \
+        "spill resume must not recompute"
 
 
 def test_donated_pages_serve_as_the_slot_engine_through_cow_and_spill():
     """The paged step donates its pages and writes them in place: the
     engine's previous page arrays are consumed by each pass, and with
     copy-on-write forks, spills and resumes in between (eager updates of
-    the rebound pages) every stream equals the slot engine's."""
+    the rebound pages) every stream equals ``generate()``'s from slot
+    caches at the same ``max_seq``."""
     cfg, params = _model("qwen1.5-4b")
     prompt = list(range(1, 11))              # 2 full blocks + a partial
-    kw = dict(max_batch=2, max_seq=24, block_size=4, prefill_chunk=1)
-    slot = ServeEngine.from_config(cfg, params=_own(params), **kw)
-    engine = ServeEngine.from_config(cfg, params=_own(params), kv_blocks=7,
-                                     paged=True, debug_invariants=True, **kw)
+    engine = ServeEngine.from_config(cfg, params=_own(params), max_batch=2,
+                                     max_seq=24, block_size=4,
+                                     prefill_chunk=1, kv_blocks=7,
+                                     debug_invariants=True)
     step, donated = engine._step_fn, []
 
     def checked(params, caches, *lanes):
@@ -145,21 +150,17 @@ def test_donated_pages_serve_as_the_slot_engine_through_cow_and_spill():
         return out
 
     engine._step_fn = checked
-    outs = []
-    for eng in (slot, engine):
-        a = eng.submit(prompt, 12)
-        while a.first_token_time is None:
-            eng.step()
-        eng.submit(prompt, 12)
-        eng.run()
-        outs.append([r.output for r in sorted(eng.finished,
-                                              key=lambda r: r.id)])
+    a = engine.submit(prompt, 12)
+    while a.first_token_time is None:
+        engine.step()
+    b = engine.submit(prompt, 12)
+    engine.run()
     assert donated and all(donated), "the paged step must donate its pages"
     assert engine.counters["cow_forks"] > 0
     assert engine.counters["preempt_spills"] > 0
     assert engine.counters["resume_uploads"] > 0
-    assert outs[1] == outs[0]
-    assert outs[1][0] == _ref_one(params, cfg, prompt, 12, max_seq=24)
+    ref = _ref_one(params, cfg, prompt, 12, max_seq=24)
+    assert a.output == ref and b.output == ref
     engine.pool.check()
 
 
@@ -176,7 +177,7 @@ def test_prefix_sharing_cow_under_concurrent_decode():
     cfg, params = _model("qwen1.5-4b")
     engine = ServeEngine.from_config(cfg, params=_own(params), max_batch=3,
                                      max_seq=MAX_SEQ, block_size=8,
-                                     paged=True, debug_invariants=True)
+                                     debug_invariants=True)
     rng = np.random.default_rng(7)
     base = rng.integers(0, cfg.vocab, 10).tolist()     # 1 full + partial
     tail = rng.integers(0, cfg.vocab, 3).tolist()
@@ -198,6 +199,33 @@ def test_prefix_sharing_cow_under_concurrent_decode():
             f"request {req.id} diverged under prefix sharing"
     assert engine.pool.stats()["free_blocks"] == engine.pool.num_blocks
     engine.pool.check()
+
+
+def test_prefix_sharing_admits_a_burst_on_a_tight_block_budget():
+    """The admission capacity prefix sharing buys: after one resident
+    request has registered a 24-token system prompt, a burst of 12
+    requests behind it (block size 8, 8 blocks, 8 slots) keeps at least 5
+    of them resident at once — the concurrency the engine reached here
+    when it still had a dense per-slot mode to compare with, where dense
+    slots held 2 — and later admissions map the prefix pages."""
+    cfg, params = _model("qwen1.5-4b")
+    burst = poisson_trace(TrafficConfig(
+        qps=1000.0, n_requests=12, n_tenants=1, prompt_len=(2, 4),
+        output_len=(4, 8), vocab=cfg.vocab, seed=11, prefix_tokens=24,
+        prefix_groups=1), ["t0"])
+    engine = ServeEngine.from_config(cfg, params=params, max_batch=8,
+                                     max_seq=MAX_SEQ, block_size=8,
+                                     kv_blocks=8, tenants=[Tenant("t0")],
+                                     debug_invariants=True)
+    warm = engine.submit(burst[0].prompt[:25], 8, tenant="t0")
+    while warm.first_token_time is None:
+        engine.step()
+    reqs = [engine.submit(a.prompt, a.max_new_tokens, tenant="t0")
+            for a in burst]
+    engine.run(max_steps=20_000)
+    assert all(r.done for r in reqs)
+    assert engine.counters["max_active"] >= 5
+    assert engine.counters["prefix_hits"] > 0
 
 
 def test_shared_prefix_trace_generator_is_seeded_and_layered():
@@ -229,7 +257,7 @@ def test_block_size_one():
     """One token per page: every advance grows the table by one block."""
     cfg, params = _model("qwen1.5-4b")
     engine = ServeEngine.from_config(cfg, params=_own(params), max_batch=2,
-                                     max_seq=24, block_size=1, paged=True,
+                                     max_seq=24, block_size=1,
                                      debug_invariants=True)
     jobs = [([3, 1, 4, 1, 5], 6), ([2, 7], 5)]
     reqs = [engine.submit(p, n) for p, n in jobs]
@@ -244,7 +272,7 @@ def test_max_seq_not_multiple_of_block_size():
     addressable; truncation still lands exactly at max_seq."""
     cfg, params = _model("qwen1.5-4b")
     engine = ServeEngine.from_config(cfg, params=_own(params), max_batch=2,
-                                     max_seq=42, block_size=8, paged=True,
+                                     max_seq=42, block_size=8,
                                      debug_invariants=True)
     req = engine.submit([5, 4, 3, 2, 1, 0], 60)        # must truncate
     engine.run()
@@ -264,7 +292,7 @@ def test_prompt_exactly_fills_last_block():
     cfg, params = _model("qwen1.5-4b")
     engine = ServeEngine.from_config(cfg, params=_own(params), max_batch=2,
                                      max_seq=MAX_SEQ, block_size=8,
-                                     paged=True, debug_invariants=True)
+                                     debug_invariants=True)
     rng = np.random.default_rng(11)
     prompt = rng.integers(0, cfg.vocab, 16).tolist()
     a = engine.submit(prompt, 6)
@@ -331,14 +359,15 @@ def test_paged_pool_invariants_under_shared_churn():
 
 
 # ---------------------------------------------------------------------------
-# zero-measurement paged hot path
+# zero-measurement serve hot path
 # ---------------------------------------------------------------------------
 
 def test_paged_serve_hot_path_zero_measurements(tmp_path, stall_db,
                                                 monkeypatch):
-    """The paged engine keeps the serve-path guarantee: schedules are
-    index lookups — zero ``Machine.run``/``Machine.time``/autotune calls
-    while serving (prefix sharing and spills included)."""
+    """Acceptance criterion: an engine constructed with a schedule cache
+    resolves its whole kernel plan and serves traffic, a shared prompt
+    prefix included, with zero ``Machine.run``/``Machine.time``/autotune
+    calls — schedules reach the serve path as pure index lookups."""
     import sys
 
     from repro.core import Machine
@@ -371,12 +400,22 @@ def test_paged_serve_hot_path_zero_measurements(tmp_path, stall_db,
     cfg, params = _model("qwen1.5-4b")
     engine = ServeEngine.from_config(
         cfg, params=params, max_batch=2, max_seq=32, block_size=8,
-        paged=True, debug_invariants=True,
+        debug_invariants=True,
         schedule_cache=ScheduleCache(str(tmp_path / "cache")))
-    engine.submit([1, 2, 3, 4], 4)
-    engine.submit([1, 2, 3, 4], 4)       # shares the admission prefix
+    # the tuned kernel resolved in every bucket; untuned fleet members
+    # explicitly serve the -O3 baseline (None), never re-measured
+    rms = {k: v for k, v in engine.plan.items()
+           if (k[0] if isinstance(k, tuple) else k) == "rmsnorm"}
+    assert rms and all(a is not None for a in rms.values())
+    assert any(a is None for a in engine.plan.values())
+    a = engine.submit([1, 2, 3, 4], 4)
+    while a.first_token_time is None:
+        engine.step()
+    engine.submit([1, 2, 3, 4], 4)       # maps the resident prompt page
+    engine.submit([5, 6], 3)
     engine.run()
     assert calls == {"run": 0, "time": 0, "autotune": 0}
+    assert engine.counters["prefix_hits"] == 1
 
 
 # ---------------------------------------------------------------------------
@@ -408,7 +447,7 @@ def test_profiled_step_writes_every_stage_span_nested(tmp_path):
     cfg, params = _model("qwen1.5-4b")
     engine = ServeEngine.from_config(cfg, params=params, max_batch=2,
                                      max_seq=24, block_size=4, kv_blocks=7,
-                                     prefill_chunk=1, paged=True)
+                                     prefill_chunk=1)
     prompt = list(range(1, 11))              # 2 full blocks + a partial
     engine.generate(prompt, 2)               # compile outside the trace
     jax.profiler.start_trace(str(tmp_path))
@@ -440,8 +479,7 @@ def test_engine_lays_out_its_weights_once_under_a_span(tmp_path):
     given = _own(params)
     jax.profiler.start_trace(str(tmp_path))
     engine = ServeEngine.from_config(cfg, params=given, max_batch=2,
-                                     max_seq=MAX_SEQ, block_size=8,
-                                     paged=True)
+                                     max_seq=MAX_SEQ, block_size=8)
     jax.profiler.stop_trace()
     (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
     assert [n for n, _, _ in _program_spans(path)] == ["layout"]
@@ -462,8 +500,7 @@ def test_paged_step_hlo_carries_named_scopes():
 
     cfg, params = _model("qwen1.5-4b")
     engine = ServeEngine.from_config(cfg, params=params, max_batch=2,
-                                     max_seq=MAX_SEQ, block_size=8,
-                                     paged=True)
+                                     max_seq=MAX_SEQ, block_size=8)
     step = _paged_step_fns(cfg, None, MAX_SEQ)[0]
     lane = jnp.zeros((2,), jnp.int32)
     table = jnp.zeros((2, engine.pool.blocks_per_slot), jnp.int32)

@@ -134,7 +134,7 @@ def test_paged_decode_of_reduced_stablelm_agrees_with_its_forward():
     own = jax.tree.map(jnp.copy, params)
     engine = ServeEngine.from_config(cfg, params=own, max_batch=2,
                                      max_seq=40, block_size=8,
-                                     prefill_chunk=2, paged=True)
+                                     prefill_chunk=2)
     rng = np.random.default_rng(5)
     jobs = [(rng.integers(0, cfg.vocab, n).tolist(), m)
             for n, m in ((9, 12), (17, 8), (5, 14))]

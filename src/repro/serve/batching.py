@@ -23,6 +23,12 @@ A request finishes when it has ``max_new_tokens`` generated tokens, or is
 *truncated* when its next write would need cache position ``max_seq``
 (the pool's :meth:`~repro.serve.pool.KVBlockPool.fits` admission check
 guarantees at least one generated token before this can trigger).
+
+All of that follows from lengths, so a slot advances when its pass is
+dispatched (:meth:`SlotState.advance`), before the pass's token is known:
+the generated token is held as ``PENDING`` until the engine reads it, and
+``feed`` names the lane of the dispatched pass that samples it, which the
+next pass reads on the device (``src`` in :func:`assemble_paged`).
 """
 
 from __future__ import annotations
@@ -39,6 +45,9 @@ from repro.serve.scheduler import Request
 # paged step's masked scatter drops every idle-lane write.
 NEVER_WRITE = 1 << 30
 
+# A generated token whose pass was dispatched and not yet read back.
+PENDING = -1
+
 
 @dataclasses.dataclass
 class SlotState:
@@ -54,6 +63,9 @@ class SlotState:
     # suppressed — those positions live in blocks shared with the donor
     # request and already hold bit-identical K/V.
     write_start: int = 0
+    # Lane, in the pass dispatched last, whose sampled token is this
+    # slot's ``PENDING`` next input.
+    feed: int = -1
 
     @classmethod
     def admit(cls, slot: int, request: Request,
@@ -95,14 +107,16 @@ class SlotState:
     def done(self) -> bool:
         return len(self.tokens) >= self.target_len
 
-    def apply(self, next_token: int, max_seq: int) -> bool:
-        """Account one completed pass at ``self.pos``; True when the pass
-        emitted (appended) a generated token — the first such pass per
-        request is what TTFT clocks."""
+    def advance(self, lane: int, max_seq: int) -> bool:
+        """Account one pass at ``self.pos``, dispatched on ``lane``; True
+        when the pass emits a generated token, appended as ``PENDING`` at
+        ``tokens[pos]`` (the new ``pos``) until the engine reads its value
+        — the first such pass per request is what TTFT clocks."""
         appended = False
         if self.pos + 1 >= self.prompt_len and len(self.tokens) < self.target_len:
-            self.tokens.append(int(next_token))
+            self.tokens.append(PENDING)
             appended = True
+        self.feed = lane
         self.pos += 1
         if not self.done and self.pos >= max_seq:
             # No cache position left for the next write: hard stop.
@@ -114,19 +128,21 @@ class SlotState:
 def assemble_paged(slots: Sequence[SlotState], max_batch: int,
                    scratch_slot: int, blocks_per_slot: int, blocks_of
                    ) -> Optional[Tuple[np.ndarray, np.ndarray, np.ndarray,
-                                       np.ndarray, np.ndarray,
+                                       np.ndarray, np.ndarray, np.ndarray,
                                        List[SlotState]]]:
     """Build the fixed-width step arrays for the slots that advance now,
     or None when nothing advances this step.
 
-    Returns ``(idx, table, tok, pos, write_start, stepped)``, each array
-    ``max_batch`` lanes long — ``idx`` points idle lanes at the scratch
-    row (the *gather* of slot-major recurrent rows must stay in bounds;
-    the engine's scatter drops them),
+    Returns ``(idx, table, tok, pos, write_start, src, stepped)``, each
+    array ``max_batch`` lanes long — ``idx`` points idle lanes at the
+    scratch row (the *gather* of slot-major recurrent rows must stay in
+    bounds; the engine's scatter drops them),
     ``table`` is (max_batch, blocks_per_slot) physical block ids padded
     with 0 (idle lanes and positions past a request's grant are masked
-    reads / suppressed writes), and ``write_start`` is ``NEVER_WRITE`` on
-    idle lanes so the single masked page scatter drops them."""
+    reads / suppressed writes), ``write_start`` is ``NEVER_WRITE`` on
+    idle lanes so the single masked page scatter drops them, and ``src``
+    is, where a lane's token is still ``PENDING``, the lane of the
+    previous pass that samples it (``tok`` is 0 there), else -1."""
     stepped = [s for s in slots if not s.done and not s.stalled]
     if not stepped:
         return None
@@ -135,11 +151,15 @@ def assemble_paged(slots: Sequence[SlotState], max_batch: int,
     tok = np.zeros((max_batch,), dtype=np.int32)
     pos = np.zeros((max_batch,), dtype=np.int32)
     wstart = np.full((max_batch,), NEVER_WRITE, dtype=np.int32)
+    src = np.full((max_batch,), -1, dtype=np.int32)
     for lane, s in enumerate(stepped):
         idx[lane] = s.slot
         blocks = blocks_of(s)
         table[lane, :len(blocks)] = blocks
-        tok[lane] = s.tokens[s.pos]
+        if s.tokens[s.pos] == PENDING:
+            src[lane] = s.feed
+        else:
+            tok[lane] = s.tokens[s.pos]
         pos[lane] = s.pos
         wstart[lane] = s.write_start
-    return idx, table, tok, pos, wstart, stepped
+    return idx, table, tok, pos, wstart, src, stepped

@@ -19,18 +19,24 @@ which tenant's request gets the next free slot.  Every engine step:
    position through ONE fixed-shape jitted decode pass (lanes reach
    their pages through the block table at per-row positions; idle
    lanes pad onto the scratch row, so the step compiles exactly once);
+   a tick runs up to ``1 + prefill_chunk`` such passes, each dispatched
+   before any of their tokens is read: a lane takes the token that the
+   previous pass sampled from that pass's output on the device, and
+   the host reads the tick's tokens in one transfer after its last pass;
 4. **preempt** — if nothing could advance (pool exhausted), the
    youngest request's pages are copied to the host and its blocks freed;
    it goes back to the head of its tenant queue and later resumes from
    the same position by re-uploading them.
 
 Each stage runs inside a host span ``repro.serve.<stage>`` (:func:`span`;
-``step`` holds ``evict``, ``admit``, ``pass`` and ``preempt``; ``pass``
-holds ``pool``, ``cow_copy``, ``assemble``, ``dispatch``, ``sync`` and
-``apply``; ``layout`` runs once, at construction), which a profiler trace
-puts on the device planes' clock.  The stage clocks in ``counters``
-(``queue_wait_s``, ``prefill_s``, ``sync_s``, ``host_s``, ``layout_s``) run
-whether or not a trace does.
+``step`` holds ``evict``, ``admit``, ``pass``, ``sync``, ``apply`` and
+``preempt``; ``pass`` holds ``pool``, ``cow_copy``, ``assemble`` and
+``dispatch``; ``sync`` and ``apply`` run once a tick, after its passes;
+``layout`` runs once, at construction), which a profiler trace puts on the
+device planes' clock.  The stage clocks in ``counters`` (``queue_wait_s``,
+``prefill_s``, ``sync_s``, ``host_s``, ``layout_s``) run whether or not a
+trace does; ``passes_ahead`` counts the passes dispatched while an earlier
+pass of the same tick was still unread.
 
 Kernel schedules come from the cache index via
 :func:`repro.sched.lowering.schedule_plan` (re-exported here) —
@@ -94,12 +100,15 @@ def _paged_step_fns(cfg: ModelConfig, mesh, max_seq: int):
 
     ``step`` donates the caches (argument 1): the pages are written in
     place, and the caller must rebind its cache list to the returned one,
-    as :meth:`ServeEngine._pass` does."""
+    as :meth:`ServeEngine._pass` does.  ``prev`` is the previous pass's
+    output, still on the device: a lane with ``src >= 0`` feeds
+    ``prev[src]`` in place of ``tok``."""
     key = (_cfg_key(cfg), None if mesh is None else id(mesh), int(max_seq))
     if key not in _STEP_FNS:
         kinds = paged_cache_kinds(cfg)
 
-        def step(params, caches, idx, table, tok, pos, wstart):
+        def step(params, caches, idx, table, tok, pos, wstart, src, prev):
+            tok = jnp.where(src >= 0, prev[src], tok)
             write_mask = pos >= wstart
             rows = [jax.tree.map(lambda a: a[idx], c) if kind == "slot"
                     else c for c, kind in zip(caches, kinds)]
@@ -244,6 +253,16 @@ class ServeEngine:
                                                    self._kinds))
         self._step_fn, self._reset_fn = _paged_step_fns(cfg, mesh,
                                                         self.max_seq)
+        # Every pass gets its ``prev`` committed to one sharding, a tick's
+        # first pass these zeros (no lane reads them), so that the first
+        # pass and the others share one compiled program.
+        self._prev_sharding = (
+            jax.sharding.SingleDeviceSharding(
+                next(iter(jax.tree.leaves(self.caches)[0].devices())))
+            if mesh is None else jax.sharding.NamedSharding(
+                mesh, jax.sharding.PartitionSpec()))
+        self._no_prev = jax.device_put(np.zeros(self.max_batch, np.int32),
+                                       self._prev_sharding)
 
         if isinstance(schedule_cache, str):
             schedule_cache = ScheduleCache(schedule_cache)
@@ -264,9 +283,9 @@ class ServeEngine:
         # Stage clocks (seconds): ``queue_wait_s`` sums submit (or requeue)
         # to admission over ``admissions``; ``prefill_s`` sums admission to
         # first token over ``first_tokens``; ``sync_s`` is the host blocked
-        # on the device for a pass's tokens, ``host_s`` the rest of step();
+        # on the device for a tick's tokens, ``host_s`` the rest of step();
         # ``layout_s`` is the weights' layout at construction.
-        self.counters = {"passes": 0, "lane_tokens": 0,
+        self.counters = {"passes": 0, "passes_ahead": 0, "lane_tokens": 0,
                          "admissions": 0, "stalls": 0, "preemptions": 0,
                          "max_active": 0,
                          "prefix_hits": 0, "cow_forks": 0,
@@ -318,7 +337,18 @@ class ServeEngine:
         tick runs one full-width pass, plus up to ``prefill_chunk`` more
         while any slot is still teacher-forcing its prompt — every pass
         advances *all* eligible slots, so prompt catch-up never drops
-        lane occupancy and never stalls the running decodes."""
+        lane occupancy and never stalls the running decodes.
+
+        The passes run ahead of the device.  Which slots step, their
+        positions, completion, truncation and pool grants follow from
+        lengths alone, so each pass is assembled and dispatched while the
+        previous one runs, and takes that pass's sampled tokens on the
+        device.  After the last pass the host reads every pass's tokens
+        in one transfer (``sync``), then fills them in, stamps first
+        tokens and registers prefix blocks, in pass order (``apply``);
+        nothing inside a tick reads them before, since admission happens
+        only at its start.  The state left when ``step()`` returns is the
+        same as if each pass had been read as it finished."""
         t0 = time.perf_counter()
         sync0 = self.counters["sync_s"]
         with span("step"):
@@ -330,24 +360,31 @@ class ServeEngine:
                                               len(self._active))
             for s in self._active:
                 s.stalled = False
-            advanced = 0
+            ran: List = []               # (sampled tokens, lanes) a pass
+            prev = self._no_prev
             for _ in range(1 + self.prefill_chunk):
                 with span("pass"):
-                    n = self._pass()
-                advanced += n
-                if n == 0 or not any(s.in_prefill and not s.done
-                                     and not s.stalled for s in self._active):
+                    out = self._pass(prev)
+                if out is None:
                     break
+                ran.append(out)
+                prev = out[0]
+                if not any(s.in_prefill and not s.done and not s.stalled
+                           for s in self._active):
+                    break
+            if ran:
+                self.counters["passes_ahead"] += len(ran) - 1
+                self._read(ran)
             with span("evict"):
                 self._evict()
-            if advanced == 0 and self._active:
+            if not ran and self._active:
                 with span("preempt"):
                     self._preempt_youngest()
             if self.debug_invariants:
                 self.pool.check()
         self.counters["host_s"] += (time.perf_counter() - t0
                                     - (self.counters["sync_s"] - sync0))
-        return advanced
+        return sum(len(lanes) for _, lanes in ran)
 
     def run(self, max_steps: int = 1_000_000) -> List[Request]:
         """Drain every queued/active request; returns finished requests
@@ -424,7 +461,12 @@ class ServeEngine:
             self._upload_spill(table, spill)
             self.counters["resume_uploads"] += 1
 
-    def _pass(self) -> int:
+    def _pass(self, prev: jax.Array) -> Optional[tuple]:
+        """Grant, assemble and dispatch one pass, feeding ``prev`` (the
+        previous pass's sampled tokens, on the device); advance its slots.
+        Returns the pass's sampled tokens, still on the device, and its
+        lanes as ``(slot, position after the pass, emitted)``; None when
+        no slot can advance."""
         cand: List[SlotState] = []
         forks: List = []
         with span("pool"):
@@ -449,29 +491,43 @@ class ServeEngine:
                 self.pool.blocks_per_slot,
                 lambda s: self.pool.table(s.request.id).blocks)
         if asm is None:
-            return 0
-        *lanes, stepped = asm     # idx, table, tok, pos, wstart
+            return None
+        *lanes, stepped = asm     # idx, table, tok, pos, wstart, src
         with span("dispatch"):
             nxt, self.caches = self._step_fn(
-                self.params, self.caches, *(jnp.asarray(a) for a in lanes))
+                self.params, self.caches, *(jnp.asarray(a) for a in lanes),
+                prev)
+            nxt = jax.device_put(nxt, self._prev_sharding)
+            advanced = []
+            for lane, s in enumerate(stepped):
+                emitted = s.advance(lane, self.max_seq)
+                advanced.append((s, s.pos, emitted))
+        self.counters["passes"] += 1
+        self.counters["lane_tokens"] += len(stepped)
+        return nxt, advanced
+
+    def _read(self, ran: List) -> None:
+        """Read the tick's passes' tokens in one blocking transfer, then
+        account each pass in order: fill in its emitted tokens, stamp
+        first tokens, register completed prefix blocks."""
         t = time.perf_counter()
         with span("sync"):
-            nxt = np.asarray(nxt)
+            sampled = jax.device_get([nxt for nxt, _ in ran])
         self.counters["sync_s"] += time.perf_counter() - t
         with span("apply"):
             now = time.monotonic()
-            for lane, s in enumerate(stepped):
-                appended = s.apply(int(nxt[lane]), self.max_seq)
-                if appended and s.request.first_token_time is None:
-                    s.request.first_token_time = now
-                    self.counters["prefill_s"] += now - s.request.admit_time
-                    self.counters["first_tokens"] += 1
-                if self._shares_prefix:
-                    self.pool.commit(s.request.id, s.tokens, s.pos,
-                                     prompt_len=s.prompt_len)
-        self.counters["passes"] += 1
-        self.counters["lane_tokens"] += len(stepped)
-        return len(stepped)
+            for nxt, (_, lanes) in zip(sampled, ran):
+                for lane, (s, pos, emitted) in enumerate(lanes):
+                    if emitted:
+                        s.tokens[pos] = int(nxt[lane])
+                        if s.request.first_token_time is None:
+                            s.request.first_token_time = now
+                            self.counters["prefill_s"] += (
+                                now - s.request.admit_time)
+                            self.counters["first_tokens"] += 1
+                    if self._shares_prefix:
+                        self.pool.commit(s.request.id, s.tokens, pos,
+                                         prompt_len=s.prompt_len)
 
     def _copy_blocks(self, forks: List) -> None:
         """Apply copy-on-write forks: device-copy each ``src`` page onto

@@ -77,7 +77,7 @@ def _compile_paged_step(sharding, cfg, max_batch, max_seq, block, blocks):
     table = jax.ShapeDtypeStruct((max_batch, -(-max_seq // block)),
                                  jnp.int32, sharding=sharding)
     compiled = step.lower(params, caches, lane, table, lane, lane,
-                          lane).compile()
+                          lane, lane, lane).compile()
     return compiled, caches
 
 

@@ -100,6 +100,115 @@ def test_paged_mla_decode_bitexact():
 
 
 # ---------------------------------------------------------------------------
+# run-ahead: a tick's passes are dispatched before their tokens are read
+# ---------------------------------------------------------------------------
+
+def _watch_ticks(engine, monkeypatch):
+    """Per ``step()``, a record of its passes and of its reads of sampled
+    tokens: ``passes`` holds, per pass dispatched, the counters it moved
+    (``stalls``, ``cow_forks``) and how many of its slots it finished;
+    ``reads`` holds, per ``jax.device_get`` call, the passes dispatched
+    before it and the arrays it read."""
+    ticks = []
+    step, pass_, get = engine.step, engine._pass, jax.device_get
+
+    def watched_step():
+        ticks.append({"passes": [], "reads": []})
+        return step()
+
+    def watched_pass(prev):
+        before = dict(engine.counters)
+        out = pass_(prev)
+        moved = {k: engine.counters[k] - before[k]
+                 for k in ("stalls", "cow_forks")}
+        if out is not None:
+            moved["finished"] = sum(s.done for s, _, _ in out[1])
+            ticks[-1]["passes"].append(moved)
+        return out
+
+    def watched_get(x):
+        if ticks:
+            ticks[-1]["reads"].append((len(ticks[-1]["passes"]), len(x)))
+        return get(x)
+
+    monkeypatch.setattr(engine, "step", watched_step)
+    monkeypatch.setattr(engine, "_pass", watched_pass)
+    monkeypatch.setattr(jax, "device_get", watched_get)
+    return ticks
+
+
+@pytest.mark.parametrize("prefill_chunk", [0, 4])
+def test_tick_dispatches_its_passes_then_reads_them_once(prefill_chunk,
+                                                         monkeypatch):
+    """Every tick that runs k passes dispatches all k before it reads any
+    token, and reads all k in one call; ``passes_ahead`` counts the k - 1
+    passes of each tick that ran behind an unread pass, so it is 0 when
+    ``prefill_chunk`` is 0 (one pass a tick).  Streams stay those of
+    ``generate()``."""
+    cfg, params = _model("qwen1.5-4b")
+    engine = ServeEngine.from_config(cfg, params=_own(params), max_batch=3,
+                                     max_seq=MAX_SEQ, block_size=8,
+                                     prefill_chunk=prefill_chunk,
+                                     debug_invariants=True)
+    ticks = _watch_ticks(engine, monkeypatch)
+    rng = np.random.default_rng(4)
+    jobs = [(rng.integers(0, cfg.vocab, 11).tolist(), n) for n in (3, 9, 6)]
+    reqs = [engine.submit(p, n) for p, n in jobs[:2]]
+    engine.step()
+    reqs.append(engine.submit(*jobs[2]))
+    engine.run()
+
+    counts = [len(t["passes"]) for t in ticks]
+    for t, k in zip(ticks, counts):
+        assert t["reads"] == ([(k, k)] if k else []), t
+    assert max(counts) == 1 + prefill_chunk
+    assert engine.counters["passes"] == sum(counts)
+    assert engine.counters["passes_ahead"] == sum(max(0, k - 1)
+                                                  for k in counts)
+    assert (engine.counters["passes_ahead"] == 0) == (prefill_chunk == 0)
+    refs = _refs(params, cfg, [p for p, _ in jobs], 9)
+    for req, (_, n), ref in zip(reqs, jobs, refs):
+        assert req.output == ref[11:11 + n]
+
+
+def test_run_ahead_streams_bitexact_through_mid_tick_finish_stall_and_fork(
+        monkeypatch):
+    """Inside one tick's unread passes a lane finishes while another is
+    still in its prompt (and the decoding lane behind it moves up a lane),
+    a lane stalls on the exhausted pool, and a lane copy-on-write-forks
+    the shared prompt page it is about to write: every stream still
+    equals ``generate()``'s."""
+    cfg, params = _model("qwen1.5-4b")
+    engine = ServeEngine.from_config(cfg, params=_own(params), max_batch=4,
+                                     max_seq=MAX_SEQ, block_size=4,
+                                     kv_blocks=12, prefill_chunk=4,
+                                     debug_invariants=True)
+    ticks = _watch_ticks(engine, monkeypatch)
+    prompt = list(range(1, 11))            # 2 full blocks + a partial
+    longer = np.random.default_rng(5).integers(0, cfg.vocab, 20).tolist()
+    a = engine.submit(prompt, 24)
+    while not engine._active or engine._active[0].pos < 12:
+        engine.step()                      # A writes past its tail block
+    x = engine.submit([7, 3], 2)           # done in the tick's third pass
+    b = engine.submit(prompt, 6)           # maps A's tail block, forks it
+    c = engine.submit(longer, 4)           # keeps the tick going
+    engine.run()
+
+    def mid_tick(key, behind):
+        return any(p[key] for t in ticks
+                   for i, p in enumerate(t["passes"]) if behind(i, t))
+    assert mid_tick("finished", lambda i, t: i < len(t["passes"]) - 1)
+    assert mid_tick("stalls", lambda i, t: i >= 1)
+    assert mid_tick("cow_forks", lambda i, t: i >= 1)
+    for req, p, n in ((a, prompt, 24), (x, [7, 3], 2), (b, prompt, 6),
+                      (c, longer, 4)):
+        ref = _ref_one(params, cfg, p, n)
+        assert req.output == (ref[:len(req.output)] if req.truncated
+                              else ref)
+    engine.pool.check()
+
+
+# ---------------------------------------------------------------------------
 # copy-free preemption: spill to host, resume by remap
 # ---------------------------------------------------------------------------
 
@@ -424,8 +533,8 @@ def test_paged_serve_hot_path_zero_measurements(tmp_path, stall_db,
 
 STAGES = {"step": None, "evict": "step", "admit": "step", "pass": "step",
           "preempt": "step", "pool": "pass", "cow_copy": "pass",
-          "assemble": "pass", "dispatch": "pass", "sync": "pass",
-          "apply": "pass"}
+          "assemble": "pass", "dispatch": "pass", "sync": "step",
+          "apply": "step"}
 
 
 def _program_spans(path):
@@ -505,7 +614,7 @@ def test_paged_step_hlo_carries_named_scopes():
     lane = jnp.zeros((2,), jnp.int32)
     table = jnp.zeros((2, engine.pool.blocks_per_slot), jnp.int32)
     text = step.lower(engine.params, engine.caches, lane, table, lane, lane,
-                      lane).compile().as_text()
+                      lane, lane, lane).compile().as_text()
     for scope in ("layer0/attn/kv_write", "layer0/attn/attend",
                   "layer1/mlp", "/head/"):
         assert scope in text, scope

@@ -161,6 +161,8 @@ def summarize(record, cap: Capture) -> dict:
         "due_to_first_token_mean_ms": sum(first) / len(first) if first
         else None,
         "first_tokens_counted": len(first),
+        # passes dispatched behind an unread pass of their tick, a share
+        "passes_ahead_share": _ms(record, "passes_ahead", "passes", False),
     }
     tr = record.get("trace")
     if tr and "idle_by_program_span" in tr:
@@ -365,7 +367,8 @@ def step_shapes(found):
     lane = jax.ShapeDtypeStruct((B,), jnp.int32)
     table = jax.ShapeDtypeStruct(
         (B, -(-geo["max_seq"] // geo["block_size"])), jnp.int32)
-    return pcfg, geo, (params, caches, lane, table, lane, lane, lane)
+    return pcfg, geo, (params, caches, lane, table, lane, lane, lane, lane,
+                       lane)
 
 
 def cmd_hlo(argv) -> int:
